@@ -7,7 +7,8 @@ are machine-generated instead of scraped from the CSV (the committed
 module, plus every plan/launch event the modules trigger) as Chrome-trace
 JSON + a sibling .jsonl event log; ``--metrics-every N`` prints a
 metrics-registry delta after every N modules. Set REPRO_BENCH_FULL=1 for
-the paper-scale corpus (600 matrices)."""
+the paper-scale corpus (600 matrices). A module that raises prints its
+``<module>/ERROR`` row, the others still run, and the run exits non-zero."""
 import argparse
 import json
 import os
@@ -86,6 +87,7 @@ def main(argv=None) -> None:
     if args.trace_out:
         trace = install_tracer(Tracer(registry=registry, strict=False))
     results = {}
+    failed = []
     print("name,us_per_call,derived")
     for i, (name, mod) in enumerate(selected, start=1):
         t0 = time.time()
@@ -98,6 +100,7 @@ def main(argv=None) -> None:
         except Exception as e:
             print(f"{name}/ERROR,0.0,{type(e).__name__}:{e}")
             traceback.print_exc(file=sys.stderr)
+            failed.append(name)
             continue
         for r_name, us, derived in rows:
             print(f"{r_name},{us:.1f},{derived}")
@@ -124,7 +127,11 @@ def main(argv=None) -> None:
         with open(tmp, "w") as f:
             json.dump(results, f, indent=2, sort_keys=True)
         os.replace(tmp, args.json_out)
+    if failed:
+        sys.exit(f"benchmark modules raised: {', '.join(failed)}")
 
 
 if __name__ == "__main__":
+    from repro.kernels.common import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -12,7 +12,7 @@ under contiguous row partitioning (Fig. 4).
 """
 from __future__ import annotations
 
-from typing import Callable, Dict
+from typing import Callable, Dict, Optional
 
 import numpy as np
 
@@ -193,6 +193,37 @@ def gen_zipf(n: int, seed: int = 0, a: float = 1.09, core_frac: float = 0.44,
     lengths = n * (max(core_frac * n, 1.0) / rank) ** s
     lengths = np.clip(lengths, 1, n).astype(np.int64)
     return _from_row_lengths(lengths, n, lambda i, ln, r: np.arange(ln), seed)
+
+
+def gen_stencil27(nx: int, ny: Optional[int] = None, nz: Optional[int] = None,
+                  seed: int = 0) -> CSR:
+    """HPCG's operator structure: the 27-point stencil on an nx*ny*nz grid.
+
+    Row ``(z*ny + y)*nx + x`` couples to every grid neighbour within one
+    step in each dimension (27 in the interior, fewer on the faces), so the
+    matrix is banded with three dense plane bands. HPCG fixes the values
+    (26 on the diagonal, -1 off it); here the off-diagonals are seeded
+    uniforms in [-1, 1) and the diagonal is 26, so a reference comparison
+    also catches a mis-placed entry. Built vectorized: one pass per stencil
+    offset, no per-row Python loop. Not part of ``GENERATORS``/Table 2.
+    """
+    ny = nx if ny is None else ny
+    nz = nx if nz is None else nz
+    n = nx * ny * nz
+    z, y, x = np.unravel_index(np.arange(n, dtype=np.int64), (nz, ny, nx))
+    cols, valid = [], []
+    # offsets in (dz, dy, dx) lexicographic order = increasing column
+    for dz in (-1, 0, 1):
+        for dy in (-1, 0, 1):
+            for dx in (-1, 0, 1):
+                cols.append(((z + dz) * ny + (y + dy)) * nx + (x + dx))
+                valid.append((0 <= z + dz) & (z + dz < nz) & (0 <= y + dy)
+                             & (y + dy < ny) & (0 <= x + dx) & (x + dx < nx))
+    cols, valid = np.stack(cols, axis=1), np.stack(valid, axis=1)
+    vals = _rng(seed).uniform(-1.0, 1.0, cols.shape).astype(np.float32)
+    vals[:, 13] = 26.0                          # (0, 0, 0): the diagonal
+    row_ptrs = np.concatenate([[0], np.cumsum(valid.sum(axis=1))])
+    return CSR(row_ptrs, cols[valid].astype(np.uint32), vals[valid], (n, n))
 
 
 GENERATORS: Dict[str, Callable[..., CSR]] = {

@@ -25,6 +25,13 @@ Schedules
     grid runs sum_s C*w_s steps instead of n_block_rows*max_w — the padding
     eliminated by slicing is grid steps that simply never launch.
 
+  Vector layout
+    A TPU block's last two dims must be multiples of (8, 128) or equal the
+    array's. A (1, bs) slice of an (n_block_cols, bs) vector is neither, so
+    the SpMV wrappers view x and y as (n, 1, bs): each block is one whole
+    (1, bs) row and the kernel computes it as x (1, bs) . A^T, an MXU op
+    with the A tile contracted on its minor dim.
+
   SpMM (multi-RHS)
     Same two schedules with x blocked as (n_block_cols, bs, k): one A-tile
     DMA now feeds a (bs, bs) @ (bs, k) MXU op, amortizing A traffic across k
@@ -45,7 +52,17 @@ from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 
-def _ell_kernel(idx_ref, cols_ref, blk_ref, x_ref, y_ref):
+def _tile_product(blk_ref, x_ref, vector: bool):
+    """One cell's product: (bs, bs) @ (bs, k) for SpMM, and for SpMV the
+    (1, bs) vector row times the tile transposed, (1, bs) . (bs, bs)^T."""
+    if vector:
+        return jax.lax.dot_general(
+            x_ref[0], blk_ref[0], (((1,), (1,)), ((), ())),
+            preferred_element_type=jnp.float32)
+    return jnp.dot(blk_ref[0], x_ref[0], preferred_element_type=jnp.float32)
+
+
+def _ell_kernel(idx_ref, cols_ref, blk_ref, x_ref, y_ref, *, vector):
     del idx_ref, cols_ref  # consumed by the index maps
     j = pl.program_id(1)
 
@@ -53,14 +70,12 @@ def _ell_kernel(idx_ref, cols_ref, blk_ref, x_ref, y_ref):
     def _init():
         y_ref[...] = jnp.zeros_like(y_ref)
 
-    # (bs, bs) @ (bs,) or (bs, bs) @ (bs, k), accumulated into the resident
-    # output block-row.
-    y_ref[...] += jnp.dot(
-        blk_ref[0], x_ref[0], preferred_element_type=jnp.float32
-    )[None]
+    # accumulated into the resident output block-row
+    y_ref[0] += _tile_product(blk_ref, x_ref, vector)
 
 
-def _sell_kernel(idx_ref, cols_ref, rows_ref, blk_ref, x_ref, y_ref):
+def _sell_kernel(idx_ref, cols_ref, rows_ref, blk_ref, x_ref, y_ref, *,
+                 vector):
     del idx_ref, cols_ref  # consumed by the index maps
     t = pl.program_id(0)
     first = jnp.logical_or(t == 0, rows_ref[t] != rows_ref[jnp.maximum(t - 1, 0)])
@@ -69,9 +84,7 @@ def _sell_kernel(idx_ref, cols_ref, rows_ref, blk_ref, x_ref, y_ref):
     def _init():
         y_ref[...] = jnp.zeros_like(y_ref)
 
-    y_ref[...] += jnp.dot(
-        blk_ref[0], x_ref[0], preferred_element_type=jnp.float32
-    )[None]
+    y_ref[0] += _tile_product(blk_ref, x_ref, vector)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -89,23 +102,11 @@ def bsr_spmv_pallas(block_indices: jax.Array, block_cols: jax.Array,
     Returns:
       (n_br, bs) float32 — blocked result vector.
     """
-    n_br, mb = block_indices.shape
+    n_br = block_indices.shape[0]
     bs = blocks.shape[-1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=2,
-        grid=(n_br, mb),
-        in_specs=[
-            pl.BlockSpec((1, bs, bs), lambda i, j, idx, cols: (idx[i, j], 0, 0)),
-            pl.BlockSpec((1, bs), lambda i, j, idx, cols: (cols[i, j], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bs), lambda i, j, idx, cols: (i, 0)),
-    )
-    return pl.pallas_call(
-        _ell_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_br, bs), jnp.float32),
-        interpret=interpret,
-    )(block_indices, block_cols, blocks, x_blocks)
+    y = _ell_call(block_indices, block_cols, blocks,
+                  x_blocks.reshape(-1, 1, bs), vector=True, interpret=interpret)
+    return y.reshape(n_br, bs)
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -120,22 +121,30 @@ def bsr_spmm_pallas(block_indices: jax.Array, block_cols: jax.Array,
     Returns:
       (n_br, bs, k) float32 — blocked result rows.
     """
+    return _ell_call(block_indices, block_cols, blocks, x_blocks,
+                     vector=False, interpret=interpret)
+
+
+def _ell_call(block_indices, block_cols, blocks, x_blocks, *, vector: bool,
+              interpret: bool) -> jax.Array:
+    """The ELL pallas_call; x_blocks is (n_bc, bs, k), or (n_bc, 1, bs) with
+    ``vector``. The output block-row has the x tile's shape."""
     n_br, mb = block_indices.shape
     bs = blocks.shape[-1]
-    k = x_blocks.shape[-1]
+    tile = tuple(x_blocks.shape[1:])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=2,
         grid=(n_br, mb),
         in_specs=[
             pl.BlockSpec((1, bs, bs), lambda i, j, idx, cols: (idx[i, j], 0, 0)),
-            pl.BlockSpec((1, bs, k), lambda i, j, idx, cols: (cols[i, j], 0, 0)),
+            pl.BlockSpec((1,) + tile, lambda i, j, idx, cols: (cols[i, j], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bs, k), lambda i, j, idx, cols: (i, 0, 0)),
+        out_specs=pl.BlockSpec((1,) + tile, lambda i, j, idx, cols: (i, 0, 0)),
     )
     return pl.pallas_call(
-        _ell_kernel,
+        functools.partial(_ell_kernel, vector=vector),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_br, bs, k), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n_br,) + tile, jnp.float32),
         interpret=interpret,
     )(block_indices, block_cols, blocks, x_blocks)
 
@@ -160,23 +169,11 @@ def bsr_spmv_sell_pallas(cell_block: jax.Array, cell_col: jax.Array,
       (n_block_rows, bs) float32 in SELL-sorted row order; scatter back with
       ``SELLBSR.row_perm``.
     """
-    n_cells = cell_block.shape[0]
     bs = blocks.shape[-1]
-    grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
-        grid=(n_cells,),
-        in_specs=[
-            pl.BlockSpec((1, bs, bs), lambda t, idx, cols, rows: (idx[t], 0, 0)),
-            pl.BlockSpec((1, bs), lambda t, idx, cols, rows: (cols[t], 0)),
-        ],
-        out_specs=pl.BlockSpec((1, bs), lambda t, idx, cols, rows: (rows[t], 0)),
-    )
-    return pl.pallas_call(
-        _sell_kernel,
-        grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_block_rows, bs), jnp.float32),
-        interpret=interpret,
-    )(cell_block, cell_col, cell_row, blocks, x_blocks)
+    y = _sell_call(cell_block, cell_col, cell_row, blocks,
+                   x_blocks.reshape(-1, 1, bs), n_block_rows, vector=True,
+                   interpret=interpret)
+    return y.reshape(n_block_rows, bs)
 
 
 @functools.partial(jax.jit, static_argnames=("n_block_rows", "interpret"))
@@ -189,21 +186,29 @@ def bsr_spmm_sell_pallas(cell_block: jax.Array, cell_col: jax.Array,
     Same contract as ``bsr_spmv_sell_pallas`` with x_blocks of shape
     (n_block_cols, bs, k); returns (n_block_rows, bs, k) in sorted order.
     """
+    return _sell_call(cell_block, cell_col, cell_row, blocks, x_blocks,
+                      n_block_rows, vector=False, interpret=interpret)
+
+
+def _sell_call(cell_block, cell_col, cell_row, blocks, x_blocks,
+               n_block_rows: int, *, vector: bool,
+               interpret: bool) -> jax.Array:
+    """The SELL pallas_call; tile shapes as in ``_ell_call``."""
     n_cells = cell_block.shape[0]
     bs = blocks.shape[-1]
-    k = x_blocks.shape[-1]
+    tile = tuple(x_blocks.shape[1:])
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
         grid=(n_cells,),
         in_specs=[
             pl.BlockSpec((1, bs, bs), lambda t, idx, cols, rows: (idx[t], 0, 0)),
-            pl.BlockSpec((1, bs, k), lambda t, idx, cols, rows: (cols[t], 0, 0)),
+            pl.BlockSpec((1,) + tile, lambda t, idx, cols, rows: (cols[t], 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, bs, k), lambda t, idx, cols, rows: (rows[t], 0, 0)),
+        out_specs=pl.BlockSpec((1,) + tile, lambda t, idx, cols, rows: (rows[t], 0, 0)),
     )
     return pl.pallas_call(
-        _sell_kernel,
+        functools.partial(_sell_kernel, vector=vector),
         grid_spec=grid_spec,
-        out_shape=jax.ShapeDtypeStruct((n_block_rows, bs, k), jnp.float32),
+        out_shape=jax.ShapeDtypeStruct((n_block_rows,) + tile, jnp.float32),
         interpret=interpret,
     )(cell_block, cell_col, cell_row, blocks, x_blocks)

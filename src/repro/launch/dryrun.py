@@ -37,7 +37,7 @@ from ..train.serve_step import make_decode_step, make_prefill_step  # noqa: E402
 from ..train.train_step import make_train_step  # noqa: E402
 from . import sharding as shd  # noqa: E402
 from . import specs as specs_mod  # noqa: E402
-from .mesh import make_production_mesh  # noqa: E402
+from .mesh import auto_mesh, make_production_mesh  # noqa: E402
 
 REPORT_DIR = Path(__file__).resolve().parents[3] / "reports" / "dryrun"
 
@@ -63,7 +63,7 @@ def build_cell(arch: str, shape_name: str, multi_pod: bool,
         n = 1
         for s in mshape:
             n *= s
-        mesh = jax.make_mesh(mshape, maxes, devices=jax.devices()[:n])
+        mesh = auto_mesh(mshape, maxes, jax.devices()[:n])
     else:
         mesh = make_production_mesh(multi_pod=multi_pod)
     model = Model(cfg)

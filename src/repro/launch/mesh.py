@@ -11,6 +11,15 @@ from __future__ import annotations
 from typing import Optional, Sequence
 
 import jax
+from jax.sharding import AxisType
+
+
+def auto_mesh(shape: Sequence[int], axes: Sequence[str], devices: Sequence):
+    """``jax.make_mesh`` with every axis ``Auto``: the partitioner places
+    arrays and ``with_sharding_constraint`` accepts specs over any axis
+    (``Explicit`` axes, the ``make_mesh`` default, refuse such hints)."""
+    return jax.make_mesh(tuple(shape), tuple(axes), devices=devices,
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False,
@@ -22,13 +31,13 @@ def make_production_mesh(*, multi_pod: bool = False,
         for s in shape:
             n *= s
         devices = jax.devices()[:n]
-    return jax.make_mesh(shape, axes, devices=devices)
+    return auto_mesh(shape, axes, devices)
 
 
 def make_debug_mesh(data: int = 1, model: int = 1):
     """Tiny mesh over however many local devices exist (tests)."""
     devices = jax.devices()[: data * model]
-    return jax.make_mesh((data, model), ("data", "model"), devices=devices)
+    return auto_mesh((data, model), ("data", "model"), devices)
 
 
 SHARD_AXIS = "shards"
@@ -46,8 +55,7 @@ def make_shard_mesh(n_shards: int, devices: Optional[Sequence] = None):
     n_shards = int(n_shards)
     if n_shards < 1 or len(devices) < n_shards:
         return None
-    return jax.make_mesh((n_shards,), (SHARD_AXIS,),
-                         devices=devices[:n_shards])
+    return auto_mesh((n_shards,), (SHARD_AXIS,), devices[:n_shards])
 
 
 def dp_axes(mesh) -> tuple:

@@ -79,4 +79,6 @@ def main(argv: Optional[list] = None) -> dict:
 
 
 if __name__ == "__main__":
+    from ..kernels.common import enable_compile_cache
+    enable_compile_cache()
     main()

@@ -49,7 +49,8 @@ def main(argv: Optional[list] = None) -> dict:
     ap.add_argument("--cache-path", default=None,
                     help="persist the schedule cache to this JSON file")
     ap.add_argument("--execute", action="store_true",
-                    help="run the SpMV kernel per request (jnp backend)")
+                    help="run the SpMV kernel per request (Pallas on a "
+                         "TPU, jnp elsewhere)")
     ap.add_argument("--fault-rate", type=float, default=0.0,
                     help="install a deterministic FaultInjector firing at "
                          "this rate across all sites (chaos mode)")
@@ -239,4 +240,6 @@ def main(argv: Optional[list] = None) -> dict:
 
 
 if __name__ == "__main__":
+    from ..kernels.common import enable_compile_cache
+    enable_compile_cache()
     main()

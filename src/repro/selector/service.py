@@ -321,7 +321,7 @@ class SelectorService:
         return Decision(req.name, sched, "shed", 0.0, "", None, batch_id)
 
     # ------------------------------------------------------------- serving
-    def process_pending(self, backend: str = "jnp") -> List[Decision]:
+    def process_pending(self, backend: str = "auto") -> List[Decision]:
         """Drain up to ``batch_max`` requests as one serving tick: decide a
         schedule per request, bucket same-schedule requests together, and run
         the kernel for requests that carried an RHS (one bucket = one
@@ -377,7 +377,7 @@ class SelectorService:
             self.refit(min_examples=self.refit_min_examples)
         return decisions
 
-    def run(self, backend: str = "jnp") -> List[Decision]:
+    def run(self, backend: str = "auto") -> List[Decision]:
         """Process every pending request; returns all decisions."""
         out: List[Decision] = []
         while self.pending:
@@ -385,7 +385,7 @@ class SelectorService:
         return out
 
     def drain_bucket(self, members: List[Tuple[Request, Decision]],
-                     backend: str = "jnp") -> List[Decision]:
+                     backend: str = "auto") -> List[Decision]:
         """Engine-driven drain path (DESIGN.md §13): execute one
         pre-bucketed group of already-decided requests as ONE stacked
         launch, then advance the serving clock.

@@ -63,13 +63,19 @@ class ServingEngine:
                  slot_max: int = 16,
                  deadline_ms: Optional[float] = None,
                  slo_ms: Optional[float] = None,
-                 backend: str = "jnp",
+                 backend: str = "auto",
                  batching: bool = True,
                  clock: Optional[Callable[[], float]] = None,
                  journal=None,
                  checkpointer=None,
-                 checkpoint_every: int = 0) -> None:
+                 checkpoint_every: int = 0,
+                 on_result: Optional[Callable[[str, Optional[np.ndarray]],
+                                              None]] = None) -> None:
         self.service = service
+        # the response path: called once per completed request with its rid
+        # and output (None when the request carried no RHS, or its launch
+        # failed past every retry and fallback)
+        self.on_result = on_result
         self.clock = clock if clock is not None else time.monotonic
         self.queue = BoundedQueue(queue_max, soft_watermark)
         # batching=False is the per-request baseline the serving bench
@@ -224,7 +230,9 @@ class ServingEngine:
                                       backend=self.backend)
         t_done = self.clock()
         reg = self._metrics.registry
-        for er, _, _ in live:
+        for er, _, dec in live:
+            if self.on_result is not None:
+                self.on_result(er.rid, dec.y)
             lat_ms = (t_done - er.t_enqueue) * 1e3
             reg.observe(self._metrics.key("request_ms"), lat_ms)
             self._counts["completed"] += 1

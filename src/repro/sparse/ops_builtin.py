@@ -57,6 +57,7 @@ from .plan import Plan, _bump_trace
 from .prepared import PreparedStore, array_key, bucket_edge, content_key
 from .registry import register_op
 from .resilience import check_fault, dense_ref_cap, register_dense_ref
+from .smem import split_cells, split_rows
 from .tensor import ShardedMeta, ShardedSparseTensor, SparseTensor
 
 MATVEC_LAYOUTS = ("ell", "sell", "dense")
@@ -88,6 +89,22 @@ def _block_x(x: jax.Array, n_cols: int, n_bc: int, bs: int,
     return xb.at[:n_cols].set(x).reshape(n_bc, bs)
 
 
+def _ell_launch(idx, cols, blocks, xb, multi: bool, interpret: bool):
+    """The ELL SpMV/SpMM kernel, split by block-row range to fit SMEM."""
+    kern = bsr_spmm_pallas if multi else bsr_spmv_pallas
+    return split_rows(
+        lambda i, c: kern(i, c, blocks, xb, interpret=interpret), (idx, cols))
+
+
+def _sell_launch(cb, cc, cr, blocks, xb, n_br: int, multi: bool,
+                 interpret: bool):
+    """The SELL SpMV/SpMM kernel, its cell stream split to fit SMEM."""
+    kern = bsr_spmm_sell_pallas if multi else bsr_spmv_sell_pallas
+    return split_cells(
+        lambda b, c, r: kern(b, c, r, blocks, xb, n_br, interpret=interpret),
+        (cb, cc, cr), cr, n_br)
+
+
 @functools.partial(jax.jit, static_argnames=("backend", "rhs_tile"))
 def _exec_matvec(st: SparseTensor, x: jax.Array, backend: str,
                  rhs_tile: int) -> jax.Array:
@@ -109,9 +126,8 @@ def _exec_matvec(st: SparseTensor, x: jax.Array, backend: str,
             y = (ref_bsr_spmm_sell if multi else ref_bsr_spmv_sell)(
                 cb, cc, cr, blocks, xb, n_br)
         else:
-            y = (bsr_spmm_sell_pallas if multi else bsr_spmv_sell_pallas)(
-                cb, cc, cr, blocks, xb, n_br,
-                interpret=(backend == "interpret"))
+            y = _sell_launch(cb, cc, cr, blocks, xb, n_br, multi,
+                             interpret=(backend == "interpret"))
         perm = st.arrays["row_perm"]
         y = jnp.zeros_like(y).at[perm].set(y)
     elif meta.layout == "ell":
@@ -120,8 +136,8 @@ def _exec_matvec(st: SparseTensor, x: jax.Array, backend: str,
         if backend == "jnp":
             y = (ref_bsr_spmm if multi else ref_bsr_spmv)(idx, cols, blocks, xb)
         else:
-            y = (bsr_spmm_pallas if multi else bsr_spmv_pallas)(
-                idx, cols, blocks, xb, interpret=(backend == "interpret"))
+            y = _ell_launch(idx, cols, blocks, xb, multi,
+                            interpret=(backend == "interpret"))
     else:
         raise ValueError(f"spmv/spmm cannot execute layout {meta.layout!r}")
     if multi:
@@ -211,6 +227,13 @@ def _exec_matvec_stacked(arrays, xs: jax.Array, layout: str,
     xb = (xs.reshape(xs.shape[0], n_bc, bs, xs.shape[-1]) if multi
           else xs.reshape(xs.shape[0], n_bc, bs))
     interpret = backend == "interpret"
+    if backend != "jnp":
+        # the kernels index one flat tile stack and one flat RHS: member b's
+        # tables are offset into them, where slicing member b out of the
+        # stack would copy its tiles for every launch
+        nb = arrays["blocks"].shape[1]
+        flat_blocks = arrays["blocks"].reshape((-1, bs, bs))
+        flat_x = xb.reshape((-1,) + xb.shape[2:])
     if layout == "ell":
         if backend == "jnp":
             def one(idx, cols, blocks, x1):
@@ -219,10 +242,10 @@ def _exec_matvec_stacked(arrays, xs: jax.Array, layout: str,
             y = jax.vmap(one)(arrays["block_indices"], arrays["block_cols"],
                               arrays["blocks"], xb)
         else:
-            kern = bsr_spmm_pallas if multi else bsr_spmv_pallas
             y = jnp.stack([
-                kern(arrays["block_indices"][b], arrays["block_cols"][b],
-                     arrays["blocks"][b], xb[b], interpret=interpret)
+                _ell_launch(arrays["block_indices"][b] + b * nb,
+                            arrays["block_cols"][b] + b * n_bc, flat_blocks,
+                            flat_x, multi, interpret)
                 for b in range(xb.shape[0])])
     else:  # sell
         n_br = arrays["row_perm"].shape[1]
@@ -236,12 +259,12 @@ def _exec_matvec_stacked(arrays, xs: jax.Array, layout: str,
                               arrays["cell_row"], arrays["blocks"],
                               arrays["row_perm"], xb)
         else:
-            kern = bsr_spmm_sell_pallas if multi else bsr_spmv_sell_pallas
             outs = []
             for b in range(xb.shape[0]):
-                ys = kern(arrays["cell_block"][b], arrays["cell_col"][b],
-                          arrays["cell_row"][b], arrays["blocks"][b], xb[b],
-                          n_br, interpret=interpret)
+                ys = _sell_launch(arrays["cell_block"][b] + b * nb,
+                                  arrays["cell_col"][b] + b * n_bc,
+                                  arrays["cell_row"][b], flat_blocks, flat_x,
+                                  n_br, multi, interpret)
                 outs.append(jnp.zeros_like(ys).at[arrays["row_perm"][b]]
                             .set(ys))
             y = jnp.stack(outs)
@@ -660,7 +683,7 @@ def _sharded_matvec_exec(mesh, layout: str, multi: bool):
     fn = _SHARDED_EXECS.get(key)
     if fn is not None:
         return fn
-    from jax.experimental.shard_map import shard_map
+    from jax import shard_map
     from ..launch.mesh import SHARD_AXIS
     P = jax.sharding.PartitionSpec
 
@@ -776,6 +799,7 @@ def _plan_matvec_sharded(operands, schedules, backend: str, *, op: str,
 
         built = _cached(store, stack_key, build_stacked)
         arrays, width, layout = built["arrays"], built["width"], built["layout"]
+        operands = (arrays,)
         exec_fn = _sharded_matvec_exec(mesh, layout, False)
         exec_fn_multi = _sharded_matvec_exec(mesh, layout, True)
 
@@ -836,6 +860,7 @@ def _plan_matvec_sharded(operands, schedules, backend: str, *, op: str,
                                host=st._host)
             nst.true_shape = st.true_shape
             placed.append(nst)
+        operands = (ShardedSparseTensor(sst.meta, placed),)
         sub = [_plan_matvec((st,), s, backend, op=op, rhs_tile=rhs_tile)
                for st, s in zip(placed, schedules)]
 
@@ -858,21 +883,45 @@ def _plan_matvec_sharded(operands, schedules, backend: str, *, op: str,
 
     sched = schedules[0] if uniform else None
     return Plan(op=op, schedule=sched, backend=backend, _run=run,
-                operands=(sst,) if sst is not None else (),
-                n_members=n_shards, n_shards=n_shards)
+                operands=operands, n_members=n_shards, n_shards=n_shards)
 
 
 # ---------------------------------------------------------------------------
 # spgemm — padded pairs ("ell") or flattened cells ("sell" layout axis)
 # ---------------------------------------------------------------------------
 
+def _spgemm_pairs_launch(pair_a, pair_b, a_blocks, b_blocks,
+                         interpret: bool):
+    """The padded-pairs spgemm kernel, split by output block range."""
+    return split_rows(
+        lambda pa, pb: bsr_spgemm_pallas(pa, pb, a_blocks, b_blocks,
+                                         interpret=interpret),
+        (pair_a, pair_b))
+
+
+def _spgemm_cells_launch(cell_a, cell_b, cell_c, a_blocks, b_blocks,
+                         n_c: int, interpret: bool):
+    """The flat-cells spgemm kernel, its cell stream split to fit SMEM."""
+    return split_cells(
+        lambda ca, cb, cc: bsr_spgemm_cells_pallas(
+            ca, cb, cc, a_blocks, b_blocks, n_c, interpret=interpret),
+        (cell_a, cell_b, cell_c), cell_c, n_c)
+
+
+def _spadd_launch(ia, ib, a_blocks, b_blocks, interpret: bool):
+    """The spadd gather-add kernel, split by output block range."""
+    return split_rows(
+        lambda i1, i2: bsr_spadd_pallas(i1, i2, a_blocks, b_blocks,
+                                        interpret=interpret), (ia, ib))
+
+
 @functools.partial(jax.jit, static_argnames=("backend",))
 def _exec_spgemm_pairs(pair_a, pair_b, a_blocks, b_blocks, backend: str):
     _bump_trace("spgemm_pairs")
     if backend == "jnp":
         return ref_pair_gemm(pair_a, pair_b, a_blocks, b_blocks)
-    return bsr_spgemm_pallas(pair_a, pair_b, a_blocks, b_blocks,
-                             interpret=(backend == "interpret"))
+    return _spgemm_pairs_launch(pair_a, pair_b, a_blocks, b_blocks,
+                                interpret=(backend == "interpret"))
 
 
 @functools.partial(jax.jit, static_argnames=("n_c", "backend"))
@@ -881,8 +930,8 @@ def _exec_spgemm_cells(cell_a, cell_b, cell_c, a_blocks, b_blocks, n_c: int,
     _bump_trace("spgemm_cells")
     if backend == "jnp":
         return ref_cell_gemm(cell_a, cell_b, cell_c, a_blocks, b_blocks, n_c)
-    return bsr_spgemm_cells_pallas(cell_a, cell_b, cell_c, a_blocks, b_blocks,
-                                   n_c, interpret=(backend == "interpret"))
+    return _spgemm_cells_launch(cell_a, cell_b, cell_c, a_blocks, b_blocks,
+                                n_c, interpret=(backend == "interpret"))
 
 
 def _with_zero_block(blocks: np.ndarray, bs: int) -> np.ndarray:
@@ -1048,8 +1097,8 @@ def _exec_spgemm_stacked(pair_a, pair_b, a_blocks, b_blocks, backend: str):
         return jax.vmap(one)(pair_a, pair_b, a_blocks, b_blocks)
     interpret = backend == "interpret"
     return jnp.stack([
-        bsr_spgemm_pallas(pair_a[i], pair_b[i], a_blocks[i], b_blocks[i],
-                          interpret=interpret)
+        _spgemm_pairs_launch(pair_a[i], pair_b[i], a_blocks[i], b_blocks[i],
+                             interpret)
         for i in range(pair_a.shape[0])])
 
 
@@ -1065,8 +1114,8 @@ def _exec_spgemm_cells_stacked(cell_a, cell_b, cell_c, a_blocks, b_blocks,
         return jax.vmap(one)(cell_a, cell_b, cell_c, a_blocks, b_blocks)
     interpret = backend == "interpret"
     return jnp.stack([
-        bsr_spgemm_cells_pallas(cell_a[i], cell_b[i], cell_c[i], a_blocks[i],
-                                b_blocks[i], n_c, interpret=interpret)
+        _spgemm_cells_launch(cell_a[i], cell_b[i], cell_c[i], a_blocks[i],
+                             b_blocks[i], n_c, interpret)
         for i in range(cell_a.shape[0])])
 
 
@@ -1079,8 +1128,7 @@ def _exec_spadd_stacked(ia, ib, a_blocks, b_blocks, backend: str):
             ia, ib, a_blocks, b_blocks)
     interpret = backend == "interpret"
     return jnp.stack([
-        bsr_spadd_pallas(ia[i], ib[i], a_blocks[i], b_blocks[i],
-                         interpret=interpret)
+        _spadd_launch(ia[i], ib[i], a_blocks[i], b_blocks[i], interpret)
         for i in range(ia.shape[0])])
 
 
@@ -1185,8 +1233,8 @@ def _exec_spadd(ia, ib, a_blocks, b_blocks, backend: str):
     _bump_trace("spadd")
     if backend == "jnp":
         return ref_block_union_add(ia, ib, a_blocks, b_blocks)
-    return bsr_spadd_pallas(ia, ib, a_blocks, b_blocks,
-                            interpret=(backend == "interpret"))
+    return _spadd_launch(ia, ib, a_blocks, b_blocks,
+                         interpret=(backend == "interpret"))
 
 
 def _spadd_host_products(a, b, schedule: Schedule):

@@ -8,8 +8,9 @@ loop. This module brings the supervisor posture of
 ``train/fault_tolerance.py`` to the serving side (DESIGN.md §11):
 
 * ``GuardedExecutor`` + ``guard_plan`` — every ``Plan`` launch runs through
-  an ordered backend fallback chain (pallas → interpret → jnp → dense
-  reference). A failed or NaN/Inf launch drops one rung, the failing
+  an ordered backend fallback chain (pallas → jnp → dense reference; a
+  plan built for the Pallas interpreter walks interpret → jnp → dense).
+  A failed or NaN/Inf launch drops one rung, the failing
   ``(op, backend, schedule)`` combo enters the ``Quarantine``, and the
   caller still gets a correct answer.
 * ``Quarantine`` — records poisoned combos so the selector and tuner never
@@ -54,8 +55,11 @@ from ..obs import trace as obs_trace
 
 # Ordered fallback ladder. A guarded launch starts at its plan's backend and
 # only ever moves right; "dense" is the per-op numpy reference of last
-# resort (registered via register_dense_ref), not a Schedule backend.
-FALLBACK_CHAIN = ("pallas", "interpret", "jnp", "dense")
+# resort (registered via register_dense_ref), not a Schedule backend. The
+# Pallas interpreter is no rung: a compiled kernel that fails on the device
+# must show as a fallback to jnp, never be served by the interpreter. Only
+# a plan built for "interpret" (tests, CPU checks) starts there.
+FALLBACK_CHAIN = ("pallas", "jnp", "dense")
 
 # Named injection sites a FaultInjector can fire at. The two mutation
 # sites (DESIGN.md §14): ``delta-apply`` fires inside the value-only device
@@ -522,6 +526,8 @@ class GuardedExecutor:
     def chain_from(self, backend: str, has_dense: bool) -> List[str]:
         if backend in FALLBACK_CHAIN:
             chain = list(FALLBACK_CHAIN[FALLBACK_CHAIN.index(backend):])
+        elif backend == "interpret":
+            chain = [backend] + list(FALLBACK_CHAIN[1:])
         else:
             chain = [backend, "dense"]
         if not has_dense:

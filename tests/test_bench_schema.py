@@ -49,3 +49,23 @@ def test_run_json_golden_schema(tmp_path):
     for name, rec in data.items():
         assert name in csv_by_name
         assert rec["us"] == pytest.approx(csv_by_name[name], abs=1.0)
+
+
+def test_run_exits_nonzero_when_a_module_raises(monkeypatch, capsys):
+    """A raising module keeps its ERROR row, the others still run, and the
+    harness exits non-zero."""
+    import types
+    from benchmarks import run
+
+    def boom():
+        raise RuntimeError("kernel refused")
+
+    ok = types.SimpleNamespace(run=lambda: [("ok/row", 1.0, "-")])
+    monkeypatch.setattr(run, "MODULES", [
+        ("bad", types.SimpleNamespace(run=boom)), ("good", ok)])
+    with pytest.raises(SystemExit) as exc:
+        run.main([])
+    assert exc.value.code not in (0, None)
+    out = capsys.readouterr().out
+    assert "bad/ERROR,0.0,RuntimeError:kernel refused" in out
+    assert "ok/row,1.0,-" in out

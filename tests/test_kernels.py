@@ -290,3 +290,114 @@ def test_bsr_spmv_dtypes(dtype, tol):
     scale = max(np.abs(ref).max(), 1e-6)
     np.testing.assert_allclose(y.reshape(-1)[:128] / scale, ref / scale,
                                rtol=tol, atol=tol)
+
+
+# ------------------------------------------------- SMEM-split launches
+# A tiny budget forces many launches on small operands: 8 rows of a 2-D
+# table (or 8 cells of three 1-D streams) per launch.
+TINY_2D = 2 * 4 * 128 * 8
+TINY_CELLS = 3 * 4 * 8
+
+
+def test_smem_budget_math():
+    """Table costs match what the v5e compiler accepts (two (1000, 16)
+    int32 tables fit its 1 MiB SMEM, two (1024, 16) do not), and every
+    range ``row_ranges`` cuts fits the budget and tiles the rows."""
+    from repro.sparse.smem import SMEM_BUDGET_BYTES, row_ranges, table_bytes
+    assert 2 * table_bytes((1000, 16)) < 1 << 20 <= 2 * table_bytes((1024, 16))
+    for shapes in ([(2048, 12)] * 2, [(196608,)] * 3, [(10, 3000)] * 2,
+                   [(6144, 12)] * 2, [(5,)] * 3):
+        rr = row_ranges(shapes[0][0], shapes)
+        assert rr[0][0] == 0 and rr[-1][1] == shapes[0][0]
+        assert all(a[1] == b[0] for a, b in zip(rr, rr[1:]))
+        for lo, hi in rr:
+            assert sum(table_bytes((hi - lo,) + tuple(s[1:]))
+                       for s in shapes) <= SMEM_BUDGET_BYTES
+
+
+@pytest.mark.parametrize("layout", ["ell", "sell"])
+@pytest.mark.parametrize("k", [None, 3])
+def test_split_matvec_launch_matches_oracle(layout, k):
+    """ELL split by block-row range and SELL split mid-row give the
+    unsplit product (Pallas interpreter)."""
+    from repro.kernels.bsr_spmv import kernel as K
+    from repro.sparse.smem import row_ranges, split_cells, split_rows
+    n, bs = 200, 8
+    csr = _sparse(n, n, 0.1, 21)
+    X = RNG.standard_normal((n,) if k is None else (n, k)).astype(np.float32)
+    n_bc = -(-n // bs)
+    xp = np.zeros((n_bc * bs,) + X.shape[1:], np.float32)
+    xp[:n] = X
+    xb = jnp.asarray(xp.reshape((n_bc, bs) + X.shape[1:]))
+    if layout == "ell":
+        a = bsr_spmv.ops.prepare(csr, bs)
+        kern = K.bsr_spmv_pallas if k is None else K.bsr_spmm_pallas
+        tables = (jnp.asarray(a.block_indices), jnp.asarray(a.block_cols))
+        blocks = jnp.asarray(a.blocks)
+        y = split_rows(lambda i, c: kern(i, c, blocks, xb, interpret=True),
+                       tables, budget=TINY_2D)
+        budget = TINY_2D
+    else:
+        a = bsr_spmv.ops.prepare_sell(csr, bs, 4, 16)
+        kern = K.bsr_spmv_sell_pallas if k is None else K.bsr_spmm_sell_pallas
+        tables = (jnp.asarray(a.cell_block), jnp.asarray(a.cell_col),
+                  jnp.asarray(a.cell_row))
+        blocks = jnp.asarray(a.blocks)
+        y = split_cells(
+            lambda b, c, r: kern(b, c, r, blocks, xb, a.n_block_rows,
+                                 interpret=True),
+            tables, tables[2], a.n_block_rows, budget=TINY_CELLS)
+        y = jnp.zeros_like(y).at[jnp.asarray(a.row_perm)].set(y)
+        # cuts every 8 cells land inside multi-cell rows
+        assert (np.diff(a.cell_row)[7::8] == 0).any()
+        budget = TINY_CELLS
+    assert len(row_ranges(tables[0].shape[0], [t.shape for t in tables],
+                          budget)) > 2
+    y = np.asarray(y).reshape((-1,) + X.shape[1:])[:n]
+    ref = (bsr_spmv.ops.spmv_oracle(csr, X) if k is None
+           else bsr_spmv.ops.spmm_oracle(csr, X))
+    np.testing.assert_allclose(y, ref, rtol=1e-4, atol=1e-4)
+
+
+def test_split_spgemm_cells_matches_reference():
+    """The Gustavson cell stream cut every 8 cells (mid output block)."""
+    from repro.core import BSR
+    from repro.kernels.bsr_spgemm.kernel import bsr_spgemm_cells_pallas
+    from repro.kernels.bsr_spgemm.ops import spgemm_symbolic_cells
+    from repro.kernels.bsr_spgemm.ref import ref_cell_gemm
+    from repro.sparse.smem import split_cells
+    a, b_ = _sparse(64, 64, 0.15, 31), _sparse(64, 64, 0.15, 32)
+    ba, bb = BSR.from_csr(a, 8), BSR.from_csr(b_, 8)
+    _, c_cols, ca, cb, cc = spgemm_symbolic_cells(ba, bb)
+    n_c = int(c_cols.size)
+    tables = tuple(jnp.asarray(t) for t in (ca, cb, cc))
+    ab, bbk = jnp.asarray(ba.blocks), jnp.asarray(bb.blocks)
+    got = split_cells(
+        lambda x, y, z: bsr_spgemm_cells_pallas(x, y, z, ab, bbk, n_c,
+                                                interpret=True),
+        tables, tables[2], n_c, budget=TINY_CELLS)
+    want = ref_cell_gemm(*tables, ab, bbk, n_c)
+    assert ca.size > 24
+    np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                               rtol=1e-4, atol=1e-4)
+
+
+def test_compile_cache_honours_env_else_checkout(monkeypatch):
+    """``JAX_COMPILATION_CACHE_DIR`` set: nothing is set in code. Unset:
+    the cache goes to ``.jax_cache`` at the checkout root."""
+    from pathlib import Path
+
+    import jax
+    from repro.kernels.common import enable_compile_cache
+    before = jax.config.jax_compilation_cache_dir
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", "/elsewhere/cache")
+    assert enable_compile_cache() == "/elsewhere/cache"
+    assert jax.config.jax_compilation_cache_dir == before
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    try:
+        path = enable_compile_cache()
+        root = Path(__file__).resolve().parents[1]
+        assert path == str(root / ".jax_cache")
+        assert jax.config.jax_compilation_cache_dir == path
+    finally:
+        jax.config.update("jax_compilation_cache_dir", before)

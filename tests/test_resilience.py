@@ -92,6 +92,23 @@ def _clean_and_faulted(op, operands, runtime, schedule=None, **kw):
     return clean, faulted
 
 
+@pytest.mark.parametrize("backend,rungs", [
+    ("pallas", ["pallas", "jnp", "dense"]),
+    ("interpret", ["interpret", "jnp", "dense"])])
+def test_pallas_ladder_never_steps_to_interpret(backend, rungs):
+    """A compiled launch that fails falls to jnp, never to the Pallas
+    interpreter; only a plan built for interpret starts there."""
+    assert GuardedExecutor().chain_from(backend, True) == rungs
+    A = _sparse(64, 64, 0.1, 2)
+    x = np.ones(64, np.float32)
+    install_injector(FaultInjector(1.0, seed=0, sites=("launch",)))
+    p = plan("spmv", A, backend=backend)
+    p.execute(x)
+    assert p.backend == "dense"
+    assert {e["backend"] for e in default_quarantine().entries()} \
+        == set(rungs[:2])
+
+
 def test_fallback_chain_spmv_spmm_match_reference():
     A = _sparse(96, 80, 0.08, 0)
     x = np.random.default_rng(1).standard_normal(80).astype(np.float32)

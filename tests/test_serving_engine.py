@@ -106,6 +106,26 @@ def test_fused_same_content_bucket_matches_per_request_results():
         np.testing.assert_allclose(np.asarray(y), yr, rtol=2e-5, atol=2e-5)
 
 
+def test_on_result_delivers_every_output(tuner, population, rhs):
+    """Each completed request's output reaches the caller once, batched
+    or not, and equals A @ x."""
+    got = {}
+    engine = _engine(tuner, slot_max=4,
+                     on_result=lambda rid, y: got.setdefault(rid, []).append(y))
+    want = {}
+    for j in range(7):
+        t = j % len(population)
+        _, A = population[t]
+        want[f"o{j}"] = A.to_dense() @ rhs[t]
+        engine.submit(f"o{j}", A, rhs[t], tenant=t, rid=f"o{j}")
+    engine.drain_all()
+    assert sorted(got) == sorted(want) and all(len(v) == 1
+                                               for v in got.values())
+    assert engine.telemetry()["multi_request_drains"] >= 1
+    for rid, ys in got.items():
+        np.testing.assert_allclose(ys[0], want[rid], rtol=1e-4, atol=1e-4)
+
+
 # ------------------------------------------------------- deadline shedding
 
 def test_deadline_expired_requests_shed_not_executed(tuner, population, rhs):
