@@ -25,6 +25,9 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+# f32 tile products: the MXU's default pass rounds f32 operands to bf16
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _spgemm_cells_kernel(ca_ref, cb_ref, cc_ref, a_ref, b_ref, c_ref):
     del ca_ref, cb_ref  # consumed by the index maps
@@ -36,8 +39,8 @@ def _spgemm_cells_kernel(ca_ref, cb_ref, cc_ref, a_ref, b_ref, c_ref):
         c_ref[...] = jnp.zeros_like(c_ref)
 
     c_ref[...] += jnp.dot(
-        a_ref[0], b_ref[0], preferred_element_type=jnp.float32
-    )[None]
+        a_ref[0], b_ref[0], precision=HIGHEST,
+        preferred_element_type=jnp.float32)[None]
 
 
 @functools.partial(jax.jit, static_argnames=("n_c_blocks", "interpret"))
@@ -88,8 +91,8 @@ def _spgemm_kernel(pa_ref, pb_ref, a_ref, b_ref, c_ref):
         c_ref[...] = jnp.zeros_like(c_ref)
 
     c_ref[...] += jnp.dot(
-        a_ref[0], b_ref[0], preferred_element_type=jnp.float32
-    )[None]
+        a_ref[0], b_ref[0], precision=HIGHEST,
+        preferred_element_type=jnp.float32)[None]
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))

@@ -51,15 +51,21 @@ import jax.numpy as jnp
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
+HIGHEST = jax.lax.Precision.HIGHEST
+
 
 def _tile_product(blk_ref, x_ref, vector: bool):
     """One cell's product: (bs, bs) @ (bs, k) for SpMM, and for SpMV the
-    (1, bs) vector row times the tile transposed, (1, bs) . (bs, bs)^T."""
+    (1, bs) vector row times the tile transposed, (1, bs) . (bs, bs)^T.
+
+    HIGHEST precision: the MXU's default f32 pass rounds both operands to
+    bf16 (~2^-8 relative error); the f32 API and ``ref.py`` promise f32."""
     if vector:
         return jax.lax.dot_general(
             x_ref[0], blk_ref[0], (((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32)
-    return jnp.dot(blk_ref[0], x_ref[0], preferred_element_type=jnp.float32)
+            precision=HIGHEST, preferred_element_type=jnp.float32)
+    return jnp.dot(blk_ref[0], x_ref[0], precision=HIGHEST,
+                   preferred_element_type=jnp.float32)
 
 
 def _ell_kernel(idx_ref, cols_ref, blk_ref, x_ref, y_ref, *, vector):

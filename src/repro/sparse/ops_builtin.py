@@ -799,7 +799,10 @@ def _plan_matvec_sharded(operands, schedules, backend: str, *, op: str,
 
         built = _cached(store, stack_key, build_stacked)
         arrays, width, layout = built["arrays"], built["width"], built["layout"]
-        operands = (arrays,)
+        homes = [set() for _ in range(n_shards)]
+        for v in arrays.values():
+            for piece in v.addressable_shards:
+                homes[piece.index[0].start or 0].add(piece.device)
         exec_fn = _sharded_matvec_exec(mesh, layout, False)
         exec_fn_multi = _sharded_matvec_exec(mesh, layout, True)
 
@@ -860,7 +863,8 @@ def _plan_matvec_sharded(operands, schedules, backend: str, *, op: str,
                                host=st._host)
             nst.true_shape = st.true_shape
             placed.append(nst)
-        operands = (ShardedSparseTensor(sst.meta, placed),)
+        homes = [{d for v in st.arrays.values() for d in v.devices()}
+                 for st in placed]
         sub = [_plan_matvec((st,), s, backend, op=op, rhs_tile=rhs_tile)
                for st, s in zip(placed, schedules)]
 
@@ -883,7 +887,9 @@ def _plan_matvec_sharded(operands, schedules, backend: str, *, op: str,
 
     sched = schedules[0] if uniform else None
     return Plan(op=op, schedule=sched, backend=backend, _run=run,
-                operands=operands, n_members=n_shards, n_shards=n_shards)
+                operands=(sst,) if sst is not None else (),
+                n_members=n_shards, n_shards=n_shards,
+                shard_devices=[frozenset(h) for h in homes])
 
 
 # ---------------------------------------------------------------------------

@@ -86,6 +86,9 @@ class Plan:
     # with source / fingerprint_key / schedule — the acceptance-level record
     # that each shard's schedule went through the selector independently
     shard_provenance: Optional[List[Dict]] = None
+    # sharded plans: the set of devices holding each shard's arrays, read
+    # from the placed arrays themselves (one device per shard when placed)
+    shard_devices: Optional[List[frozenset]] = None
     # wall-clock of the most recent execute (set per call). With the NaN
     # guard on (default) the guarded run synchronizes on the result, so
     # this is end-to-end launch latency, not dispatch-only.

@@ -49,18 +49,24 @@ def table_bytes(shape: Sequence[int]) -> int:
     return 4 * _round_up(rows, 8) * _round_up(cols, 128)
 
 
+def launch_bytes(shapes: Sequence[Sequence[int]], rows: int) -> int:
+    """SMEM bytes of the tables of ``shapes`` with their shared leading
+    axis cut to ``rows``."""
+    return sum(table_bytes((rows,) + tuple(s[1:])) for s in shapes)
+
+
 def rows_per_launch(shapes: Sequence[Sequence[int]],
                     budget: int = SMEM_BUDGET_BYTES) -> int:
     """Most leading-axis rows one launch may take so that the tables of
-    ``shapes`` (sharing that axis) fit ``budget``: a multiple of 128 where
-    one fits, else of 8, and at least 1."""
-    per_row = sum(4 * (_round_up(s[1], 128) if len(s) == 2 else 1)
-                  for s in shapes)
-    n = budget // per_row
+    ``shapes`` (sharing that axis) fit ``budget``: the largest multiple of
+    128 whose ``launch_bytes`` fit, else a multiple of 8 that fits, and at
+    least 1. ``launch_bytes`` is linear over such multiples (1-D tables
+    round 8 rows up to 128, so the 8-row step only errs low)."""
     for q in (128, 8):
-        if n >= q:
-            return n // q * q
-    return max(n, 1)
+        n = budget // launch_bytes(shapes, q) * q
+        if n:
+            return n
+    return 1
 
 
 def row_ranges(n_rows: int, shapes: Sequence[Sequence[int]],

@@ -293,17 +293,18 @@ def test_bsr_spmv_dtypes(dtype, tol):
 
 
 # ------------------------------------------------- SMEM-split launches
-# A tiny budget forces many launches on small operands: 8 rows of a 2-D
-# table (or 8 cells of three 1-D streams) per launch.
+# A tiny budget forces many launches on small operands: 8 rows of two 2-D
+# tables (or 128 cells of three 1-D streams, SMEM's 1-D granule) a launch.
 TINY_2D = 2 * 4 * 128 * 8
-TINY_CELLS = 3 * 4 * 8
+TINY_CELLS = 3 * 4 * 128
 
 
 def test_smem_budget_math():
     """Table costs match what the v5e compiler accepts (two (1000, 16)
     int32 tables fit its 1 MiB SMEM, two (1024, 16) do not), and every
     range ``row_ranges`` cuts fits the budget and tiles the rows."""
-    from repro.sparse.smem import SMEM_BUDGET_BYTES, row_ranges, table_bytes
+    from repro.sparse.smem import (SMEM_BUDGET_BYTES, launch_bytes,
+                                   row_ranges, table_bytes)
     assert 2 * table_bytes((1000, 16)) < 1 << 20 <= 2 * table_bytes((1024, 16))
     for shapes in ([(2048, 12)] * 2, [(196608,)] * 3, [(10, 3000)] * 2,
                    [(6144, 12)] * 2, [(5,)] * 3):
@@ -311,8 +312,7 @@ def test_smem_budget_math():
         assert rr[0][0] == 0 and rr[-1][1] == shapes[0][0]
         assert all(a[1] == b[0] for a, b in zip(rr, rr[1:]))
         for lo, hi in rr:
-            assert sum(table_bytes((hi - lo,) + tuple(s[1:]))
-                       for s in shapes) <= SMEM_BUDGET_BYTES
+            assert launch_bytes(shapes, hi - lo) <= SMEM_BUDGET_BYTES
 
 
 @pytest.mark.parametrize("layout", ["ell", "sell"])
@@ -348,8 +348,8 @@ def test_split_matvec_launch_matches_oracle(layout, k):
                                  interpret=True),
             tables, tables[2], a.n_block_rows, budget=TINY_CELLS)
         y = jnp.zeros_like(y).at[jnp.asarray(a.row_perm)].set(y)
-        # cuts every 8 cells land inside multi-cell rows
-        assert (np.diff(a.cell_row)[7::8] == 0).any()
+        # cuts every 128 cells land inside multi-cell rows
+        assert (np.diff(a.cell_row)[127::128] == 0).any()
         budget = TINY_CELLS
     assert len(row_ranges(tables[0].shape[0], [t.shape for t in tables],
                           budget)) > 2
@@ -360,13 +360,15 @@ def test_split_matvec_launch_matches_oracle(layout, k):
 
 
 def test_split_spgemm_cells_matches_reference():
-    """The Gustavson cell stream cut every 8 cells (mid output block)."""
+    """The Gustavson cell stream cut every 128 cells (mid output block)."""
     from repro.core import BSR
     from repro.kernels.bsr_spgemm.kernel import bsr_spgemm_cells_pallas
     from repro.kernels.bsr_spgemm.ops import spgemm_symbolic_cells
     from repro.kernels.bsr_spgemm.ref import ref_cell_gemm
     from repro.sparse.smem import split_cells
-    a, b_ = _sparse(64, 64, 0.15, 31), _sparse(64, 64, 0.15, 32)
+    # 9 block-rows x 9 block-cols: 9 cells per C block, so cuts every 128
+    # cells land mid-block
+    a, b_ = _sparse(72, 72, 0.15, 31), _sparse(72, 72, 0.15, 32)
     ba, bb = BSR.from_csr(a, 8), BSR.from_csr(b_, 8)
     _, c_cols, ca, cb, cc = spgemm_symbolic_cells(ba, bb)
     n_c = int(c_cols.size)
@@ -377,7 +379,7 @@ def test_split_spgemm_cells_matches_reference():
                                                 interpret=True),
         tables, tables[2], n_c, budget=TINY_CELLS)
     want = ref_cell_gemm(*tables, ab, bbk, n_c)
-    assert ca.size > 24
+    assert ca.size > 2 * 128 and (np.diff(cc)[127::128] == 0).any()
     np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                rtol=1e-4, atol=1e-4)
 

@@ -132,6 +132,18 @@ def test_plan_sharded_spmm_matches_single_device(zipf, n_shards):
     np.testing.assert_allclose(Y_sharded, Y_single, rtol=2e-4, atol=2e-4)
 
 
+@pytest.mark.parametrize("n_shards", [1, 2, 4])
+def test_plan_sharded_reports_shard_devices(zipf, n_shards):
+    """``Plan.shard_devices`` is read from the placed arrays: one device
+    per shard, round-robin over the local devices (the shard_map path,
+    taken when enough devices exist, puts slot i on device i)."""
+    p = plan_sharded("spmv", (zipf,), n_shards=n_shards,
+                     schedule=Schedule("bsr", 32, 1.0), backend="jnp")
+    devs = jax.devices()
+    assert p.shard_devices == [frozenset({devs[i % len(devs)]})
+                               for i in range(n_shards)]
+
+
 def test_plan_sharded_heterogeneous_schedules(zipf):
     """Per-shard schedules may disagree (the skewed-matrix case the
     selector produces); the fallback path still matches the dense oracle."""
