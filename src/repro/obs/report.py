@@ -78,7 +78,7 @@ def summarize(launches: List[Dict]) -> Dict[str, Dict[str, float]]:
             "launches": float(len(pairs)),
             "measured_gm_ms": gm([m for m, _ in pairs]),
             "modeled_gm_ms": gm([p for _, p in pairs]),
-            "residual_log10": mean_resid,
+            "mean_log10_residual": mean_resid,
             "calibration_scale": scale,
             "calibrated_mape": mape,
         }
@@ -104,7 +104,7 @@ def main(argv: Optional[List[str]] = None) -> Dict[str, Dict[str, float]]:
             print(f"{key:36s} {row['launches']:5.0f} "
                   f"{row['measured_gm_ms']:9.3f} "
                   f"{row['modeled_gm_ms']:9.3f} "
-                  f"{row['residual_log10']:+7.2f} "
+                  f"{row['mean_log10_residual']:+7.2f} "
                   f"{row['calibration_scale']:9.2f} "
                   f"{row['calibrated_mape']:6.2f}")
     if args.json_out:

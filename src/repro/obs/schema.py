@@ -50,11 +50,27 @@ from typing import Dict, Mapping, Tuple
 #   recovery    one incarnation finished restore+replay: how many journal
 #               records were replayed and how many artifacts were dropped
 #               as corrupt on the way
+#
+# Layer spans inside the calls above (DESIGN.md §12): each splits a host
+# cost that the enclosing span cannot tell apart, so a profiler trace can
+# charge the device's idle time to the work that held it up.
+#   hash        content_key's sha1 over the CSR bytes, inside select
+#   fingerprint the static features of a matrix the memo has not seen
+#   admission   one engine tick's queue pops, selects and slot assignment
+#               (only a tick that finds the queue non-empty opens one)
+#   drain_plan  plan_bucket for a drain: store lookup and guarded build
+#   drain_stack the host stack, pad and upload of a bucket's RHS vectors
+#   drain_fetch the device-to-host copies of a drain's answers
+#   drain_answer the on_result calls and bookkeeping after a drain
+#   dispatch    inside a guarded launch: eager pads and slices, jit dispatch
+#   finite_check the NaN/Inf guard's isfinite ops and the host's wait
 EVENT_TYPES: Tuple[str, ...] = (
     "select", "prep", "compile", "launch", "fallback", "quarantine",
     "shed", "store_evict", "enqueue", "admit", "drain",
     "mutate", "epoch_swap", "drift",
     "checkpoint", "restart", "recovery",
+    "hash", "fingerprint", "admission", "drain_plan", "drain_stack",
+    "drain_fetch", "drain_answer", "dispatch", "finite_check",
 )
 
 # Required ``args`` fields per event type — the golden-schema contract a
@@ -78,6 +94,15 @@ EVENT_FIELDS: Dict[str, Tuple[str, ...]] = {
     "checkpoint": ("tick", "outcome"),
     "restart": ("attempt", "reason"),
     "recovery": ("replayed", "dropped_corrupt"),
+    "hash": (),
+    "fingerprint": (),
+    "admission": ("admitted",),
+    "drain_plan": ("n_members",),
+    "drain_stack": ("n_members",),
+    "drain_fetch": ("n_members",),
+    "drain_answer": ("n_requests",),
+    "dispatch": ("op", "backend"),
+    "finite_check": ("op", "backend"),
 }
 
 # Telemetry keys are flat snake_case identifiers: lowercase alphanumerics

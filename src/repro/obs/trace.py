@@ -17,10 +17,18 @@ per-event counts reconcile exactly with the registry snapshot" a provable
 identity rather than a hope. All mutation happens under one lock; emitting
 from many threads is safe (each event carries its ``tid``).
 
+Every span of the taxonomy is also a ``jax.profiler.TraceAnnotation``
+named ``repro.<type>`` (``PROFILER_NAMES``), opened whether or not a tracer
+is installed: under a profiler session the program's spans land in the
+``.xplane.pb`` beside the device's operations, on one clock; with no session
+the annotation costs under a microsecond. The name is a constant and the
+annotation takes no arguments, which the profiler would format into the
+event name on every call. Instants (``emit``) stay tracer-only.
+
 The process-wide installed tracer mirrors the FaultInjector pattern:
-``install_tracer(t)`` turns instrumentation on, ``install_tracer(None)``
-returns every ``emit``/``span`` call site to a no-op — the zero-overhead
-production default.
+``install_tracer(t)`` turns recording on, ``install_tracer(None)`` returns
+every ``emit`` call site to a no-op and every ``span`` to its profiler
+annotation alone — the production default.
 """
 from __future__ import annotations
 
@@ -33,6 +41,20 @@ from typing import Any, Callable, Dict, Iterator, List, Optional
 
 from .metrics import MetricsRegistry, default_registry
 from .schema import EVENT_TYPES
+
+# span type -> the profiler annotation's name, one constant string per type
+PROFILER_NAMES: Dict[str, str] = {t: f"repro.{t}" for t in EVENT_TYPES}
+
+
+def _annotation(type_: str):
+    """The profiler span of ``type_``; types outside the taxonomy (a
+    non-strict tracer's own categories) get none. JAX is imported on the
+    first span, so the pure-JSONL readers of this package never load it."""
+    name = PROFILER_NAMES.get(type_)
+    if not name:
+        return contextlib.nullcontext()
+    from jax.profiler import TraceAnnotation
+    return TraceAnnotation(name)
 
 
 class Tracer:
@@ -79,16 +101,18 @@ class Tracer:
     @contextlib.contextmanager
     def span(self, type_: str, name: str = "",
              **args: Any) -> Iterator[Dict[str, Any]]:
-        """Timed span; the yielded dict is live — fields added inside the
-        ``with`` body (a decision source, a measured cost) are recorded."""
+        """Timed span, also written to the profiler's trace; the yielded
+        dict is live — fields added inside the ``with`` body (a decision
+        source, a measured cost) are recorded."""
         fields: Dict[str, Any] = dict(args)
-        t0 = self._now_us()
-        try:
-            yield fields
-        finally:
-            t1 = self._now_us()
-            self._record(type_, name, t0, t1 - t0, fields)
-            self.registry.observe(f"span_ms.{type_}", (t1 - t0) / 1e3)
+        with _annotation(type_):
+            t0 = self._now_us()
+            try:
+                yield fields
+            finally:
+                t1 = self._now_us()
+                self._record(type_, name, t0, t1 - t0, fields)
+                self.registry.observe(f"span_ms.{type_}", (t1 - t0) / 1e3)
 
     def instant(self, type_: str, name: str = "", **args: Any) -> Dict:
         """Zero-duration event (quarantine entries, evictions, sheds)."""
@@ -174,10 +198,12 @@ def emit(type_: str, name: str = "", **args: Any) -> None:
 @contextlib.contextmanager
 def span(type_: str, name: str = "",
          **args: Any) -> Iterator[Dict[str, Any]]:
-    """Span through the installed tracer; without one, yields a throwaway
-    fields dict so call sites never branch."""
+    """Span through the installed tracer; without one, the profiler
+    annotation alone, yielding a throwaway fields dict so call sites never
+    branch."""
     if _TRACER is None:
-        yield dict(args)
+        with _annotation(type_):
+            yield dict(args)
         return
     with _TRACER.span(type_, name, **args) as fields:
         yield fields

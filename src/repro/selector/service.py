@@ -231,13 +231,15 @@ class SelectorService:
 
     def _fingerprint(self, req: Request) -> Fingerprint:
         from ..sparse.prepared import content_key
-        req.ck = content_key(req.csr)
+        with obs_trace.span("hash", req.name):
+            req.ck = content_key(req.csr)
         fp = self._fp_memo.get(req.ck)
         if fp is not None:
             self._fp_memo.move_to_end(req.ck)
             self._counts["fp_memo_hits"] += 1
             return fp
-        fp = fingerprint(req.csr)
+        with obs_trace.span("fingerprint", req.name):
+            fp = fingerprint(req.csr)
         self._fp_memo[req.ck] = fp
         while len(self._fp_memo) > self._fp_memo_cap:
             self._fp_memo.popitem(last=False)
@@ -458,11 +460,12 @@ class SelectorService:
             mks = [req.ck for req, _ in grp]
 
             def attempt(grp=grp, mks=mks):
-                bucket_plan = plan_bucket(
-                    "spmv", [req.csr for req, _ in grp],
-                    grp[0][1].schedule, backend=backend,
-                    store=self.prepared_store, executor=self.executor,
-                    member_keys=(mks if all(mks) else None))
+                with obs_trace.span("drain_plan", n_members=len(grp)):
+                    bucket_plan = plan_bucket(
+                        "spmv", [req.csr for req, _ in grp],
+                        grp[0][1].schedule, backend=backend,
+                        store=self.prepared_store, executor=self.executor,
+                        member_keys=(mks if all(mks) else None))
                 # modeled cost of the stacked launch = sum of the members'
                 # tree/cache predictions, so the launch trace event carries
                 # modeled_ms next to wall-clock (repro.obs.report needs both)
@@ -497,8 +500,10 @@ class SelectorService:
             measured_s = bucket_plan.last_measured_s
             per_member_ms = (measured_s * 1e3 / max(len(grp), 1)
                              if measured_s is not None else None)
-            for (req, dec), y in zip(grp, ys):
-                dec.y = np.asarray(y)
+            with obs_trace.span("drain_fetch", n_members=len(grp)):
+                for (_, dec), y in zip(grp, ys):
+                    dec.y = np.asarray(y)
+            for _, dec in grp:
                 self._counts["executed"] += 1
                 if per_member_ms is None:
                     continue

@@ -163,21 +163,26 @@ class ServingEngine:
         """Move up to ``admit_max`` queued requests into slots: decide a
         Schedule per request (the service's cache/tree/verify path) and key
         the slot by (schedule, PreparedStore residency)."""
+        if not len(self.queue):
+            return 0
         admitted = 0
         store = self.service.prepared_store
-        while len(self.queue) and admitted < self.admit_max:
-            er = self.queue.pop()
-            dec = self.service.select(er.csr, name=er.name)
-            resident = bool(dec.ck) and store.resident(dec.ck)
-            sreq = Request(er.name, er.csr, er.x, ck=dec.ck)
-            slot = self.slots.assign((er, sreq, dec), dec.schedule, resident,
-                                     affinity=dec.ck)
-            self._counts["admitted"] += 1
-            if resident:
-                self._counts["resident_admits"] += 1
-            obs_trace.emit("admit", er.name, slot=slot.label,
-                           resident=resident, occupancy=len(slot.members))
-            admitted += 1
+        with obs_trace.span("admission") as ev:
+            while len(self.queue) and admitted < self.admit_max:
+                er = self.queue.pop()
+                dec = self.service.select(er.csr, name=er.name)
+                resident = bool(dec.ck) and store.resident(dec.ck)
+                sreq = Request(er.name, er.csr, er.x, ck=dec.ck)
+                slot = self.slots.assign((er, sreq, dec), dec.schedule,
+                                         resident, affinity=dec.ck)
+                self._counts["admitted"] += 1
+                if resident:
+                    self._counts["resident_admits"] += 1
+                obs_trace.emit("admit", er.name, slot=slot.label,
+                               resident=resident,
+                               occupancy=len(slot.members))
+                admitted += 1
+            ev["admitted"] = admitted
         return admitted
 
     # ---------------------------------------------------------------- drain
@@ -230,17 +235,18 @@ class ServingEngine:
                                       backend=self.backend)
         t_done = self.clock()
         reg = self._metrics.registry
-        for er, _, dec in live:
-            if self.on_result is not None:
-                self.on_result(er.rid, dec.y)
-            lat_ms = (t_done - er.t_enqueue) * 1e3
-            reg.observe(self._metrics.key("request_ms"), lat_ms)
-            self._counts["completed"] += 1
-            self._terminal_outcome(er, "completed")
-            if self.slo_ms is not None:
-                key = ("slo_attained" if lat_ms <= self.slo_ms
-                       else "slo_missed")
-                self._counts[key] += 1
+        with obs_trace.span("drain_answer", n_requests=len(live)):
+            for er, _, dec in live:
+                if self.on_result is not None:
+                    self.on_result(er.rid, dec.y)
+                lat_ms = (t_done - er.t_enqueue) * 1e3
+                reg.observe(self._metrics.key("request_ms"), lat_ms)
+                self._counts["completed"] += 1
+                self._terminal_outcome(er, "completed")
+                if self.slo_ms is not None:
+                    key = ("slo_attained" if lat_ms <= self.slo_ms
+                           else "slo_missed")
+                    self._counts[key] += 1
         self._counts["drains"] += 1
         self._counts["drained_members"] += len(live)
         if len(live) >= 2:

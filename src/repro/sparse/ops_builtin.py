@@ -53,6 +53,7 @@ from ..kernels.flash_attention.ref import ref_attention
 from ..kernels.moe_gmm.kernel import moe_gmm_pallas
 from ..kernels.moe_gmm.ops import route_and_pad  # noqa: F401  (facade re-export)
 from ..kernels.moe_gmm.ref import ref_gmm
+from ..obs import trace as obs_trace
 from .plan import Plan, _bump_trace
 from .prepared import PreparedStore, array_key, bucket_edge, content_key
 from .registry import register_op
@@ -555,21 +556,24 @@ def _plan_matvec_rhs_stacked(members: List, schedule: Schedule,
                              "inputs (got mixed vector/multi-RHS)")
         if n == 1:
             return [inner._run(xs[0])]
-        if ndims == {1}:
-            ks, X = None, np.stack(xs, axis=1)
-        else:
-            ks = [x.shape[1] for x in xs]
-            X = np.concatenate(xs, axis=1)
-        k = X.shape[1]
-        # power-of-two rounding (not bucket_edge): the RHS width is the
-        # jit compile key of the multi-RHS program, and {1,2,4,8,...} is
-        # half the keys of the 1.5x edge ladder — occupancy jitter under
-        # live traffic then never compiles mid-replay once the pow2 rungs
-        # are warm
-        k_pad = (1 << (k - 1).bit_length()) if shape_bucket else k
-        if k_pad != k:
-            X = np.concatenate(
-                [X, np.zeros((X.shape[0], k_pad - k), np.float32)], axis=1)
+        with obs_trace.span("drain_stack", n_members=n):
+            if ndims == {1}:
+                ks, X = None, np.stack(xs, axis=1)
+            else:
+                ks = [x.shape[1] for x in xs]
+                X = np.concatenate(xs, axis=1)
+            k = X.shape[1]
+            # power-of-two rounding (not bucket_edge): the RHS width is the
+            # jit compile key of the multi-RHS program, and {1,2,4,8,...}
+            # is half the keys of the 1.5x edge ladder — occupancy jitter
+            # under live traffic then never compiles mid-replay once the
+            # pow2 rungs are warm
+            k_pad = (1 << (k - 1).bit_length()) if shape_bucket else k
+            if k_pad != k:
+                X = np.concatenate(
+                    [X, np.zeros((X.shape[0], k_pad - k), np.float32)],
+                    axis=1)
+            X = jnp.asarray(X)
         y = inner._run(X)                       # (true_rows, k_pad)
         if ks is None:
             return [y[:, i] for i in range(n)]
@@ -641,17 +645,19 @@ def _plan_matvec_bucket(members: List, schedule: Schedule, backend: str, *,
                 f"{sorted(sigs)}; split the bucket by RHS signature "
                 "(SelectorService does this automatically)")
         multi = xs[0].ndim == 2
-        if multi:
-            k = xs[0].shape[1]
-            k_pad = -(-k // tile) * tile
-            xpad = np.zeros((b_pad, width, k_pad), np.float32)
-            for i, x in enumerate(xs):
-                xpad[i, : x.shape[0], :k] = x
-        else:
-            xpad = np.zeros((b_pad, width), np.float32)
-            for i, x in enumerate(xs):
-                xpad[i, : x.shape[0]] = x
-        ys = _exec_matvec_stacked(arrays, jnp.asarray(xpad), layout=layout,
+        with obs_trace.span("drain_stack", n_members=len(xs)):
+            if multi:
+                k = xs[0].shape[1]
+                k_pad = -(-k // tile) * tile
+                xpad = np.zeros((b_pad, width, k_pad), np.float32)
+                for i, x in enumerate(xs):
+                    xpad[i, : x.shape[0], :k] = x
+            else:
+                xpad = np.zeros((b_pad, width), np.float32)
+                for i, x in enumerate(xs):
+                    xpad[i, : x.shape[0]] = x
+            xpad = jnp.asarray(xpad)
+        ys = _exec_matvec_stacked(arrays, xpad, layout=layout,
                                   backend=backend)
         if multi:
             return [ys[i, : shapes[i][0], : xs[i].shape[1]]

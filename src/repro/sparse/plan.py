@@ -23,7 +23,6 @@ timed: the measurement feeds the ``launch_ms.<op>`` latency histogram, the
 from __future__ import annotations
 
 import dataclasses
-import math
 import time
 from typing import Callable, Dict, List, Optional, Sequence
 
@@ -119,11 +118,7 @@ class Plan:
                       measured_ms=dt * 1e3, modeled_ms=modeled_ms,
                       source=self.source, n_members=self.n_members,
                       n_shards=self.n_shards)
-        reg = default_registry()
-        reg.observe(f"launch_ms.{self.op}", dt * 1e3)
-        if modeled_ms:
-            reg.observe(f"residual_log10.{self.op}",
-                        math.log10(max(dt * 1e3, 1e-9) / modeled_ms))
+        default_registry().observe(f"launch_ms.{self.op}", dt * 1e3)
         return out
 
     __call__ = execute

@@ -648,10 +648,14 @@ def guard_plan(p, rebuild: Optional[Callable] = None,
                                 f"no rebuild path for op {op!r} rung {b!r}")
                         state["run"] = rebuild(b)._run
                         p.backend = b
-                    out = state["run"](*runtime)
-                if ex.nan_guard and not output_finite(out):
-                    raise NonFiniteOutput(
-                        f"{op} produced non-finite output on backend {b!r}")
+                    with obs_trace.span("dispatch", op=op, backend=b):
+                        out = state["run"](*runtime)
+                if ex.nan_guard:
+                    with obs_trace.span("finite_check", op=op, backend=b):
+                        finite = output_finite(out)
+                    if not finite:
+                        raise NonFiniteOutput(f"{op} produced non-finite "
+                                              f"output on backend {b!r}")
                 if b == "dense":
                     ex.dense_served += 1
                     p.backend = "dense"

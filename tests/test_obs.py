@@ -8,7 +8,10 @@ on retraining examples consumed by ``refit()``, thread-safety under
 concurrent hammering, and the bench_compare regression differ."""
 import importlib.util
 import json
+import os
 import pathlib
+import subprocess
+import sys
 import threading
 
 import numpy as np
@@ -19,7 +22,7 @@ from repro.obs import (CounterDict, EVENT_FIELDS, EVENT_TYPES, Histogram,
                        MetricsRegistry, Tracer, default_registry,
                        install_tracer, ordered, telemetry_key)
 from repro.obs import trace as obs_trace
-from repro.obs.report import load_launches, summarize
+from repro.obs.report import load_launches, main as report_main, summarize
 from repro.obs.schema import TELEMETRY_KEY_RE
 from repro.selector import ScheduleCache, SelectorService
 from repro.sparse import (FaultInjector, GuardedExecutor, PreparedStore,
@@ -214,6 +217,81 @@ def test_installed_tracer_call_sites_are_noops_without_one():
         assert tr.counts() == {"shed": 1}
     finally:
         install_tracer(None)
+
+
+def _profiled(tmp_path, fn):
+    """Host events of a ``jax.profiler`` trace of ``fn()``, one list of
+    ``(name, start_ns, end_ns)`` per host thread line."""
+    import glob
+    import jax
+    from jax.profiler import ProfileData
+    jax.profiler.start_trace(str(tmp_path))
+    try:
+        fn()
+    finally:
+        jax.profiler.stop_trace()
+    (path,) = glob.glob(str(tmp_path / "**" / "*.xplane.pb"), recursive=True)
+    return [[(e.name, e.start_ns, e.end_ns) for e in line.events]
+            for plane in ProfileData.from_file(path).planes
+            if plane.name == "/host:CPU" for line in plane.lines]
+
+
+def test_spans_land_in_the_profiler_trace_nested_on_the_calling_thread(
+        tmp_path):
+    """One selector decision and one guarded launch under a CPU profiler
+    session, no Tracer installed: each layer span is a ``repro.<type>``
+    event on the thread that opened it, nested as DESIGN.md §12 lists."""
+    import jax
+    from repro.sparse import plan
+    reset_resilience()
+    assert obs_trace.tracer() is None
+    tuner = ScheduleTuner("spmv", TPU_V5E).fit(TRAIN, max_mats=3)
+    svc = SelectorService(tuner, cache=ScheduleCache())
+    _, _, A = TRAIN[0]
+    x = np.ones(A.shape[1], np.float32)
+
+    def work():
+        with jax.profiler.TraceAnnotation("test.calling_thread"):
+            plan("spmv", A, selector=svc).execute(x)
+
+    lines = _profiled(tmp_path, work)
+    (events,) = [ev for ev in lines
+                 if any(n == "test.calling_thread" for n, _, _ in ev)]
+    spans = {}
+    for name, a, b in events:
+        if name.startswith("repro."):
+            assert name not in spans, f"{name} twice"
+            spans[name] = (a, b)
+    for name in ("repro.select", "repro.hash", "repro.fingerprint",
+                 "repro.launch", "repro.dispatch", "repro.finite_check"):
+        assert name in spans, name
+    # no repro.* span on any other thread
+    assert not [n for ev in lines if ev is not events
+                for n, _, _ in ev if n.startswith("repro.")]
+
+    def inside(inner, outer):
+        return (spans[outer][0] <= spans[inner][0]
+                and spans[inner][1] <= spans[outer][1])
+
+    assert inside("repro.hash", "repro.select")
+    assert inside("repro.fingerprint", "repro.select")
+    assert spans["repro.hash"][1] <= spans["repro.fingerprint"][0]
+    assert inside("repro.dispatch", "repro.launch")
+    assert inside("repro.finite_check", "repro.launch")
+    assert spans["repro.dispatch"][1] <= spans["repro.finite_check"][0]
+    assert spans["repro.select"][1] <= spans["repro.launch"][0]
+
+
+def test_profiler_span_names_are_constant_and_not_the_benchmarks_own():
+    bench = str(pathlib.Path(__file__).resolve().parent.parent / "bench")
+    if bench not in sys.path:
+        sys.path.insert(0, bench)
+    from spbench.harness import SPANS
+    names = obs_trace.PROFILER_NAMES
+    assert set(names) == set(EVENT_TYPES)
+    for type_, name in names.items():
+        assert name == f"repro.{type_}"
+        assert name not in SPANS
 
 
 # ------------------------------------------- telemetry() as registry views
@@ -436,7 +514,7 @@ def test_calibration_report_from_serve_trace(traced_serve, tmp_path):
         assert row["calibrated_mape"] >= 0
         # the scale is exactly 10**mean_residual
         assert row["calibration_scale"] == pytest.approx(
-            10.0 ** row["residual_log10"])
+            10.0 ** row["mean_log10_residual"])
 
 
 def test_report_skips_torn_lines(tmp_path):
@@ -450,8 +528,37 @@ def test_report_skips_torn_lines(tmp_path):
     launches = load_launches([str(path)])
     assert len(launches) == 1
     rep = summarize(launches)
-    assert rep["spmv/ell/jnp"]["residual_log10"] == \
+    assert rep["spmv/ell/jnp"]["mean_log10_residual"] == \
         pytest.approx(np.log10(2.0))
+
+
+def test_report_cli_prints_one_row_per_group(tmp_path, capsys):
+    path = tmp_path / "one.jsonl"
+    path.write_text(json.dumps({"type": "launch", "op": "spmv",
+                                "layout": "ell", "backend": "jnp",
+                                "measured_ms": 2.0, "modeled_ms": 1.0})
+                    + "\n")
+    rep = report_main([str(path)])
+    out = capsys.readouterr().out.splitlines()
+    assert len(out) == 2 and out[0].split()[4] == "resid"
+    row = out[1].split()
+    assert row[0] == "spmv/ell/jnp" and row[1] == "1"
+    assert float(row[4]) == pytest.approx(
+        rep["spmv/ell/jnp"]["mean_log10_residual"], abs=0.005)
+
+
+def test_obs_package_loads_jax_only_on_the_first_span():
+    code = ("import sys\n"
+            "import repro.obs, repro.obs.report\n"
+            "assert 'jax' not in sys.modules, 'jax at import'\n"
+            "with repro.obs.span('prep'):\n"
+            "    pass\n"
+            "assert 'jax' in sys.modules\n")
+    src = str(pathlib.Path(__file__).resolve().parent.parent / "src")
+    env = dict(os.environ, PYTHONPATH=src, JAX_PLATFORMS="cpu")
+    done = subprocess.run([sys.executable, "-c", code], env=env,
+                          capture_output=True, text=True, timeout=120)
+    assert done.returncode == 0, done.stderr
 
 
 # ------------------------------------------------------------ bench_compare

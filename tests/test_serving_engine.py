@@ -194,6 +194,26 @@ def test_trace_counts_reconcile_with_registry(tuner, population, rhs):
     assert tel["admitted"] == tel["completed"] + tel["shed"]
 
 
+def test_ticks_that_only_drain_open_no_admission_span(
+        tuner, population, rhs):
+    """A tick admits only when the queue holds something: the ticks that
+    drain the slots left after one admission open no ``admission`` span."""
+    tr = install_tracer(Tracer(registry=default_registry()))
+    try:
+        engine = _engine(tuner, slot_max=4)
+        for t, (name, A) in enumerate(population):
+            engine.submit(f"adm{t}:{name}", A, rhs[t], tenant=t)
+        engine.drain_all()
+    finally:
+        install_tracer(None)
+    counts = tr.counts()
+    # one tick admitted every tenant; one drain per tenant's slot followed
+    assert counts["admission"] == 1
+    assert counts["drain"] == len(population) > 1
+    (ev,) = [e for e in tr.events() if e["type"] == "admission"]
+    assert ev["args"]["admitted"] == len(population)
+
+
 # ------------------------------------------------------------- slot table
 
 def test_affinity_keeps_slots_content_pure(tuner):
