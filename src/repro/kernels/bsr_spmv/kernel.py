@@ -10,7 +10,7 @@ Schedules
     the x segment is ``x[cols[i, j]]`` — data-dependent HBM->VMEM DMA with no
     data-dependent control flow in the kernel body. Padding slots point at a
     trailing all-zeros block (ELLBSR invariant), so irregular rows cost dead
-    MXU lanes (the counters' ``padding_fraction``) instead of branches: the
+    tile work (the counters' ``padding_fraction``) instead of branches: the
     paper's branch-misprediction bottleneck transformed into a measurable,
     tree-visible quantity.
 
@@ -29,18 +29,28 @@ Schedules
     A TPU block's last two dims must be multiples of (8, 128) or equal the
     array's. A (1, bs) slice of an (n_block_cols, bs) vector is neither, so
     the SpMV wrappers view x and y as (n, 1, bs): each block is one whole
-    (1, bs) row and the kernel computes it as x (1, bs) . A^T, an MXU op
-    with the A tile contracted on its minor dim.
+    (1, bs) row. SpMV has one useful right-hand side, so its tile product
+    runs on the VPU in f32, not on the MXU: for each 128-lane group g of
+    the tile, ``acc += A[:, g] * x[g]`` with the x row broadcast over
+    sublanes, into a (bs, 128) f32 VMEM accumulator (one (bs, bs) group
+    where 128 does not divide bs). The row's last cell sums ``acc`` across
+    lanes once (a transpose, then a sublane sum) and writes the (1, bs)
+    output row. No transpose of A, no MXU pass.
 
   SpMM (multi-RHS)
     Same two schedules with x blocked as (n_block_cols, bs, k): one A-tile
-    DMA now feeds a (bs, bs) @ (bs, k) MXU op, amortizing A traffic across k
-    right-hand sides — the reuse the paper finds missing from SpMV.
+    DMA now feeds a (bs, bs) @ (bs, k) MXU op at ``precision=HIGHEST``,
+    amortizing A traffic across k right-hand sides — the reuse the paper
+    finds missing from SpMV. SpMM accumulates into its resident output
+    tile.
 
-VMEM per grid cell: (1+1 double-buffered) x (bs*bs + bs*k + bs*k) * 4B; at
-bs=128, k=8 that is ~148 KB, far under VMEM, leaving room for deeper
-pipelining. MXU alignment wants bs in {128, 256}; smaller bs trades padding
-for underutilized systolic lanes (autotune.py arbitrates via the tree model).
+VMEM per grid cell: double-buffered A tile, x tile and output tile,
+2 x (bs*bs + bs*k + bs*k) * 4B for SpMM (at bs=128, k=8 that is ~148 KB)
+and 2 x (bs*bs + 2*8*bs) * 4B (a (1, bs) tile pads to 8 sublanes) plus the
+bs * 128 * 4B accumulator for SpMV (bs * bs where 128 does not divide bs;
+at bs=256, ~670 KB in all): far under VMEM either way. bs in {128, 256}
+fills whole vregs and MXU tiles; smaller bs trades padding for partly
+empty lanes (autotune.py arbitrates via the tree model).
 """
 from __future__ import annotations
 
@@ -54,43 +64,65 @@ from jax.experimental.pallas import tpu as pltpu
 HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def _tile_product(blk_ref, x_ref, vector: bool):
-    """One cell's product: (bs, bs) @ (bs, k) for SpMM, and for SpMV the
-    (1, bs) vector row times the tile transposed, (1, bs) . (bs, bs)^T.
+def _tile_product(first, last, blk_ref, x_ref, y_ref, acc_ref=None):
+    """Accumulate one grid cell's product into its output row.
 
-    HIGHEST precision: the MXU's default f32 pass rounds both operands to
-    bf16 (~2^-8 relative error); the f32 API and ``ref.py`` promise f32."""
-    if vector:
-        return jax.lax.dot_general(
-            x_ref[0], blk_ref[0], (((1,), (1,)), ((), ())),
-            precision=HIGHEST, preferred_element_type=jnp.float32)
-    return jnp.dot(blk_ref[0], x_ref[0], precision=HIGHEST,
-                   preferred_element_type=jnp.float32)
+    ``first`` / ``last`` mark the row's first and last cell. SpMM
+    (``acc_ref`` None): (bs, bs) @ (bs, k) on the MXU at HIGHEST precision,
+    added into the resident output tile; the MXU's default f32 pass would
+    round both operands to bf16 (~2^-8 relative error), and the f32 API and
+    ``ref.py`` promise f32. SpMV: an f32 multiply-add on the VPU into
+    ``acc_ref`` (module docstring, "Vector layout"), exact f32 products with
+    no MXU pass; the lane sum to the (1, bs) output row runs once, on the
+    row's last cell."""
+    if acc_ref is None:
+        @pl.when(first)
+        def _init_tile():
+            y_ref[...] = jnp.zeros_like(y_ref)
 
-
-def _ell_kernel(idx_ref, cols_ref, blk_ref, x_ref, y_ref, *, vector):
-    del idx_ref, cols_ref  # consumed by the index maps
-    j = pl.program_id(1)
-
-    @pl.when(j == 0)
-    def _init():
-        y_ref[...] = jnp.zeros_like(y_ref)
-
-    # accumulated into the resident output block-row
-    y_ref[0] += _tile_product(blk_ref, x_ref, vector)
-
-
-def _sell_kernel(idx_ref, cols_ref, rows_ref, blk_ref, x_ref, y_ref, *,
-                 vector):
-    del idx_ref, cols_ref  # consumed by the index maps
-    t = pl.program_id(0)
-    first = jnp.logical_or(t == 0, rows_ref[t] != rows_ref[jnp.maximum(t - 1, 0)])
+        y_ref[0] += jnp.dot(blk_ref[0], x_ref[0], precision=HIGHEST,
+                            preferred_element_type=jnp.float32)
+        return
 
     @pl.when(first)
-    def _init():
-        y_ref[...] = jnp.zeros_like(y_ref)
+    def _init_acc():
+        acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    y_ref[0] += _tile_product(blk_ref, x_ref, vector)
+    lanes = acc_ref.shape[1]
+    acc = acc_ref[...]
+    for g in range(blk_ref.shape[-1] // lanes):
+        cut = slice(g * lanes, (g + 1) * lanes)
+        acc += blk_ref[0, :, cut] * x_ref[0, :, cut]
+    acc_ref[...] = acc
+
+    @pl.when(last)
+    def _flush():
+        y_ref[0] = jnp.sum(acc_ref[...].T, axis=0, keepdims=True)
+
+
+def _ell_kernel(idx_ref, cols_ref, blk_ref, x_ref, y_ref, *acc):
+    del idx_ref, cols_ref  # consumed by the index maps
+    j = pl.program_id(1)
+    _tile_product(j == 0, j == pl.num_programs(1) - 1, blk_ref, x_ref, y_ref,
+                  *acc)
+
+
+def _sell_kernel(idx_ref, cols_ref, rows_ref, blk_ref, x_ref, y_ref, *acc):
+    del idx_ref, cols_ref  # consumed by the index maps
+    t = pl.program_id(0)
+    end = pl.num_programs(0) - 1
+    row = rows_ref[t]
+    first = jnp.logical_or(t == 0, row != rows_ref[jnp.maximum(t - 1, 0)])
+    last = jnp.logical_or(t == end, row != rows_ref[jnp.minimum(t + 1, end)])
+    _tile_product(first, last, blk_ref, x_ref, y_ref, *acc)
+
+
+def _acc_scratch(bs: int, vector: bool) -> list:
+    """The SpMV lane-group accumulator, (bs, 128) where 128-lane groups
+    tile the block and (bs, bs) where they do not, so its groups cover
+    every column; SpMM accumulates in its output."""
+    lanes = 128 if bs % 128 == 0 else bs
+    return [pltpu.VMEM((bs, lanes), jnp.float32)] if vector else []
 
 
 @functools.partial(jax.jit, static_argnames=("interpret",))
@@ -146,9 +178,10 @@ def _ell_call(block_indices, block_cols, blocks, x_blocks, *, vector: bool,
             pl.BlockSpec((1,) + tile, lambda i, j, idx, cols: (cols[i, j], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1,) + tile, lambda i, j, idx, cols: (i, 0, 0)),
+        scratch_shapes=_acc_scratch(bs, vector),
     )
     return pl.pallas_call(
-        functools.partial(_ell_kernel, vector=vector),
+        _ell_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_br,) + tile, jnp.float32),
         interpret=interpret,
@@ -211,9 +244,10 @@ def _sell_call(cell_block, cell_col, cell_row, blocks, x_blocks,
             pl.BlockSpec((1,) + tile, lambda t, idx, cols, rows: (cols[t], 0, 0)),
         ],
         out_specs=pl.BlockSpec((1,) + tile, lambda t, idx, cols, rows: (rows[t], 0, 0)),
+        scratch_shapes=_acc_scratch(bs, vector),
     )
     return pl.pallas_call(
-        functools.partial(_sell_kernel, vector=vector),
+        _sell_kernel,
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((n_block_rows,) + tile, jnp.float32),
         interpret=interpret,

@@ -199,7 +199,8 @@ def _plan_matvec(operands, schedule: Optional[Schedule], backend: str, *,
         return y[:true_rows] if true_rows != pad_rows else y
 
     return Plan(op=op, schedule=sched, backend=backend, _run=run,
-                operands=(st,))
+                operands=(st,),
+                kernel_rhs=None if st.layout == "dense" else "given")
 
 
 # ---------------------------------------------------------------------------
@@ -584,7 +585,8 @@ def _plan_matvec_rhs_stacked(members: List, schedule: Schedule,
         return outs
 
     return Plan(op=op, schedule=schedule, backend=backend, _run=run,
-                operands=inner.operands, n_members=n)
+                operands=inner.operands, n_members=n,
+                kernel_rhs=inner.kernel_rhs and "stacked")
 
 
 def _pad_member_axis(built: Dict, b_pad: int) -> Dict:
@@ -665,7 +667,8 @@ def _plan_matvec_bucket(members: List, schedule: Schedule, backend: str, *,
         return [ys[i, : shapes[i][0]] for i in range(len(xs))]
 
     return Plan(op=op, schedule=schedule, backend=backend, _run=run,
-                n_members=len(shapes))
+                n_members=len(shapes),
+                kernel_rhs=None if layout == "dense" else "given")
 
 
 # ---------------------------------------------------------------------------
@@ -895,7 +898,9 @@ def _plan_matvec_sharded(operands, schedules, backend: str, *, op: str,
     return Plan(op=op, schedule=sched, backend=backend, _run=run,
                 operands=(sst,) if sst is not None else (),
                 n_members=n_shards, n_shards=n_shards,
-                shard_devices=[frozenset(h) for h in homes])
+                shard_devices=[frozenset(h) for h in homes],
+                kernel_rhs=None if mesh is not None or all(
+                    q.kernel_rhs is None for q in sub) else "given")
 
 
 # ---------------------------------------------------------------------------

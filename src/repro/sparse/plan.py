@@ -19,6 +19,9 @@ stacked-launch tests assert. Every ``execute`` is additionally wall-clock
 timed: the measurement feeds the ``launch_ms.<op>`` latency histogram, the
 ``launch`` trace event (measured next to the plan's modeled cost), and the
 ``Plan.last_measured_s`` field the selector's residual feedback reads.
+A launch whose SpMV/SpMM kernel ran (``pallas`` or ``interpret``) also
+ticks ``kernel.tile_product.vpu`` or ``.mxu``, as ``Plan.tile_product``
+reads it from the launch's runtime input.
 """
 from __future__ import annotations
 
@@ -88,6 +91,11 @@ class Plan:
     # sharded plans: the set of devices holding each shard's arrays, read
     # from the placed arrays themselves (one device per shard when placed)
     shard_devices: Optional[List[frozenset]] = None
+    # how a launch's runtime input reaches the bsr_spmv kernels: "given"
+    # (each input as it is), "stacked" (member vectors stacked into one
+    # multi-RHS launch), None where no such kernel runs (a dense operand,
+    # a mesh program). Read by ``tile_product``.
+    kernel_rhs: Optional[str] = None
     # wall-clock of the most recent execute (set per call). With the NaN
     # guard on (default) the guarded run synchronizes on the result, so
     # this is end-to-end launch latency, not dispatch-only.
@@ -111,15 +119,33 @@ class Plan:
                           if self.modeled_time_s else None)
             # backend/layout read AFTER the run: the guard rewrites
             # ``p.backend`` when the launch fell down the fallback ladder
+            tile = self.tile_product(*runtime)
             ev.update(op=self.op, backend=self.backend,
                       layout=(s.layout if s is not None
                               and s.backend != "dense"
                               else "dense" if s is not None else "per-shard"),
                       measured_ms=dt * 1e3, modeled_ms=modeled_ms,
                       source=self.source, n_members=self.n_members,
-                      n_shards=self.n_shards)
-        default_registry().observe(f"launch_ms.{self.op}", dt * 1e3)
+                      n_shards=self.n_shards, tile_product=tile)
+        reg = default_registry()
+        reg.observe(f"launch_ms.{self.op}", dt * 1e3)
+        if tile is not None:
+            reg.inc(f"kernel.tile_product.{tile}")
         return out
+
+    def tile_product(self, *runtime) -> Optional[str]:
+        """Where a launch on ``runtime`` runs the bsr_spmv kernels' tile
+        product: "vpu" for a vector right-hand side (f32 multiply-add),
+        "mxu" for a matrix one (HIGHEST), None where no such kernel runs
+        (a jnp or dense launch, or one the guard moved off the kernel)."""
+        if self.kernel_rhs is None \
+                or self.backend not in ("pallas", "interpret"):
+            return None
+        xs = runtime[0] if isinstance(runtime[0], (list, tuple)) \
+            else runtime[:1]
+        if self.kernel_rhs == "stacked" and len(xs) > 1:
+            return "mxu"
+        return "vpu" if np.ndim(xs[0]) == 1 else "mxu"
 
     __call__ = execute
 

@@ -19,7 +19,7 @@ def _sparse(n, m, density, seed):
 
 # ------------------------------------------------------------------ SpMV
 @pytest.mark.parametrize("n,bs", [(64, 8), (100, 16), (257, 32), (512, 128),
-                                  (96, 96)])
+                                  (96, 96), (600, 256), (400, 192)])
 @pytest.mark.parametrize("backend", ["jnp", "interpret"])
 def test_bsr_spmv_allclose(n, bs, backend):
     csr = _sparse(n, n, 0.06, n)
@@ -49,7 +49,8 @@ def test_bsr_spmv_ell_capacity_drop():
 
 # ------------------------------------------------- SELL (bucketed) SpMV/SpMM
 @pytest.mark.parametrize("n,bs,C,sigma", [(64, 8, 2, 8), (100, 16, 4, 2),
-                                          (257, 32, 3, 1000), (512, 128, 8, 64)])
+                                          (257, 32, 3, 1000), (512, 128, 8, 64),
+                                          (700, 256, 2, 4)])
 @pytest.mark.parametrize("backend", ["jnp", "interpret"])
 def test_bsr_spmv_sell_allclose(n, bs, C, sigma, backend):
     csr = _sparse(n, n, 0.06, n)
@@ -102,6 +103,154 @@ def test_bsr_sell_zipf_allclose(backend):
     y = np.asarray(bsr_spmv.bsr_spmv(sell, jnp.asarray(x), backend=backend))
     np.testing.assert_allclose(y, bsr_spmv.ops.spmv_oracle(csr, x),
                                rtol=1e-4, atol=1e-4)
+
+
+# Block pattern of the row-structure cases, one entry per block-row: a
+# hub row of five cells, one-cell rows, an empty row (ELL pads it with the
+# zero tile, SELL keeps one zero-tile cell) and a two-cell row.
+ROW_BLOCKS = [(0, 1, 2, 3, 4), (1,), (), (0, 4), (2,)]
+
+
+def _block_pattern(bs, seed):
+    """CSR of ROW_BLOCKS at block size ``bs`` (last block-row and column
+    cut short), its float64 dense form, and an x."""
+    rng = np.random.default_rng(seed)
+    n = len(ROW_BLOCKS) * bs - 3
+    d = np.zeros((len(ROW_BLOCKS) * bs,) * 2, np.float32)
+    for r, cols in enumerate(ROW_BLOCKS):
+        for c in cols:
+            tile = rng.standard_normal((bs, bs)) * (rng.random((bs, bs)) < 0.3)
+            d[r * bs:(r + 1) * bs, c * bs:(c + 1) * bs] = tile
+    d = d[:n, :n]
+    x = rng.standard_normal(n).astype(np.float32)
+    return CSR.from_dense(d), d.astype(np.float64), x
+
+
+def _assert_f64_close(y, d64, x):
+    """Within f32 rounding of the float64 product, row by row on |A||x|."""
+    x64 = x.astype(np.float64)
+    bound = np.abs(d64) @ np.abs(x64)
+    assert np.all(np.abs(np.asarray(y, np.float64) - d64 @ x64)
+                  <= 1e-6 * bound + 1e-30)
+
+
+@pytest.mark.parametrize("bs", [32, 128, 192, 256])
+@pytest.mark.parametrize("layout", ["ell", "sell"])
+def test_spmv_vpu_row_structures(layout, bs):
+    """The SpMV tile product (VPU, f32) against the float64 oracle on rows
+    of one cell, of many cells and of zero-tile padding only; SELL row
+    changes and the SMEM-split launches (one row, or one cell, a launch:
+    SELL rows then straddle launches) included."""
+    from repro.kernels.bsr_spmv import kernel as K
+    from repro.sparse.smem import SMEM_BUDGET_BYTES, split_cells, split_rows
+    csr, d64, x = _block_pattern(bs, bs)
+    n = x.shape[0]
+    n_bc = -(-n // bs)
+    xb = jnp.asarray(np.pad(x, (0, n_bc * bs - n)).reshape(n_bc, bs))
+    if layout == "ell":
+        a = bsr_spmv.ops.prepare(csr, bs)
+        zero = a.blocks.shape[0] - 1
+        assert (a.block_indices == zero).sum() >= 4    # padded slots
+        tables = (jnp.asarray(a.block_indices), jnp.asarray(a.block_cols))
+        blocks = jnp.asarray(a.blocks)
+
+        def launch(budget):
+            return split_rows(
+                lambda i, c: K.bsr_spmv_pallas(i, c, blocks, xb,
+                                               interpret=True),
+                tables, budget=budget)
+        perm = None
+    else:
+        a = bsr_spmv.ops.prepare_sell(csr, bs, 2, 4)
+        cr = a.cell_row
+        assert (np.diff(cr) > 0).sum() == a.n_block_rows - 1  # row changes
+        assert (np.bincount(cr) == 1).any() and (np.bincount(cr) > 1).any()
+        tables = (jnp.asarray(a.cell_block), jnp.asarray(a.cell_col),
+                  jnp.asarray(cr))
+        blocks = jnp.asarray(a.blocks)
+
+        def launch(budget):
+            return split_cells(
+                lambda b, c, r: K.bsr_spmv_sell_pallas(
+                    b, c, r, blocks, xb, a.n_block_rows, interpret=True),
+                tables, tables[2], a.n_block_rows, budget=budget)
+        perm = jnp.asarray(a.row_perm)
+    for budget in (SMEM_BUDGET_BYTES, 1):
+        y = launch(budget)
+        if perm is not None:
+            y = jnp.zeros_like(y).at[perm].set(y)
+        _assert_f64_close(np.asarray(y).reshape(-1)[:n], d64, x)
+
+
+@pytest.mark.parametrize("layout", ["ell", "sell"])
+def test_stacked_spmv_vpu_matches_f64(layout):
+    """A stacked bucket (one program, B unrolled SpMV launches into one
+    tile stack) at bs 128 against the float64 oracle, member by member."""
+    from repro.core.autotune import Schedule
+    from repro.sparse import plan_bucket
+    mats = [_block_pattern(128, s) for s in (1, 2)]
+    sched = (Schedule("bsr", 128, 1.0) if layout == "ell"
+             else Schedule("bsr", 128, 1.0, layout="sell", slice_height=2))
+    ys = plan_bucket("spmv", [m[0] for m in mats], sched,
+                     backend="interpret").execute([m[2] for m in mats])
+    for (_, d64, x), y in zip(mats, ys):
+        _assert_f64_close(y, d64, x)
+
+
+def test_tile_product_provenance_and_counters():
+    """SpMV launches run the VPU tile product and SpMM launches (a fused
+    same-operand drain among them) the MXU one: ``Plan.tile_product``
+    says which for a runtime input, the launch event carries it, and
+    ``kernel.tile_product.*`` counts each kernel launch once. A jnp plan
+    runs no kernel and counts nothing."""
+    from repro.core.autotune import Schedule
+    from repro.obs import default_registry, trace as obs_trace
+    from repro.sparse import PreparedStore, plan, plan_bucket, plan_sharded
+    from repro.sparse.prepared import content_key
+    csr, _, x = _block_pattern(32, 5)
+    X = np.stack([x, -x, 2 * x], axis=1)
+    sched = Schedule("bsr", 32, 1.0)
+    reg = default_registry()
+
+    def counts():
+        return (reg.get("kernel.tile_product.vpu"),
+                reg.get("kernel.tile_product.mxu"))
+
+    spmv = plan("spmv", (csr,), schedule=sched, backend="interpret")
+    spmm = plan("spmm", (csr,), schedule=sched, backend="interpret")
+    store, ck = PreparedStore(), content_key(csr)
+    fused = plan_bucket("spmv", [csr, csr], sched, backend="interpret",
+                        store=store, member_keys=(ck, ck))
+    bucket = plan_bucket("spmv", [csr, csr], sched, backend="interpret")
+    sharded = plan_sharded("spmv", csr, n_shards=2, schedule=sched,
+                           backend="interpret")
+    plain = plan("spmv", (csr,), schedule=sched, backend="jnp")
+    assert (spmv.tile_product(x), spmm.tile_product(X)) == ("vpu", "mxu")
+    assert fused.tile_product([x, -x]) == "mxu"
+    assert bucket.tile_product([x, -x]) == "vpu"
+    assert bucket.tile_product([X, X]) == "mxu"
+    assert sharded.tile_product(x) == "vpu"
+    assert plain.tile_product(x) is None
+    vpu0, mxu0 = counts()
+    for _ in range(3):
+        spmv.execute(x)
+    spmm.execute(X)
+    fused.execute([x, -x])
+    bucket.execute([x, -x])
+    sharded.execute(x)
+    plain.execute(x)
+    assert counts() == (vpu0 + 5, mxu0 + 2)
+    # the launch, not the op name, decides: a matrix RHS through an spmv
+    # plan takes the MXU, and the launch event says so
+    was = obs_trace.tracer()
+    tr = obs_trace.install_tracer(obs_trace.Tracer())
+    try:
+        spmv.execute(X)
+    finally:
+        obs_trace.install_tracer(was)
+    assert counts() == (vpu0 + 5, mxu0 + 3)
+    assert [e["args"]["tile_product"] for e in tr.events()
+            if e["type"] == "launch"] == ["mxu"]
 
 
 def test_sell_padding_beats_global_ell_on_zipf():

@@ -82,10 +82,11 @@ def _launches(compiled) -> int:
 
 # (layout, bs, op): 64^3 stencil = 262,144 rows after shape bucketing.
 # ell bs=128: n_br 2048 (two 512 KiB tables unsplit: over SMEM);
-# ell bs=256: the selector's pick for the stencil; sell bs=32: 196,608
-# cells (2.25 MiB of cell streams unsplit).
+# ell bs=256: the selector's pick for the stencil; sell bs=32 and 128:
+# 196,608 cells (2.25 MiB of cell streams unsplit). spmv runs the VPU tile
+# product (its lane-group accumulator and the row flush), spmm the MXU one.
 MATVEC = [("ell", 128, "spmv"), ("ell", 128, "spmm"), ("ell", 256, "spmv"),
-          ("sell", 32, "spmv"), ("sell", 32, "spmm")]
+          ("sell", 32, "spmv"), ("sell", 32, "spmm"), ("sell", 128, "spmv")]
 
 
 @pytest.mark.parametrize("layout,bs,op", MATVEC)
