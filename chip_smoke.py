@@ -22,8 +22,8 @@ The last stdout line is ``{"ok": true, "device": {...}}``, printed only
 when every phase passed.
 
 ``--four-chips`` instead runs only ``plan_sharded("spmv", n_shards=4)`` on
-the 64^3 stencil, on the Pallas per-shard path and on the shard_map path,
-each checked against the one-chip plan and the reference, with the four
+the 64^3 stencil, one shard_map program with the Pallas kernel and one with
+the jnp body, each checked against the one-chip plan and the reference, with the four
 shards' arrays on four distinct devices.
 
 Usage:  python chip_smoke.py [--four-chips] [--seed N]
@@ -324,8 +324,8 @@ class Smoke:
         runs = self.runs(lambda: one.execute(x))
         y_one = np.asarray(runs[-1][0])
         self.report("spmv one chip", one, runs, rel_error(y_one, ref, bound))
-        for label, backend in (("pallas per-shard", "auto"),
-                               ("shard_map", "jnp")):
+        for label, backend in (("pallas shard_map", "auto"),
+                               ("jnp shard_map", "jnp")):
             p = plan_sharded("spmv", A, n_shards=4, backend=backend,
                              executor=self.executor)
             runs = self.runs(lambda: p.execute(x))

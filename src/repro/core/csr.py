@@ -17,7 +17,7 @@ on the serving path (bench_kernels_micro reports it as its own row).
 from __future__ import annotations
 
 import dataclasses
-from typing import Tuple
+from typing import Callable, Optional, Tuple
 
 import numpy as np
 
@@ -96,6 +96,28 @@ class CSR:
         row_ptrs = np.cumsum(row_ptrs)
         return cls(row_ptrs, cols.astype(np.uint32), vals, shape)
 
+    def block_row_tiles(self, bs: int) -> np.ndarray:
+        """Distinct (bs x bs) tiles in each block row: the ``blocks_per_row``
+        of ``BSR.from_csr(self, bs)`` without building a tile. A bitmap over
+        a band of block rows at a time keeps memory near 64 MB, or one block
+        row's width where that is more."""
+        n_br = -(-self.n_rows // bs)
+        n_bc = max(-(-self.n_cols // bs), 1)
+        out = np.zeros(n_br, dtype=np.int64)
+        band = max((1 << 26) // n_bc, 1)
+        for r0 in range(0, n_br, band):
+            r1 = min(r0 + band, n_br)
+            lo, hi = r0 * bs, min(r1 * bs, self.n_rows)
+            p0, p1 = int(self.row_ptrs[lo]), int(self.row_ptrs[hi])
+            if p1 == p0:
+                continue
+            brows = np.repeat(np.arange(lo, hi) // bs - r0,
+                              np.diff(self.row_ptrs[lo:hi + 1]))
+            seen = np.zeros((r1 - r0) * n_bc, dtype=bool)
+            seen[brows * n_bc + self.col_idxs[p0:p1] // bs] = True
+            out[r0:r1] = seen.reshape(r1 - r0, n_bc).sum(axis=1)
+        return out
+
     def to_dense(self) -> np.ndarray:
         out = np.zeros(self.shape, dtype=np.float32)
         lens = self.row_lengths()
@@ -149,7 +171,12 @@ class BSR:
         return 1.0 - nnz / stored
 
     @classmethod
-    def from_csr(cls, csr: CSR, block_size: int) -> "BSR":
+    def from_csr(cls, csr: CSR, block_size: int,
+                 alloc: Optional[Callable[[int], np.ndarray]] = None
+                 ) -> "BSR":
+        """Block ``csr``. ``alloc(n)``, given, returns a zeroed float32
+        array of at least ``n`` tiles for the tiles to be written into: the
+        BSR's ``blocks`` is then its first ``n``, a view."""
         bs = block_size
         n_br = -(-csr.n_rows // bs)
         n_bc = -(-csr.n_cols // bs)
@@ -160,8 +187,17 @@ class BSR:
         # unique (brow, bcol) pairs, row-major order
         key = brows * n_bc + bcols
         uniq, inv = np.unique(key, return_inverse=True)
-        blocks = np.zeros((uniq.size, bs, bs), dtype=np.float32)
-        np.add.at(blocks, (inv, rows % bs, cols % bs), csr.nnz_vals)
+        blocks = (np.zeros((uniq.size, bs, bs), dtype=np.float32)
+                  if alloc is None else alloc(uniq.size)[: uniq.size])
+        r, c = rows % bs, cols % bs
+        same_row = rows[1:] == rows[:-1]
+        if np.all(cols[1:][same_row] > cols[:-1][same_row]):
+            # columns ascend inside each row, so no entry repeats: a plain
+            # scatter (+0.0 turns a stored -0.0 into the sum's 0.0)
+            blocks.reshape(-1)[(inv * bs + r) * bs + c] = csr.nnz_vals + \
+                np.float32(0.0)
+        else:
+            np.add.at(blocks, (inv, r, c), csr.nnz_vals)
         u_brows, u_bcols = uniq // n_bc, uniq % n_bc
         block_ptrs = np.zeros(n_br + 1, dtype=np.int64)
         np.add.at(block_ptrs, u_brows + 1, 1)
@@ -206,7 +242,10 @@ class ELLBSR:
         return 1.0 - valid / max(total, 1)
 
     @classmethod
-    def from_bsr(cls, bsr: BSR, max_blocks: int | None = None) -> "ELLBSR":
+    def from_bsr(cls, bsr: BSR, max_blocks: int | None = None,
+                 blocks: np.ndarray | None = None) -> "ELLBSR":
+        """ELL of ``bsr``. ``blocks``, given, already holds bsr's tiles
+        followed by the zero tile and is used as it is, not copied."""
         bpr = bsr.blocks_per_row()
         mb = int(bpr.max()) if max_blocks is None else int(max_blocks)
         mb = max(mb, 1)
@@ -223,9 +262,10 @@ class ELLBSR:
             block_cols = np.where(valid, bsr.block_cols[safe], 0).astype(np.int32)
         else:
             block_cols = np.zeros((n_br, mb), dtype=np.int32)
-        blocks = np.concatenate(
-            [bsr.blocks, np.zeros((1, bsr.block_size, bsr.block_size), np.float32)], axis=0
-        )
+        if blocks is None:
+            blocks = np.concatenate(
+                [bsr.blocks, np.zeros((1, bsr.block_size, bsr.block_size), np.float32)], axis=0
+            )
         return cls(
             block_indices,
             block_cols,

@@ -64,6 +64,10 @@ from typing import Dict, Mapping, Tuple
 #   drain_answer the on_result calls and bookkeeping after a drain
 #   dispatch    inside a guarded launch: eager pads and slices, jit dispatch
 #   finite_check the NaN/Inf guard's isfinite ops and the host's wait
+#
+# Sharded path (DESIGN.md §10):
+#   shard_build one row shard's container build, upload to its own chip and
+#               release of its host copies, inside a sharded plan's prep
 EVENT_TYPES: Tuple[str, ...] = (
     "select", "prep", "compile", "launch", "fallback", "quarantine",
     "shed", "store_evict", "enqueue", "admit", "drain",
@@ -71,6 +75,7 @@ EVENT_TYPES: Tuple[str, ...] = (
     "checkpoint", "restart", "recovery",
     "hash", "fingerprint", "admission", "drain_plan", "drain_stack",
     "drain_fetch", "drain_answer", "dispatch", "finite_check",
+    "shard_build",
 )
 
 # Required ``args`` fields per event type — the golden-schema contract a
@@ -103,6 +108,7 @@ EVENT_FIELDS: Dict[str, Tuple[str, ...]] = {
     "drain_answer": ("n_requests",),
     "dispatch": ("op", "backend"),
     "finite_check": ("op", "backend"),
+    "shard_build": ("shard", "n_shards"),
 }
 
 # Telemetry keys are flat snake_case identifiers: lowercase alphanumerics

@@ -53,13 +53,14 @@ from ..kernels.flash_attention.ref import ref_attention
 from ..kernels.moe_gmm.kernel import moe_gmm_pallas
 from ..kernels.moe_gmm.ops import route_and_pad  # noqa: F401  (facade re-export)
 from ..kernels.moe_gmm.ref import ref_gmm
-from ..obs import trace as obs_trace
+from ..obs import default_registry, trace as obs_trace
 from .plan import Plan, _bump_trace
 from .prepared import PreparedStore, array_key, bucket_edge, content_key
 from .registry import register_op
 from .resilience import check_fault, dense_ref_cap, register_dense_ref
 from .smem import split_cells, split_rows
-from .tensor import ShardedMeta, ShardedSparseTensor, SparseTensor
+from .tensor import (ShardedMeta, ShardedSparseTensor, SparseTensor,
+                     build_host, container_shapes)
 
 MATVEC_LAYOUTS = ("ell", "sell", "dense")
 
@@ -106,6 +107,31 @@ def _sell_launch(cb, cc, cr, blocks, xb, n_br: int, multi: bool,
         (cb, cc, cr), cr, n_br)
 
 
+def _matvec_tiles(arrays, layout: str, xb: jax.Array, n_br: int,
+                  backend: str, multi: bool) -> jax.Array:
+    """The ell/sell product on the blocked RHS, as block rows (n_br, bs) or
+    (n_br, bs, k): the Pallas kernels on pallas/interpret, the jnp reference
+    otherwise. One body for the one-chip launch and for each shard."""
+    if layout == "sell":
+        cb, cc, cr = (arrays["cell_block"], arrays["cell_col"],
+                      arrays["cell_row"])
+        blocks = arrays["blocks"]
+        if backend == "jnp":
+            y = (ref_bsr_spmm_sell if multi else ref_bsr_spmv_sell)(
+                cb, cc, cr, blocks, xb, n_br)
+        else:
+            y = _sell_launch(cb, cc, cr, blocks, xb, n_br, multi,
+                             interpret=(backend == "interpret"))
+        perm = arrays["row_perm"]
+        return jnp.zeros_like(y).at[perm].set(y)
+    idx, cols = arrays["block_indices"], arrays["block_cols"]
+    blocks = arrays["blocks"]
+    if backend == "jnp":
+        return (ref_bsr_spmm if multi else ref_bsr_spmv)(idx, cols, blocks, xb)
+    return _ell_launch(idx, cols, blocks, xb, multi,
+                       interpret=(backend == "interpret"))
+
+
 @functools.partial(jax.jit, static_argnames=("backend", "rhs_tile"))
 def _exec_matvec(st: SparseTensor, x: jax.Array, backend: str,
                  rhs_tile: int) -> jax.Array:
@@ -114,33 +140,14 @@ def _exec_matvec(st: SparseTensor, x: jax.Array, backend: str,
     meta = st.meta
     if meta.layout == "dense":
         return st.arrays["dense"] @ x.astype(jnp.float32)
+    if meta.layout not in ("ell", "sell"):
+        raise ValueError(f"spmv/spmm cannot execute layout {meta.layout!r}")
     bs = meta.block_size
     n_bc = -(-meta.shape[1] // bs)
     multi = x.ndim == 2
     xb = _block_x(x, meta.shape[1], n_bc, bs, rhs_tile)
-    if meta.layout == "sell":
-        cb, cc, cr = (st.arrays["cell_block"], st.arrays["cell_col"],
-                      st.arrays["cell_row"])
-        blocks = st.arrays["blocks"]
-        n_br = meta.n_block_rows
-        if backend == "jnp":
-            y = (ref_bsr_spmm_sell if multi else ref_bsr_spmv_sell)(
-                cb, cc, cr, blocks, xb, n_br)
-        else:
-            y = _sell_launch(cb, cc, cr, blocks, xb, n_br, multi,
-                             interpret=(backend == "interpret"))
-        perm = st.arrays["row_perm"]
-        y = jnp.zeros_like(y).at[perm].set(y)
-    elif meta.layout == "ell":
-        idx, cols = st.arrays["block_indices"], st.arrays["block_cols"]
-        blocks = st.arrays["blocks"]
-        if backend == "jnp":
-            y = (ref_bsr_spmm if multi else ref_bsr_spmv)(idx, cols, blocks, xb)
-        else:
-            y = _ell_launch(idx, cols, blocks, xb, multi,
-                            interpret=(backend == "interpret"))
-    else:
-        raise ValueError(f"spmv/spmm cannot execute layout {meta.layout!r}")
+    y = _matvec_tiles(st.arrays, meta.layout, xb, meta.n_block_rows, backend,
+                      multi)
     if multi:
         k = x.shape[1]
         return y.reshape(y.shape[0] * y.shape[1], -1)[: meta.shape[0], :k]
@@ -677,18 +684,28 @@ def _plan_matvec_bucket(members: List, schedule: Schedule, backend: str, *,
 
 _SHARDED_EXECS: dict = {}
 
+# the arrays a launch reads, per layout (valid_counts and slice_widths are
+# construction-side bookkeeping the kernels never touch)
+_LAUNCH_FIELDS = {"ell": ("block_indices", "block_cols", "blocks"),
+                  "sell": ("cell_block", "cell_col", "cell_row", "row_perm",
+                           "blocks"),
+                  "dense": ("dense",)}
 
-def _sharded_matvec_exec(mesh, layout: str, multi: bool):
-    """One jitted shard_map program per (mesh, layout, arity).
+
+def _sharded_matvec_exec(mesh, layout: str, backend: str, multi: bool,
+                         rows: int):
+    """One jitted shard_map program per (mesh, layout, backend, arity, rows).
 
     The stacked shard arrays are sharded along the leading member axis (one
-    shard per mesh slot) and the blocked RHS is replicated; each slot
-    computes its own output rows. A *row* decomposition needs only a concat
-    of per-shard results — no psum — so the program body has zero
+    shard per mesh slot) and the blocked RHS is replicated; each slot runs
+    the one-chip body (``_matvec_tiles``: the Pallas kernel with its SMEM
+    splits, or the jnp reference) on its own shard and returns its first
+    ``rows`` output rows. A *row* decomposition needs only a concat of
+    per-shard results — no psum — so the program body has zero
     cross-device collectives (the column-partitioned variant would psum
     partial products instead; DESIGN.md §10 records the tradeoff).
     """
-    key = (mesh, layout, multi)
+    key = (mesh, layout, backend, multi, rows)
     fn = _SHARDED_EXECS.get(key)
     if fn is not None:
         return fn
@@ -698,28 +715,20 @@ def _sharded_matvec_exec(mesh, layout: str, multi: bool):
 
     def local(arrays, xb):
         # local leading dim is 1: this slot's single shard
+        a = {k: v[0] for k, v in arrays.items()}
         if layout == "dense":
-            y = arrays["dense"][0] @ xb
-            return y[None]
-        bs = arrays["blocks"].shape[-1]
-        n_bc = xb.shape[0] // bs
-        xblk = xb.reshape((n_bc, bs) + xb.shape[1:])
-        if layout == "ell":
-            idx, cols = arrays["block_indices"][0], arrays["block_cols"][0]
-            eq = "rmab,rmbk->rak" if multi else "rmab,rmb->ra"
-            y = jnp.einsum(eq, arrays["blocks"][0][idx], xblk[cols])
-        else:  # sell
-            cb, cc, cr = (arrays["cell_block"][0], arrays["cell_col"][0],
-                          arrays["cell_row"][0])
-            perm = arrays["row_perm"][0]
-            eq = "tab,tbk->tak" if multi else "tab,tb->ta"
-            prods = jnp.einsum(eq, arrays["blocks"][0][cb], xblk[cc])
-            y = jax.ops.segment_sum(prods, cr, num_segments=perm.shape[0])
-            y = jnp.zeros_like(y).at[perm].set(y)
-        return y.reshape((1, y.shape[0] * y.shape[1]) + y.shape[2:])
+            y = a["dense"] @ xb
+        else:
+            bs = a["blocks"].shape[-1]
+            xblk = xb.reshape((xb.shape[0] // bs, bs) + xb.shape[1:])
+            n_br = a["row_perm"].shape[0] if layout == "sell" else 0
+            y = _matvec_tiles(a, layout, xblk, n_br, backend, multi)
+            y = y.reshape((y.shape[0] * y.shape[1],) + y.shape[2:])
+        return y[None, :rows]
 
+    # check_vma off: a pallas_call's output declares no mesh-axis variance
     mapped = shard_map(local, mesh=mesh, in_specs=(P(SHARD_AXIS), P()),
-                       out_specs=P(SHARD_AXIS))
+                       out_specs=P(SHARD_AXIS), check_vma=False)
 
     def run(arrays, xb):
         _bump_trace("matvec_sharded")
@@ -728,6 +737,127 @@ def _sharded_matvec_exec(mesh, layout: str, multi: bool):
     fn = jax.jit(run)
     _SHARDED_EXECS[key] = fn
     return fn
+
+
+@functools.partial(jax.jit, static_argnames=("width", "k_pad"))
+def _pad_rhs(x: jax.Array, width: int, k_pad: int) -> jax.Array:
+    """The RHS zero-padded to the shards' column width (and RHS tile)."""
+    x = x.astype(jnp.float32)
+    if x.ndim == 2:
+        return jnp.zeros((width, k_pad), jnp.float32) \
+            .at[: x.shape[0], : x.shape[1]].set(x)
+    return jnp.zeros((width,), jnp.float32).at[: x.shape[0]].set(x)
+
+
+@functools.partial(jax.jit, static_argnames=("rows", "k"))
+def _concat_rows(ys: jax.Array, rows: Tuple[int, ...],
+                 k: Optional[int]) -> jax.Array:
+    """Shard i's first ``rows[i]`` output rows, in shard order."""
+    y = jnp.concatenate([ys[i, :r] for i, r in enumerate(rows)], axis=0)
+    return y if k is None else y[:, :k]
+
+
+def _home(x) -> jax.Device:
+    """Where a launch on ``x`` returns its output: x's device when x lives
+    on one, else the default device."""
+    if isinstance(x, jax.Array) and len(x.devices()) == 1:
+        return next(iter(x.devices()))
+    return jax.devices()[0]
+
+
+def _count_exchange(nbytes: int) -> None:
+    reg = default_registry()
+    reg.inc("shard.launches")
+    reg.inc("shard.exchange_bytes", float(nbytes))
+
+
+def _pad_to(arr: np.ndarray, shape: Tuple[int, ...], fill) -> np.ndarray:
+    """``arr`` zero-copy when it already has ``shape``, else padded to it
+    with ``fill`` after its own entries."""
+    if arr.shape == tuple(shape):
+        return arr
+    if any(a > b for a, b in zip(arr.shape, shape)):
+        raise ValueError(f"shard array {arr.shape} exceeds the common shape "
+                         f"{tuple(shape)}")
+    out = np.full(shape, fill, arr.dtype)
+    out[tuple(slice(0, n) for n in arr.shape)] = arr
+    return out
+
+
+def _launch_arrays(host, layout: str, target: Dict[str, Tuple[int, ...]]
+                   ) -> Dict[str, np.ndarray]:
+    """A shard's host container as the arrays a launch reads, padded to the
+    common shapes with ``_build_matvec_bucket``'s fills: pad slots point at
+    the shard's own last (all-zeros) tile, pad cells extend its last sorted
+    row, ``row_perm`` extends with identity."""
+    if layout == "dense":
+        return {"dense": _pad_to(np.asarray(host, np.float32),
+                                 target["dense"], 0.0)}
+    zero = host.blocks.shape[0] - 1
+    out = {"blocks": _pad_to(host.blocks.astype(np.float32, copy=False),
+                             target["blocks"], 0.0)}
+    if layout == "ell":
+        out["block_indices"] = _pad_to(host.block_indices.astype(np.int32),
+                                       target["block_indices"], zero)
+        out["block_cols"] = _pad_to(host.block_cols.astype(np.int32),
+                                    target["block_cols"], 0)
+        return out
+    cr = host.cell_row.astype(np.int32)
+    out["cell_block"] = _pad_to(host.cell_block.astype(np.int32),
+                                target["cell_block"], zero)
+    out["cell_col"] = _pad_to(host.cell_col.astype(np.int32),
+                              target["cell_col"], 0)
+    out["cell_row"] = _pad_to(cr, target["cell_row"],
+                              int(cr[-1]) if cr.size else 0)
+    perm = host.row_perm.astype(np.int32)
+    out["row_perm"] = np.concatenate([perm, np.arange(
+        perm.size, target["row_perm"][0], dtype=np.int32)])
+    return out
+
+
+def _build_on_mesh(members: List, schedule: Schedule, sigma: int,
+                   shape_bucket: bool, mesh) -> Dict:
+    """The shards' launch arrays, stacked along a leading axis sharded over
+    the mesh, built one shard at a time: each shard's container is built
+    on the host, padded to the shapes every shard shares, uploaded to its
+    own device and dropped before the next starts, so the host holds one
+    shard's build at a time. The shared shapes come first, from each
+    shard's tile counts (``container_shapes``), without building a tile."""
+    from ..launch.mesh import SHARD_AXIS
+    layout = ("dense" if schedule.backend == "dense" else
+              "sell" if schedule.layout == "sell" else "ell")
+    shapes = [container_shapes(m, schedule, sigma=sigma,
+                               shape_bucket=shape_bucket)
+              if isinstance(m, CSR) else
+              {k: tuple(v.shape) for k, v in m.arrays.items()}
+              for m in members]
+    target = {k: tuple(max(s[k][d] for s in shapes)
+                       for d in range(len(shapes[0][k])))
+              for k in _LAUNCH_FIELDS[layout]}
+    if layout == "dense":
+        width = target["dense"][1]
+    else:
+        bs = schedule.block_size
+        n_bc = max(-(-m.shape[1] // bs) for m in members)
+        width = (bucket_edge(n_bc) if shape_bucket else n_bc) * bs
+    devices = list(mesh.devices.flat)
+    pieces: Dict[str, List] = {k: [] for k in target}
+    for i, (m, dev) in enumerate(zip(members, devices)):
+        with obs_trace.span("shard_build", shard=i, n_shards=len(members)):
+            host = (build_host(m, schedule, sigma=sigma,
+                               shape_bucket=shape_bucket)[0]
+                    if isinstance(m, CSR) else m.to_host())
+            placed = {k: jax.device_put(v[None], dev) for k, v in
+                      _launch_arrays(host, layout, target).items()}
+            jax.block_until_ready(placed)
+            del host
+        for k, v in placed.items():
+            pieces[k].append(v)
+    sharding = jax.sharding.NamedSharding(
+        mesh, jax.sharding.PartitionSpec(SHARD_AXIS))
+    arrays = {k: jax.make_array_from_single_device_arrays(
+        (len(members),) + target[k], sharding, pieces[k]) for k in target}
+    return {"arrays": arrays, "layout": layout, "width": int(width)}
 
 
 def _plan_matvec_sharded(operands, schedules, backend: str, *, op: str,
@@ -739,16 +869,21 @@ def _plan_matvec_sharded(operands, schedules, backend: str, *, op: str,
                          operand_key: Optional[str] = None, **_) -> Plan:
     """Distributed matvec plan: one prepared shard per mesh slot.
 
-    Homogeneous per-shard schedules on the jnp backend execute as ONE
-    shard_map program over the ``shards`` mesh axis (stacked arrays sharded
-    on the member axis, RHS replicated, outputs concatenated by row range).
-    Heterogeneous schedules — the per-shard selector picking different
-    layouts/block sizes for skewed shards — or too few devices fall back to
-    round-robin per-shard launches: each shard's operands are committed to
-    its own device and the per-shard jitted dispatches overlap
-    asynchronously. Both the partition and the prepared shard containers
-    ride the PreparedStore, so warm sharded plans skip partitioning AND
-    prep (the zero-rebuild property, extended to the distributed path).
+    Homogeneous per-shard schedules execute as ONE shard_map program over
+    the ``shards`` mesh axis, on every backend: each slot runs the one-chip
+    body (on pallas, the same ``bsr_spmv_pallas`` launch and SMEM splits),
+    the padded RHS is replicated from x's device, and the shards' rows come
+    back to x's device as one array. Heterogeneous schedules — the
+    per-shard selector picking different layouts/block sizes for skewed
+    shards — or too few devices take round-robin per-shard launches: each
+    shard's operands are committed to its own device, the per-shard jitted
+    dispatches overlap asynchronously, and the outputs are concatenated on
+    x's device. Either way shards are built one at a time and keep no host
+    copy, and the answer never passes through the host. Both the partition
+    and the prepared shards ride the PreparedStore, so warm sharded plans
+    skip partitioning AND prep (the zero-rebuild property, extended to the
+    distributed path). Every launch ticks ``shard.launches`` and adds the
+    bytes it moves between chips to ``shard.exchange_bytes``.
     """
     (a,) = operands
     sst: Optional[ShardedSparseTensor] = a if isinstance(
@@ -773,134 +908,131 @@ def _plan_matvec_sharded(operands, schedules, backend: str, *, op: str,
         shape = (int(a.shape[0]), int(a.shape[1]))
         strategy = part.strategy
     n_shards = len(bounds) - 1
-    true_rows = [bounds[i + 1] - bounds[i] for i in range(n_shards)]
+    true_rows = tuple(int(bounds[i + 1] - bounds[i]) for i in range(n_shards))
     n_cols = int(shape[1])
     tile = rhs_tile if rhs_tile is not None else (128 if backend == "pallas"
                                                   else 8)
     uniform = len(set(schedules)) == 1 and schedules[0] is not None
+    default_registry().set_gauge("shard.count", n_shards)
 
-    if uniform and backend == "jnp":
+    if uniform and mesh is None:
         from ..launch.mesh import make_shard_mesh
-        if mesh is None:
-            mesh = make_shard_mesh(n_shards)
-    else:
+        mesh = make_shard_mesh(n_shards)
+    elif not uniform:
         mesh = None
+
+    def check_x(x):
+        if getattr(x, "ndim", None) is None:
+            x = np.asarray(x, np.float32)
+        if x.shape[0] != n_cols:
+            raise ValueError(f"{op}: runtime input leading dim "
+                             f"{x.shape[0]} != operand cols {n_cols}")
+        return x
 
     if mesh is not None:
         # ---- single shard_map program over the mesh's shards axis. The
-        # stacked arrays are the ONLY device copy: CSR shards go through
-        # _bucket_hosts' host-container build, never per-shard staging, so
-        # the store pins one entry for the launch, not two.
-        from ..launch.mesh import SHARD_AXIS
+        # stacked arrays are the ONLY device copy: CSR shards go straight
+        # from their host containers to their devices, never through
+        # per-shard staging, so the store pins one entry for the launch.
         stack_key = None if store is None or not isinstance(a, CSR) else (
             "matvec_shards_stacked", operand_key or content_key(a),
             strategy, bounds, tuple(schedules), sigma,
             bool(shape_bucket), n_shards)
         members = list(sst.shards) if sst is not None else shard_csrs
-
-        def build_stacked():
-            built = _build_matvec_bucket(members, schedules[0], sigma,
-                                         shape_bucket)
-            sharding = jax.sharding.NamedSharding(
-                mesh, jax.sharding.PartitionSpec(SHARD_AXIS))
-            built["arrays"] = jax.device_put(built["arrays"], sharding)
-            return built
-
-        built = _cached(store, stack_key, build_stacked)
+        built = _cached(store, stack_key, lambda: _build_on_mesh(
+            members, schedules[0], sigma, shape_bucket, mesh))
         arrays, width, layout = built["arrays"], built["width"], built["layout"]
-        homes = [set() for _ in range(n_shards)]
-        for v in arrays.values():
-            for piece in v.addressable_shards:
-                homes[piece.index[0].start or 0].add(piece.device)
-        exec_fn = _sharded_matvec_exec(mesh, layout, False)
-        exec_fn_multi = _sharded_matvec_exec(mesh, layout, True)
+        slots = list(mesh.devices.flat)
+        homes = [{d} for d in slots]
+        r_max = max(true_rows)
+        kernel_rhs = None if layout == "dense" else "given"
 
         def run(x):
-            # pad on device (eager .at[].set): a device-resident serving
-            # input never round-trips through the host
-            if getattr(x, "ndim", None) is None:
-                x = np.asarray(x, np.float32)
-            if x.shape[0] != n_cols:
-                raise ValueError(f"{op}: runtime input leading dim "
-                                 f"{x.shape[0]} != operand cols {n_cols}")
+            x = check_x(x)
             multi = x.ndim == 2
-            xj = jnp.asarray(x, jnp.float32)
-            if multi:
-                k = x.shape[1]
-                k_pad = -(-k // tile) * tile
-                xb = jnp.zeros((width, k_pad), jnp.float32) \
-                    .at[: x.shape[0], :k].set(xj)
-            else:
-                xb = jnp.zeros((width,), jnp.float32).at[: x.shape[0]].set(xj)
-            # replicate the padded RHS over the mesh (device-to-device
-            # broadcast): a dev-0-committed serving input would otherwise
-            # clash with the mesh-sharded operand arrays under jit
-            xb = jax.device_put(xb, jax.sharding.NamedSharding(
-                mesh, jax.sharding.PartitionSpec()))
-            fn = exec_fn_multi if multi else exec_fn
-            ys = np.asarray(fn(arrays, xb))
-            if multi:
-                return np.concatenate(
-                    [ys[i, : true_rows[i], : x.shape[1]]
-                     for i in range(n_shards)], axis=0)
-            return np.concatenate(
-                [ys[i, : true_rows[i]] for i in range(n_shards)], axis=0)
+            k = x.shape[1] if multi else None
+            k_pad = -(-k // tile) * tile if multi else 0
+            home = _home(x)
+            # pad on x's device, then replicate the padded RHS over the
+            # mesh (device-to-device): x never round-trips through the host
+            xb = jax.device_put(_pad_rhs(x, width=width, k_pad=k_pad),
+                                jax.sharding.NamedSharding(
+                                    mesh, jax.sharding.PartitionSpec()))
+            fn = _sharded_matvec_exec(mesh, layout, backend, multi, r_max)
+            ys = jax.device_put(fn(arrays, xb), home)
+            # x out to every other slot, each slot's rows back to x's device
+            _count_exchange(sum(d != home for d in slots) * 4
+                            * (width + r_max) * max(k_pad, 1))
+            return _concat_rows(ys, rows=true_rows, k=k)
     else:
         # ---- per-shard fallback: round-robin device placement, one jitted
         # dispatch per shard (async overlap across devices); the path every
         # heterogeneous-schedule plan takes, whatever the backend
+        devices = jax.devices()
+        shard_devs = [devices[i % len(devices)] for i in range(n_shards)]
         if sst is None:
             key = None if store is None else (
                 "matvec_shards", operand_key or content_key(a), strategy,
                 bounds, tuple(schedules), sigma, bool(shape_bucket))
-            sst = _cached(store, key, lambda: ShardedSparseTensor(
-                ShardedMeta(shape, bounds, strategy),
-                [SparseTensor.from_csr(c, schedule=s, sigma=sigma,
-                                       shape_bucket=shape_bucket)
-                 for c, s in zip(shard_csrs, schedules)]))
+
+            def build_shards():
+                shards = []
+                for i, (c, s, dev) in enumerate(zip(shard_csrs, schedules,
+                                                    shard_devs)):
+                    with obs_trace.span("shard_build", shard=i,
+                                        n_shards=n_shards):
+                        st = SparseTensor.from_csr(
+                            c, schedule=s, sigma=sigma,
+                            shape_bucket=shape_bucket, device=dev)
+                        jax.block_until_ready(st.arrays)
+                        st._host = None
+                    shards.append(st)
+                return ShardedSparseTensor(
+                    ShardedMeta(shape, bounds, strategy), shards)
+
+            sst = _cached(store, key, build_shards)
             for st in sst.shards:
                 if st.layout not in MATVEC_LAYOUTS:
                     raise ValueError(f"{op} needs ell/sell/dense shards, "
                                      f"got a {st.layout!r} SparseTensor")
-        devices = jax.devices()
-        shard_devs = [devices[i % len(devices)]
-                      for i in range(len(sst.shards))]
         placed = []
         for st, dev in zip(sst.shards, shard_devs):
             nst = SparseTensor(st.meta, {k: jax.device_put(v, dev)
-                                         for k, v in st.arrays.items()},
-                               host=st._host)
+                                         for k, v in st.arrays.items()})
             nst.true_shape = st.true_shape
             placed.append(nst)
         homes = [{d for v in st.arrays.values() for d in v.devices()}
                  for st in placed]
         sub = [_plan_matvec((st,), s, backend, op=op, rhs_tile=rhs_tile)
                for st, s in zip(placed, schedules)]
+        kernel_rhs = None if all(q.kernel_rhs is None for q in sub) \
+            else "given"
 
         def run(x):
-            if getattr(x, "ndim", None) is None:
-                x = np.asarray(x, np.float32)
-            if x.shape[0] != n_cols:
-                raise ValueError(f"{op}: runtime input leading dim "
-                                 f"{x.shape[0]} != operand cols {n_cols}")
+            x = check_x(x)
+            home = _home(x)
             if isinstance(x, jax.Array):
                 # committed device input: device-to-device transfer per
                 # shard, never through the host
                 ys = [p._run(jax.device_put(x, d))
                       for p, d in zip(sub, shard_devs)]
+                x_moves = sum(d != home for d in shard_devs) * x.nbytes
             else:
-                # uncommitted host input: each shard's jit places it next
-                # to that shard's committed operands
+                # host input: each shard's jit places it next to that
+                # shard's committed operands
                 ys = [p._run(x) for p in sub]
-            return np.concatenate([np.asarray(y) for y in ys], axis=0)
+                x_moves = 0
+            _count_exchange(x_moves + sum(
+                y.nbytes for y, d in zip(ys, shard_devs) if d != home))
+            return jnp.concatenate([jax.device_put(y, home) for y in ys],
+                                   axis=0)
 
-    sched = schedules[0] if uniform else None
-    return Plan(op=op, schedule=sched, backend=backend, _run=run,
+    return Plan(op=op, schedule=schedules[0] if uniform else None,
+                backend=backend, _run=run,
                 operands=(sst,) if sst is not None else (),
                 n_members=n_shards, n_shards=n_shards,
                 shard_devices=[frozenset(h) for h in homes],
-                kernel_rhs=None if mesh is not None or all(
-                    q.kernel_rhs is None for q in sub) else "given")
+                kernel_rhs=kernel_rhs)
 
 
 # ---------------------------------------------------------------------------

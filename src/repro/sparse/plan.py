@@ -31,7 +31,7 @@ from typing import Callable, Dict, List, Optional, Sequence
 
 import numpy as np
 
-from ..core.autotune import Schedule
+from ..core.autotune import SELL_SIGMA, Schedule
 from ..core.csr import BSR, CSR, ELLBSR, SELLBSR
 from ..kernels.common import resolve_backend
 from ..obs import default_registry, trace as obs_trace
@@ -165,6 +165,40 @@ class Plan:
         return f"plan[{self.op}] {sched} via {self.source}{extra}"
 
 
+# f32 vectors of the operand's height and width a caller keeps on a chip
+# beside the prepared operand: its x and y, and a solver's work vectors
+CALLER_VECTORS = 8
+
+
+def chip_memory_bytes() -> Optional[int]:
+    """One local chip's memory, as the device's own ``memory_stats()``
+    reports it (``bytes_limit``); None where the backend reports none."""
+    import jax
+    stats = jax.local_devices()[0].memory_stats() or {}
+    limit = stats.get("bytes_limit")
+    return int(limit) if limit else None
+
+
+def _needs_placement(op: str, a, schedule: Optional[Schedule],
+                     sigma: int) -> bool:
+    """True when ``a`` prepared under ``schedule`` (the op planner's default
+    when None) would not fit one local chip with room for the caller's
+    vectors, and there are other local chips to spread it over."""
+    import jax
+    from .tensor import prepared_nbytes
+    if not isinstance(a, CSR) or get_op(op).sharded_planner is None \
+            or jax.local_device_count() < 2:
+        return False
+    limit = chip_memory_bytes()
+    if limit is None:
+        return False
+    sched = schedule if schedule is not None \
+        else SparseTensor.default_schedule()
+    room = CALLER_VECTORS * 4 * max(sched.n_rhs, 1) * (a.n_rows + a.n_cols)
+    return prepared_nbytes(a, sched, sigma=sigma, shape_bucket=True) \
+        + room > limit
+
+
 def _resolve_with_selector(selector, A: CSR, op: str = "",
                            quarantine=None):
     """(Schedule, provenance, operand content key) from a SelectorService
@@ -246,6 +280,19 @@ def plan(op: str, operands, schedule: Optional[Schedule] = None,
             and spec.layouts and schedule.layout not in spec.layouts:
         raise ValueError(f"op {op!r} supports layouts {spec.layouts}, "
                          f"schedule asks for {schedule.layout!r}")
+    if len(operands) == 1 and _needs_placement(
+            op, operands[0], schedule,
+            op_kwargs.get("sigma", SELL_SIGMA)):
+        # too large for one chip: nnz-balanced row shards over the local
+        # chips, each shard's schedule selected on its own
+        import jax
+        selected = bool(provenance)
+        return plan_sharded(
+            op, operands, n_shards=jax.local_device_count(),
+            schedule=None if selected else schedule,
+            selector=selector if selected else None, backend=backend,
+            store=store, executor=executor,
+            **{"operand_key": operand_key, **op_kwargs})
     # only inject serving-path extras when a store is in play AND the
     # planner declares/accepts them — custom planners registered through
     # the public register_op API need not know about either kwarg
@@ -275,6 +322,7 @@ def plan_sharded(op: str, operands, n_shards: Optional[int] = None,
                  selector=None, strategy: str = "nnz", backend: str = "auto",
                  mesh=None, store: Optional[PreparedStore] = None,
                  executor: Optional[resilience.GuardedExecutor] = None,
+                 operand_key: Optional[str] = None,
                  **op_kwargs) -> Plan:
     """Distributed plan: nnz-balanced row shards, one schedule per shard.
 
@@ -293,6 +341,9 @@ def plan_sharded(op: str, operands, n_shards: Optional[int] = None,
     Per-shard provenance lands on ``Plan.shard_provenance``; the
     PreparedStore (``store=``, or the selector's own) caches the partition
     and the prepared shard operands, so warm sharded plans skip both.
+    ``operand_key`` is the operand's ``content_key`` where the caller has
+    hashed it already. ``plan()`` comes here by itself for an operand too
+    large for one chip.
     """
     import jax
     from .partition import STRATEGIES, partition_rows
@@ -337,7 +388,7 @@ def plan_sharded(op: str, operands, n_shards: Optional[int] = None,
         n_shards = max(int(n_shards), 1)
         if store is not None:
             from .prepared import content_key
-            ck = content_key(a)
+            ck = operand_key or content_key(a)
         part_key = None if ck is None else ("row_partition", ck,
                                             n_shards, strategy)
         built = store.get(part_key) if part_key is not None else None

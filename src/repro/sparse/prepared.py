@@ -131,10 +131,24 @@ def array_key(arr: np.ndarray) -> str:
     return h.hexdigest()
 
 
-def entry_nbytes(value: Any) -> int:
-    """Device/host bytes held by a cached value (pytree leaves' nbytes)."""
-    return int(sum(getattr(leaf, "nbytes", 0)
-                   for leaf in jax.tree_util.tree_leaves(value)))
+def entry_charges(value: Any, nbytes: Optional[int] = None) -> Dict[Any, int]:
+    """Bytes a cached value holds on each device: every shard of a device
+    array on its own device, so an operand sharded over chips is charged to
+    each chip it lives on. Host leaves, and an explicit ``nbytes``, count
+    against the default device; a value with no bytes still charges it 0,
+    so the LRU can reach it."""
+    default = jax.devices()[0]
+    if nbytes is not None:
+        return {default: int(nbytes)}
+    charges: Dict[Any, int] = {default: 0}
+    for leaf in jax.tree_util.tree_leaves(value):
+        if isinstance(leaf, jax.Array):
+            for piece in leaf.addressable_shards:
+                charges[piece.device] = (charges.get(piece.device, 0)
+                                         + int(piece.data.nbytes))
+        else:
+            charges[default] += int(getattr(leaf, "nbytes", 0))
+    return charges
 
 
 def _leaves_alive(value: Any) -> bool:
@@ -173,9 +187,12 @@ class PreparedStore:
 
     Keys are tuples ``(kind, content_key(s)..., Schedule, prep kwargs)``;
     values are whatever the planner needs to skip host prep entirely — the
-    store never interprets them beyond byte accounting. Entries larger than
-    the whole budget are rejected (counted, not raised): a single huge
-    matrix must not flush the working set that is getting hits.
+    store never interprets them beyond byte accounting. The budget is per
+    device: an entry is charged to each chip it lives on
+    (``entry_charges``), so one sharded over four chips takes a quarter of
+    its bytes from each chip's budget. Entries larger than a chip's budget
+    are rejected (counted, not raised): a single huge matrix must not
+    flush the working set that is getting hits.
 
     Donation safety: cached values are returned by reference, and the
     facade's executors never donate operand buffers. A jit consumer that
@@ -207,6 +224,9 @@ class PreparedStore:
         self._metrics = default_registry().scope("prepared_store")
         self.byte_budget = int(byte_budget)
         self._entries: "OrderedDict[Tuple, Tuple[Any, int]]" = OrderedDict()
+        # per entry, its bytes on each device; and each device's total
+        self._charges: Dict[Tuple, Dict[Any, int]] = {}
+        self._device_bytes: Dict[Any, int] = {}
 
     def __len__(self) -> int:
         return len(self._entries)
@@ -222,8 +242,7 @@ class PreparedStore:
         if fault_fired("store-evict", str(key)):
             # injected fault: lose the entry, recover by serving a miss —
             # the caller rebuilds exactly as after a real eviction
-            self._entries.pop(key)
-            self.bytes_in_use -= entry[1]
+            self._drop(key)
             self.fault_evictions += 1
             self.misses += 1
             note_recovery("store-evict")
@@ -234,8 +253,7 @@ class PreparedStore:
             # a consumer donated the cached buffers — drop the entry and
             # serve a miss so the caller rebuilds instead of crashing on
             # deleted device arrays
-            self._entries.pop(key)
-            self.bytes_in_use -= entry[1]
+            self._drop(key)
             self.invalidated += 1
             self.misses += 1
             obs_trace.emit("store_evict", "donated", reason="donated",
@@ -245,23 +263,46 @@ class PreparedStore:
         self.hits += 1
         return entry[0]
 
+    def _drop(self, key: Tuple) -> Tuple[Any, int]:
+        """Remove ``key``'s entry and its charges; its (value, bytes)."""
+        value, nb = self._entries.pop(key)
+        for dev, b in self._charges.pop(key).items():
+            self._device_bytes[dev] -= b
+        self.bytes_in_use -= nb
+        return value, nb
+
+    def device_bytes(self) -> Dict[Any, int]:
+        """Bytes the store holds on each device."""
+        return {d: b for d, b in self._device_bytes.items() if b}
+
+    def _over(self) -> set:
+        return {d for d, b in self._device_bytes.items()
+                if b > self.byte_budget}
+
     def put(self, key: Tuple, value: Any,
             nbytes: Optional[int] = None) -> bool:
-        nb = entry_nbytes(value) if nbytes is None else int(nbytes)
-        if nb > self.byte_budget:
+        charges = entry_charges(value, nbytes)
+        if max(charges.values()) > self.byte_budget:
             self.rejected += 1
             return False
-        old = self._entries.pop(key, None)
-        if old is not None:
-            self.bytes_in_use -= old[1]
+        if key in self._entries:
+            self._drop(key)
+        nb = sum(charges.values())
         self._entries[key] = (value, nb)
+        self._charges[key] = charges
+        for dev, b in charges.items():
+            self._device_bytes[dev] = self._device_bytes.get(dev, 0) + b
         self.bytes_in_use += nb
         self.puts += 1
-        while self.bytes_in_use > self.byte_budget and len(self._entries) > 1:
-            _, (_, freed) = self._entries.popitem(last=False)
-            self.bytes_in_use -= freed
+        over = self._over()
+        while over and len(self._entries) > 1:
+            # the least recently used entry on a chip past its budget
+            victim = next(k for k in self._entries
+                          if over & self._charges[k].keys())
+            _, freed = self._drop(victim)
             self.evictions += 1
             obs_trace.emit("store_evict", "lru", reason="lru", nbytes=freed)
+            over = self._over()
         # a lone over-budget survivor cannot happen (rejected above), but an
         # exactly-at-budget single entry is fine — loop guard keeps >= 1.
         return True
@@ -313,12 +354,7 @@ class PreparedStore:
             return False
 
         matched = [k for k in self._entries if _refs(k)]
-        out = []
-        for k in matched:
-            value, nb = self._entries.pop(k)
-            self.bytes_in_use -= nb
-            out.append((k, value))
-        return out
+        return [(k, self._drop(k)[0]) for k in matched]
 
     @staticmethod
     def rewrite_key(key: Tuple, old_ck: str, new_ck: str) -> Tuple:
@@ -346,6 +382,8 @@ class PreparedStore:
 
     def clear(self) -> None:
         self._entries.clear()
+        self._charges.clear()
+        self._device_bytes.clear()
         self.bytes_in_use = 0
 
     # -------------------------------------------------- cross-run persistence

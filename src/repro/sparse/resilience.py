@@ -448,11 +448,13 @@ def make_dense_bucket_run(op: str, members: Sequence, schedule,
 # guarded execution
 # ---------------------------------------------------------------------------
 
-def _leaf_finite(x: Any) -> bool:
-    """Finiteness of one array leaf. Device (jax) arrays are reduced ON
-    DEVICE via ``jnp.isfinite(...).all()`` and only the scalar verdict
-    crosses to host — the guard never forces a full-output
-    device-to-host copy onto the serving fast path."""
+def _finite_verdict(x: Any) -> Any:
+    """Finiteness of one array leaf: a bool for host (numpy) arrays and
+    non-float leaves; for device (jax) arrays the ``isfinite(...).all()``
+    reduction, dispatched ON DEVICE (across every shard of a sharded
+    array) and not yet read — only the scalar verdict ever crosses to the
+    host, so the guard never forces a full-output device-to-host copy onto
+    the serving fast path."""
     dt = getattr(x, "dtype", None)
     if dt is None:
         return True
@@ -462,26 +464,30 @@ def _leaf_finite(x: Any) -> bool:
         return bool(np.isfinite(x).all())
     try:
         import jax.numpy as jnp
-        return bool(jnp.isfinite(x).all())
+        return jnp.isfinite(x).all()
     except (ImportError, TypeError):
         return bool(np.isfinite(np.asarray(x)).all())
 
 
+def _leaves(out: Any) -> List[Any]:
+    if out is None:
+        return []
+    if isinstance(out, (list, tuple)):
+        return [leaf for o in out for leaf in _leaves(o)]
+    blocks = getattr(out, "blocks", None)
+    return [blocks if blocks is not None else out]   # BSR-like result
+
+
 def output_finite(out: Any) -> bool:
     """True if every float leaf of an op output is finite. Understands the
-    facade's output shapes: arrays (np/jax), BSR results (``.blocks``),
-    and per-member lists from bucket plans. Note the check is still a
+    facade's output shapes: arrays (np/jax, sharded ones included), BSR
+    results (``.blocks``), and per-member lists from bucket plans. Every
+    leaf's reduction is dispatched before any is read, so a launch pays one
+    wait for the device, not one per leaf. The check is still a
     synchronization point (it must block on the result to decide whether
     to fall back); latency-critical callers can disable it with
     ``GuardedExecutor(nan_guard=False)`` or ``REPRO_NAN_GUARD=0``."""
-    if out is None:
-        return True
-    if isinstance(out, (list, tuple)):
-        return all(output_finite(o) for o in out)
-    blocks = getattr(out, "blocks", None)
-    if blocks is not None:                      # BSR-like result
-        return _leaf_finite(blocks)
-    return _leaf_finite(out)
+    return all(bool(v) for v in [_finite_verdict(x) for x in _leaves(out)])
 
 
 class GuardedExecutor:

@@ -26,7 +26,8 @@ import jax.numpy as jnp
 import numpy as np
 
 from ..core.autotune import SELL_SIGMA, Schedule
-from ..core.csr import BSR, CSR, ELLBSR, SELLBSR, ell_block_cap
+from ..core.csr import (BSR, CSR, ELLBSR, SELLBSR, ell_block_cap,
+                        sell_layout)
 from .prepared import bucket_edge
 
 HostLayout = Union[ELLBSR, SELLBSR, BSR, np.ndarray]
@@ -141,12 +142,8 @@ class SparseTensor:
             return SELLBSR.from_bsr(BSR.from_csr(csr, schedule.block_size),
                                     max(schedule.slice_height, 1), sigma)
         bsr = BSR.from_csr(csr, schedule.block_size)
-        mb = max_blocks
-        if full_rows:
-            mb = None
-        elif mb is None and schedule.ell_quantile < 1.0:
-            mb = ell_block_cap(bsr.blocks_per_row(), schedule.ell_quantile)
-        return ELLBSR.from_bsr(bsr, mb)
+        return ELLBSR.from_bsr(bsr, _ell_cap(bsr, schedule, max_blocks,
+                                             full_rows))
 
     @staticmethod
     def default_schedule(block_size: int = 128, layout: Optional[str] = None,
@@ -164,7 +161,8 @@ class SparseTensor:
                  slice_height: int = 8, sigma: int = SELL_SIGMA,
                  max_blocks: Optional[int] = None,
                  shape_bucket: bool = False,
-                 slack: int = 0) -> "SparseTensor":
+                 slack: int = 0,
+                 device: Optional[jax.Device] = None) -> "SparseTensor":
         """Prepare ``csr`` under ``schedule`` (or the keyword defaults).
 
         ``layout="bsr"`` forces the raw blocked container regardless of the
@@ -182,21 +180,16 @@ class SparseTensor:
         ``apply_delta`` can absorb structural inserts without a rebuild.
         ``MutableMatrix`` sets ``csr.mutation_slack`` and every planner's
         prep path forwards it here automatically.
+
+        ``device`` places the arrays on that device straight from the host
+        (the default device otherwise).
         """
         if schedule is None:
             schedule = cls.default_schedule(block_size, layout, slice_height)
-        container = cls.build_container(csr, schedule, layout=layout,
-                                        sigma=sigma, max_blocks=max_blocks,
-                                        full_rows=slack > 0)
-        spare: list = []
-        if slack > 0 and isinstance(container, (ELLBSR, SELLBSR)):
-            from .mutate import reserve_slack
-            container, spare = reserve_slack(container, int(slack))
-        zero_idx = (int(container.blocks.shape[0]) - 1
-                    if isinstance(container, (ELLBSR, SELLBSR)) else None)
-        if shape_bucket and not isinstance(container, BSR):
-            container = pad_container_to_bucket(container)
-        st = cls.from_layout(container, schedule=schedule)
+        container, zero_idx, spare = build_host(
+            csr, schedule, layout=layout, sigma=sigma, max_blocks=max_blocks,
+            shape_bucket=shape_bucket, slack=slack)
+        st = cls.from_layout(container, schedule=schedule, device=device)
         st.true_shape = (int(csr.shape[0]), int(csr.shape[1]))
         st.spare_blocks = spare
         st._zero_idx = zero_idx
@@ -204,8 +197,15 @@ class SparseTensor:
 
     @classmethod
     def from_layout(cls, container: HostLayout,
-                    schedule: Optional[Schedule] = None) -> "SparseTensor":
-        """Wrap an existing host container (ELLBSR/SELLBSR/BSR/dense)."""
+                    schedule: Optional[Schedule] = None,
+                    device: Optional[jax.Device] = None) -> "SparseTensor":
+        """Wrap an existing host container (ELLBSR/SELLBSR/BSR/dense), its
+        arrays uploaded to ``device`` (the default device when None)."""
+        if device is None:
+            put = jnp.asarray
+        else:
+            def put(a, dtype):
+                return jax.device_put(np.asarray(a, dtype), device)
         if isinstance(container, ELLBSR):
             if schedule is None:
                 schedule = Schedule("bsr", container.block_size, 1.0)
@@ -213,10 +213,10 @@ class SparseTensor:
                               n_block_rows=container.block_indices.shape[0],
                               schedule=schedule)
             arrays = {
-                "block_indices": jnp.asarray(container.block_indices, jnp.int32),
-                "block_cols": jnp.asarray(container.block_cols, jnp.int32),
-                "blocks": jnp.asarray(container.blocks, jnp.float32),
-                "valid_counts": jnp.asarray(container.valid_counts, jnp.int32),
+                "block_indices": put(container.block_indices, jnp.int32),
+                "block_cols": put(container.block_cols, jnp.int32),
+                "blocks": put(container.blocks, jnp.float32),
+                "valid_counts": put(container.valid_counts, jnp.int32),
             }
             return cls(meta, arrays, host=container)
         if isinstance(container, SELLBSR):
@@ -229,12 +229,12 @@ class SparseTensor:
                               slice_height=container.slice_height,
                               sigma=container.sigma, schedule=schedule)
             arrays = {
-                "cell_block": jnp.asarray(container.cell_block, jnp.int32),
-                "cell_col": jnp.asarray(container.cell_col, jnp.int32),
-                "cell_row": jnp.asarray(container.cell_row, jnp.int32),
-                "row_perm": jnp.asarray(container.row_perm, jnp.int32),
-                "slice_widths": jnp.asarray(container.slice_widths, jnp.int32),
-                "blocks": jnp.asarray(container.blocks, jnp.float32),
+                "cell_block": put(container.cell_block, jnp.int32),
+                "cell_col": put(container.cell_col, jnp.int32),
+                "cell_row": put(container.cell_row, jnp.int32),
+                "row_perm": put(container.row_perm, jnp.int32),
+                "slice_widths": put(container.slice_widths, jnp.int32),
+                "blocks": put(container.blocks, jnp.float32),
             }
             return cls(meta, arrays, host=container)
         if isinstance(container, BSR):
@@ -244,9 +244,9 @@ class SparseTensor:
                               n_block_rows=container.n_block_rows,
                               schedule=schedule)
             arrays = {
-                "block_ptrs": jnp.asarray(container.block_ptrs, jnp.int32),
-                "block_cols": jnp.asarray(container.block_cols, jnp.int32),
-                "blocks": jnp.asarray(container.blocks, jnp.float32),
+                "block_ptrs": put(container.block_ptrs, jnp.int32),
+                "block_cols": put(container.block_cols, jnp.int32),
+                "blocks": put(container.blocks, jnp.float32),
             }
             return cls(meta, arrays, host=container)
         dense = np.asarray(container, np.float32)
@@ -257,7 +257,7 @@ class SparseTensor:
             schedule = Schedule("dense", 128, 1.0)
         meta = SparseMeta("dense", dense.shape, schedule.block_size,
                           schedule=schedule)
-        return cls(meta, {"dense": jnp.asarray(dense)}, host=dense)
+        return cls(meta, {"dense": put(dense, jnp.float32)}, host=dense)
 
     @classmethod
     def wrap(cls, obj, schedule: Optional[Schedule] = None) -> "SparseTensor":
@@ -312,12 +312,104 @@ class SparseTensor:
         return host
 
 
+# ------------------------------------------------ shapes before a build
+
+def container_shapes(csr: CSR, schedule: Schedule, *,
+                     sigma: int = SELL_SIGMA,
+                     shape_bucket: bool = False) -> Dict[str, Tuple[int, ...]]:
+    """Shape of each device array ``SparseTensor.from_csr(csr, schedule,
+    sigma=sigma, shape_bucket=shape_bucket)`` would hold, from the tile
+    count of each block row alone: no tile is built. Every array is 4 bytes
+    an element (int32 or float32)."""
+    edge = bucket_edge if shape_bucket else int
+    if schedule.backend == "dense":
+        return {"dense": (edge(csr.n_rows), edge(csr.n_cols))}
+    bs = schedule.block_size
+    bpr = csr.block_row_tiles(bs)
+    n_br, nb = bpr.size, int(bpr.sum()) + 1   # + the shared zero tile
+    if schedule.layout == "sell":
+        _, widths = sell_layout(bpr, max(schedule.slice_height, 1), sigma)
+        n_cells = int(np.repeat(widths, max(schedule.slice_height, 1))
+                      [:n_br].sum())
+        return {"cell_block": (edge(n_cells),), "cell_col": (edge(n_cells),),
+                "cell_row": (edge(n_cells),), "row_perm": (edge(n_br),),
+                "slice_widths": (edge(widths.size),),
+                "blocks": (edge(nb), bs, bs)}
+    mb = ell_block_cap(bpr, min(schedule.ell_quantile, 1.0))
+    return {"block_indices": (edge(n_br), edge(mb)),
+            "block_cols": (edge(n_br), edge(mb)),
+            "blocks": (edge(nb), bs, bs), "valid_counts": (edge(n_br),)}
+
+
+def prepared_nbytes(csr: CSR, schedule: Schedule, **kw) -> int:
+    """Device bytes of the container ``container_shapes`` describes."""
+    return sum(4 * int(np.prod(s))
+               for s in container_shapes(csr, schedule, **kw).values())
+
+
+def build_host(csr: CSR, schedule: Schedule, *, layout: Optional[str] = None,
+               sigma: int = SELL_SIGMA, max_blocks: Optional[int] = None,
+               shape_bucket: bool = False, slack: int = 0
+               ) -> Tuple[HostLayout, Optional[int], list]:
+    """The host side of ``SparseTensor.from_csr``: the container it wraps,
+    the index of that container's shared zero tile (None for bsr/dense),
+    and the spare tiles of a mutable one."""
+    if shape_bucket and slack == 0 and layout != "bsr" \
+            and schedule.backend != "dense" and schedule.layout != "sell":
+        container, zero_idx = _bucketed_ell(csr, schedule, max_blocks)
+        return container, zero_idx, []
+    container = SparseTensor.build_container(
+        csr, schedule, layout=layout, sigma=sigma, max_blocks=max_blocks,
+        full_rows=slack > 0)
+    spare: list = []
+    if slack > 0 and isinstance(container, (ELLBSR, SELLBSR)):
+        from .mutate import reserve_slack
+        container, spare = reserve_slack(container, int(slack))
+    zero_idx = (int(container.blocks.shape[0]) - 1
+                if isinstance(container, (ELLBSR, SELLBSR)) else None)
+    if shape_bucket and not isinstance(container, BSR):
+        container = pad_container_to_bucket(container)
+    return container, zero_idx, spare
+
+
 # --------------------------------------------------------- shape bucketing
 
-def _pad_ell_to_bucket(ell: ELLBSR) -> ELLBSR:
+def _ell_cap(bsr: BSR, schedule: Schedule, max_blocks: Optional[int],
+             full_rows: bool) -> Optional[int]:
+    """The ELL slot width ``build_container`` gives ``ELLBSR.from_bsr``."""
+    if full_rows:
+        return None
+    if max_blocks is None and schedule.ell_quantile < 1.0:
+        return ell_block_cap(bsr.blocks_per_row(), schedule.ell_quantile)
+    return max_blocks
+
+
+def _bucketed_ell(csr: CSR, schedule: Schedule,
+                  max_blocks: Optional[int]) -> Tuple[ELLBSR, int]:
+    """``build_container`` then ``pad_container_to_bucket`` for an ELL
+    schedule, the same arrays, with every tile written once, straight into
+    the bucket-sized tile array: no copy of the tiles between the steps.
+    Returns the container and its zero tile's index."""
+    bs = schedule.block_size
+    tiles: list = []
+
+    def alloc(n: int) -> np.ndarray:
+        tiles.append(np.zeros((bucket_edge(n + 1), bs, bs), np.float32))
+        return tiles[0]
+
+    bsr = BSR.from_csr(csr, bs, alloc=alloc)
+    ell = ELLBSR.from_bsr(bsr, _ell_cap(bsr, schedule, max_blocks, False),
+                          blocks=tiles[0][: bsr.n_blocks + 1])
+    return _pad_ell_to_bucket(ell, tiles=tiles[0]), bsr.n_blocks
+
+
+def _pad_ell_to_bucket(ell: ELLBSR,
+                       tiles: Optional[np.ndarray] = None) -> ELLBSR:
     """Pad an ELL container's dims (block-rows, slot width, block count,
     block-columns) up to bucket edges; numerics unchanged — pad slots point
-    at the existing all-zeros block and pad output rows are sliced away."""
+    at the existing all-zeros block and pad output rows are sliced away.
+    ``tiles``, given, is the bucket-sized tile array that already starts
+    with ``ell.blocks``; it becomes the container's, uncopied."""
     n_br, mb = ell.block_indices.shape
     nb = ell.blocks.shape[0]            # includes the trailing zero block
     bs = ell.block_size
@@ -329,8 +421,12 @@ def _pad_ell_to_bucket(ell: ELLBSR) -> ELLBSR:
     bi[:n_br, :mb] = ell.block_indices
     bc = np.zeros((n_br_p, mb_p), np.int32)
     bc[:n_br, :mb] = ell.block_cols
-    blocks = np.zeros((nb_p, bs, bs), np.float32)
-    blocks[:nb] = ell.blocks
+    if tiles is None:
+        blocks = np.zeros((nb_p, bs, bs), np.float32)
+        blocks[:nb] = ell.blocks
+    else:
+        assert tiles.shape[0] == nb_p
+        blocks = tiles
     vc = np.zeros(n_br_p, np.int32)
     vc[:n_br] = ell.valid_counts
     return ELLBSR(bi, bc, blocks, (n_br_p * bs, n_bc_p * bs), bs, vc)

@@ -26,10 +26,9 @@ KERNEL = 'custom_call_target="tpu_custom_call"'
 
 
 @pytest.fixture(scope="module")
-def one_chip():
-    """One chip of a described v5e:2x2, with the persistent compilation
-    cache off: an entry compiled for a described chip cannot be read back
-    without one."""
+def topo():
+    """A described v5e:2x2, with the persistent compilation cache off: an
+    entry compiled for a described chip cannot be read back without one."""
     os.environ.setdefault("TPU_LOG_DIR", "disabled")
     from jax.experimental import topologies
     from jax.experimental.compilation_cache import compilation_cache
@@ -37,13 +36,26 @@ def one_chip():
     jax.config.update("jax_enable_compilation_cache", False)
     compilation_cache.reset_cache()
     try:
-        topo = topologies.get_topology_desc(platform="tpu",
+        desc = topologies.get_topology_desc(platform="tpu",
                                             topology_name="v5e:2x2")
     except Exception as e:  # no TPU compiler in this installation
         jax.config.update("jax_enable_compilation_cache", was)
         pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
-    yield SingleDeviceSharding(topo.devices[0])
+    yield desc
     jax.config.update("jax_enable_compilation_cache", was)
+
+
+@pytest.fixture(scope="module")
+def one_chip(topo):
+    """One chip of the described v5e:2x2."""
+    return SingleDeviceSharding(topo.devices[0])
+
+
+@pytest.fixture(scope="module")
+def four_chips(topo):
+    """The described v5e:2x2 as the sharded path's 1-D ``shards`` mesh."""
+    from repro.launch.mesh import SHARD_AXIS, auto_mesh
+    return auto_mesh((4,), (SHARD_AXIS,), topo.devices)
 
 
 def _spec(sharding, shape, dtype):
@@ -150,3 +162,28 @@ def test_spadd_compiles_for_v5e(one_chip):
         *[_spec(one_chip, (n_c + 1, bs, bs), F32)] * 2,
         backend="pallas").compile()
     assert _launches(compiled) == len(row_ranges(n_c, tables)) > 1
+
+
+def test_sharded_matvec_compiles_for_four_v5e(four_chips):
+    """hpcg.cg_4chip's launch: the 96x96x384 stencil in four row shards,
+    each the 4,096 x 12 ELL grid over 32,768 bs=256 tiles (8.6 GB a chip),
+    x replicated. One shard_map program: each chip runs the same split
+    ELL launches as a one-chip plan, and no collective is compiled in."""
+    from jax.sharding import NamedSharding, PartitionSpec as P
+    from repro.launch.mesh import SHARD_AXIS
+    from repro.sparse.ops_builtin import _sharded_matvec_exec
+    rows = NamedSharding(four_chips, P(SHARD_AXIS))
+    n_br, mb, nb, bs = 4096, 12, 32768, 256
+    arrays = {"block_indices": _spec(rows, (4, n_br, mb), I32),
+              "block_cols": _spec(rows, (4, n_br, mb), I32),
+              "blocks": _spec(rows, (4, nb, bs, bs), F32)}
+    xb = _spec(NamedSharding(four_chips, P()), (16384 * bs,), F32)
+    compiled = _sharded_matvec_exec(four_chips, "ell", "pallas", False,
+                                    886294).lower(arrays, xb).compile()
+    assert _launches(compiled) == len(row_ranges(n_br, [(n_br, mb)] * 2)) > 1
+    text = compiled.as_text()
+    for collective in ("all-gather", "all-reduce", "collective-permute",
+                       "all-to-all"):
+        assert collective not in text
+    assert compiled.memory_analysis().argument_size_in_bytes \
+        < 9 * 10 ** 9    # one shard and x, per chip
