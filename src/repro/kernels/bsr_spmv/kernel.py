@@ -3,16 +3,24 @@ adapted per §4.4 / DESIGN §2).
 
 Schedules
   ELL (global padding, DESIGN §2.2)
-    grid = (n_block_rows, max_blocks_per_row); the slot axis is innermost so
-    the output block-row stays resident in VMEM across accumulation steps.
-    Scalar-prefetched ``block_indices`` / ``block_cols`` drive the BlockSpec
-    index maps: the A tile for grid cell (i, j) is ``blocks[idx[i, j]]`` and
-    the x segment is ``x[cols[i, j]]`` — data-dependent HBM->VMEM DMA with no
-    data-dependent control flow in the kernel body. Padding slots point at a
-    trailing all-zeros block (ELLBSR invariant), so irregular rows cost dead
-    tile work (the counters' ``padding_fraction``) instead of branches: the
-    paper's branch-misprediction bottleneck transformed into a measurable,
-    tree-visible quantity.
+    SpMV (``bsr_spmv_pallas``, where 128 divides bs): grid = (n_block_rows,)
+    and a tile stream. Scalar-prefetched ``block_indices`` / ``block_cols``
+    / ``valid_counts`` name each row's valid tiles ``blocks[idx[i, j]]``
+    and x segments ``x[cols[i, j]]`` for j < ``valid_counts[i]``; the kernel
+    copies them itself from HBM into a ring of ``STREAM_DEPTH`` VMEM slots,
+    keeping STREAM_DEPTH - 1 copies in flight while the VPU multiplies the
+    one that landed, and the stream runs on across rows. Padding slots and
+    padded rows cost no DMA and no VPU pass: ``padding_fraction`` stays a
+    counted property of the layout (the plan counts the slots a launch
+    skipped, ``kernel.ell_stream.skipped``) but the kernel no longer pays
+    it. Tiles are addressed through ``block_indices`` only, so a container
+    whose inserts appended tiles out of row order streams the same way.
+    SpMM, and SpMV at other block sizes (Mosaic copies a tile by hand only
+    where its lanes are whole 128-lane groups): grid = (n_block_rows,
+    max_blocks_per_row), the slot axis innermost so the output block-row
+    stays resident in VMEM, and the same tables drive the BlockSpec index
+    maps. Padding slots there point at a trailing all-zeros block (ELLBSR
+    invariant), so irregular rows cost dead tile work instead of branches.
 
   SELL (sliced padding, DESIGN §2.3)
     grid = (n_cells,) — a ragged schedule flattened on the host. Three
@@ -47,8 +55,10 @@ Schedules
 VMEM per grid cell: double-buffered A tile, x tile and output tile,
 2 x (bs*bs + bs*k + bs*k) * 4B for SpMM (at bs=128, k=8 that is ~148 KB)
 and 2 x (bs*bs + 2*8*bs) * 4B (a (1, bs) tile pads to 8 sublanes) plus the
-bs * 128 * 4B accumulator for SpMV (bs * bs where 128 does not divide bs;
-at bs=256, ~670 KB in all): far under VMEM either way. bs in {128, 256}
+bs * 128 * 4B accumulator for the slot-grid SpMV (bs * bs where 128 does
+not divide bs); the streamed SpMV holds STREAM_DEPTH x (bs*bs + 8*bs) * 4B
+of ring, its double-buffered output row and the accumulator (at bs=256,
+~1.2 MB in all): far under VMEM either way. bs in {128, 256}
 fills whole vregs and MXU tiles; smaller bs trades padding for partly
 empty lanes (autotune.py arbitrates via the tree model).
 """
@@ -58,10 +68,30 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax import lax
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
 HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _vpu_madd(acc, tile_ref, x_ref, k):
+    """``acc`` plus the products of tile ``k`` of ``tile_ref`` (n, bs, bs)
+    and x row ``k`` of ``x_ref`` (n, 1, bs), on the VPU in f32: for each
+    128-lane group g (one group of ``acc``'s width where 128 does not
+    divide bs), ``acc += A[:, g] * x[g]`` with the x row broadcast over
+    sublanes."""
+    lanes = acc.shape[1]
+    for g in range(tile_ref.shape[-1] // lanes):
+        cut = slice(g * lanes, (g + 1) * lanes)
+        acc += tile_ref[k, :, cut] * x_ref[k, :, cut]
+    return acc
+
+
+def _lane_sum(acc):
+    """The (1, bs) output row of a (bs, lanes) accumulator: a transpose,
+    then a sublane sum."""
+    return jnp.sum(acc.T, axis=0, keepdims=True)
 
 
 def _tile_product(first, last, blk_ref, x_ref, y_ref, acc_ref=None):
@@ -71,10 +101,9 @@ def _tile_product(first, last, blk_ref, x_ref, y_ref, acc_ref=None):
     (``acc_ref`` None): (bs, bs) @ (bs, k) on the MXU at HIGHEST precision,
     added into the resident output tile; the MXU's default f32 pass would
     round both operands to bf16 (~2^-8 relative error), and the f32 API and
-    ``ref.py`` promise f32. SpMV: an f32 multiply-add on the VPU into
-    ``acc_ref`` (module docstring, "Vector layout"), exact f32 products with
-    no MXU pass; the lane sum to the (1, bs) output row runs once, on the
-    row's last cell."""
+    ``ref.py`` promise f32. SpMV: ``_vpu_madd`` into ``acc_ref`` (module
+    docstring, "Vector layout"), exact f32 products with no MXU pass; the
+    lane sum to the (1, bs) output row runs once, on the row's last cell."""
     if acc_ref is None:
         @pl.when(first)
         def _init_tile():
@@ -88,16 +117,11 @@ def _tile_product(first, last, blk_ref, x_ref, y_ref, acc_ref=None):
     def _init_acc():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    lanes = acc_ref.shape[1]
-    acc = acc_ref[...]
-    for g in range(blk_ref.shape[-1] // lanes):
-        cut = slice(g * lanes, (g + 1) * lanes)
-        acc += blk_ref[0, :, cut] * x_ref[0, :, cut]
-    acc_ref[...] = acc
+    acc_ref[...] = _vpu_madd(acc_ref[...], blk_ref, x_ref, 0)
 
     @pl.when(last)
     def _flush():
-        y_ref[0] = jnp.sum(acc_ref[...].T, axis=0, keepdims=True)
+        y_ref[0] = _lane_sum(acc_ref[...])
 
 
 def _ell_kernel(idx_ref, cols_ref, blk_ref, x_ref, y_ref, *acc):
@@ -125,25 +149,131 @@ def _acc_scratch(bs: int, vector: bool) -> list:
     return [pltpu.VMEM((bs, lanes), jnp.float32)] if vector else []
 
 
+# Tile copies the ELL SpMV stream keeps in its VMEM ring: STREAM_DEPTH - 1
+# are in flight while the VPU multiplies the one that has landed. 4 was the
+# fastest of 2-4 on both solve cells' operands on a v5e (2: 13.5 / 6.89 ms
+# a launch, 3: 11.13 / 5.77, 4: 10.85 / 5.76 at hpcg's 4,096 x 12 grid and
+# kron's 128 x 128, bs 256).
+STREAM_DEPTH = 4
+
+
+def ell_streams(bs: int) -> bool:
+    """Whether ``bsr_spmv_pallas`` streams a block size's valid tiles.
+    Mosaic copies a tile by hand only where its lanes are whole 128-lane
+    groups; other block sizes keep the grid over every slot."""
+    return bs % 128 == 0
+
+
+def _ell_stream_kernel(idx_ref, cols_ref, vc_ref, blocks_hbm, x_hbm, y_ref,
+                       tiles, xs, sems, cur, acc_ref):
+    """One grid step per block-row: the row's ``vc_ref[i]`` valid tiles and
+    x segments, copied from HBM into a ring of ``STREAM_DEPTH`` VMEM slots.
+
+    The stream runs over the whole launch, row after row: tile t of the
+    stream lands in slot ``t % depth``, and consuming tile t first starts
+    the copy of tile ``t + depth - 1`` into the slot tile t - 1 has freed,
+    so a row's last tiles overlap the next rows' first copies. ``cur``
+    (SMEM, kept across the sequential grid) holds the producer's next (row,
+    slot) and the counts of tiles started and consumed. Slots at or past a
+    row's valid count are never read: they cost no copy and no VPU pass."""
+    n_br = pl.num_programs(0)
+    depth = tiles.shape[0]
+    i = pl.program_id(0)
+
+    def copies(slot, tile=0, col=0):
+        return (pltpu.make_async_copy(blocks_hbm.at[tile], tiles.at[slot],
+                                      sems.at[0, slot]),
+                pltpu.make_async_copy(x_hbm.at[col], xs.at[slot],
+                                      sems.at[1, slot]))
+
+    def row_len(r):
+        return vc_ref[jnp.minimum(r, n_br - 1)]
+
+    def start_next():
+        """Start the copies of the producer's next valid tile, if any."""
+        def before(c):
+            r, j, n = c
+            return jnp.logical_and(r < n_br, j >= n)
+
+        def next_row(c):
+            return c[0] + 1, 0, row_len(c[0] + 1)
+
+        r, j, _ = lax.while_loop(before, next_row,
+                                 (cur[0], cur[1], row_len(cur[0])))
+
+        @pl.when(r < n_br)
+        def _start():
+            for cp in copies(cur[2] % depth, idx_ref[r, j], cols_ref[r, j]):
+                cp.start()
+            cur[2] += 1
+
+        cur[0] = r
+        cur[1] = j + 1
+
+    @pl.when(i == 0)
+    def _prologue():
+        for k in range(4):
+            cur[k] = 0
+        for _ in range(depth - 1):
+            start_next()
+
+    acc_ref[...] = jnp.zeros_like(acc_ref)
+
+    def consume(_, carry):
+        start_next()
+        slot = cur[3] % depth
+        for cp in copies(slot):
+            cp.wait()
+        acc_ref[...] = _vpu_madd(acc_ref[...], tiles, xs, slot)
+        cur[3] += 1
+        return carry
+
+    lax.fori_loop(0, vc_ref[i], consume, 0)
+    y_ref[0] = _lane_sum(acc_ref[...])
+
+
 @functools.partial(jax.jit, static_argnames=("interpret",))
 def bsr_spmv_pallas(block_indices: jax.Array, block_cols: jax.Array,
-                    blocks: jax.Array, x_blocks: jax.Array,
-                    interpret: bool = False) -> jax.Array:
-    """y = A @ x with A in ELL-BSR layout.
+                    valid_counts: jax.Array, blocks: jax.Array,
+                    x_blocks: jax.Array, interpret: bool = False) -> jax.Array:
+    """y = A @ x with A in ELL-BSR layout, streaming only the valid tiles.
 
     Args:
-      block_indices: (n_br, mb) int32 — index into ``blocks``; padding slots
-        hold ``blocks.shape[0] - 1`` (the all-zeros block).
+      block_indices: (n_br, mb) int32 — index into ``blocks``; slots at or
+        past a row's valid count are never read.
       block_cols:    (n_br, mb) int32 — block-column of each slot.
-      blocks:        (n_blocks + 1, bs, bs) float32, last block all-zeros.
+      valid_counts:  (n_br,) int32 — valid slots of each row, a prefix.
+      blocks:        (n_blocks + 1, bs, bs) float32.
       x_blocks:      (n_block_cols, bs) float32 — dense vector, blocked.
     Returns:
       (n_br, bs) float32 — blocked result vector.
     """
     n_br = block_indices.shape[0]
     bs = blocks.shape[-1]
-    y = _ell_call(block_indices, block_cols, blocks,
-                  x_blocks.reshape(-1, 1, bs), vector=True, interpret=interpret)
+    xv = x_blocks.reshape(-1, 1, bs)
+    if not ell_streams(bs):
+        return _ell_call(block_indices, block_cols, blocks, xv, vector=True,
+                         interpret=interpret).reshape(n_br, bs)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=3,
+        grid=(n_br,),
+        in_specs=[pl.BlockSpec(memory_space=pl.ANY),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((1, 1, bs), lambda i, idx, cols, vc: (i, 0, 0)),
+        scratch_shapes=[pltpu.VMEM((STREAM_DEPTH, bs, bs), jnp.float32),
+                        pltpu.VMEM((STREAM_DEPTH, 1, bs), jnp.float32),
+                        pltpu.SemaphoreType.DMA((2, STREAM_DEPTH)),
+                        pltpu.SMEM((4,), jnp.int32)]
+        + _acc_scratch(bs, True),
+    )
+    y = pl.pallas_call(
+        _ell_stream_kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((n_br, 1, bs), jnp.float32),
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(block_indices, block_cols, valid_counts, blocks, xv)
     return y.reshape(n_br, bs)
 
 
@@ -165,8 +295,9 @@ def bsr_spmm_pallas(block_indices: jax.Array, block_cols: jax.Array,
 
 def _ell_call(block_indices, block_cols, blocks, x_blocks, *, vector: bool,
               interpret: bool) -> jax.Array:
-    """The ELL pallas_call; x_blocks is (n_bc, bs, k), or (n_bc, 1, bs) with
-    ``vector``. The output block-row has the x tile's shape."""
+    """The ELL grid pallas_call, one step per slot; x_blocks is (n_bc, bs,
+    k), or (n_bc, 1, bs) with ``vector``. The output block-row has the x
+    tile's shape."""
     n_br, mb = block_indices.shape
     bs = blocks.shape[-1]
     tile = tuple(x_blocks.shape[1:])

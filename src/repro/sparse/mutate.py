@@ -288,6 +288,8 @@ def _insert_blocks(st: SparseTensor, mut: Dict, brs: np.ndarray,
             st.arrays["block_cols"].at[(br_a, sl_a)].set(bc_a)
         st.arrays["valid_counts"] = \
             st.arrays["valid_counts"].at[br_a].add(1)
+        if st._stream_tiles is not None:
+            st._stream_tiles += len(br_a)
         host = st._host
         if host is not None:
             host.block_indices[br_a, sl_a] = k_a

@@ -45,7 +45,8 @@ from ..kernels.bsr_spgemm.kernel import (bsr_spgemm_cells_pallas,
 from ..kernels.bsr_spgemm.ops import spgemm_symbolic, spgemm_symbolic_cells
 from ..kernels.bsr_spgemm.ref import ref_cell_gemm, ref_pair_gemm
 from ..kernels.bsr_spmv.kernel import (bsr_spmm_pallas, bsr_spmm_sell_pallas,
-                                       bsr_spmv_pallas, bsr_spmv_sell_pallas)
+                                       bsr_spmv_pallas, bsr_spmv_sell_pallas,
+                                       ell_streams)
 from ..kernels.bsr_spmv.ref import (ref_bsr_spmm, ref_bsr_spmm_sell,
                                     ref_bsr_spmv, ref_bsr_spmv_sell)
 from ..kernels.flash_attention.kernel import flash_attention_pallas
@@ -91,11 +92,18 @@ def _block_x(x: jax.Array, n_cols: int, n_bc: int, bs: int,
     return xb.at[:n_cols].set(x).reshape(n_bc, bs)
 
 
-def _ell_launch(idx, cols, blocks, xb, multi: bool, interpret: bool):
-    """The ELL SpMV/SpMM kernel, split by block-row range to fit SMEM."""
-    kern = bsr_spmm_pallas if multi else bsr_spmv_pallas
+def _ell_launch(idx, cols, vc, blocks, xb, multi: bool, interpret: bool):
+    """The ELL SpMV/SpMM kernel, split by block-row range to fit SMEM. The
+    SpMV streams each row's ``vc`` valid tiles, so its valid-count table
+    rides the split (and its SMEM) beside the slot tables."""
+    if multi:
+        return split_rows(
+            lambda i, c: bsr_spmm_pallas(i, c, blocks, xb,
+                                         interpret=interpret), (idx, cols))
     return split_rows(
-        lambda i, c: kern(i, c, blocks, xb, interpret=interpret), (idx, cols))
+        lambda i, c, v: bsr_spmv_pallas(i, c, v, blocks, xb,
+                                        interpret=interpret),
+        (idx, cols, vc))
 
 
 def _sell_launch(cb, cc, cr, blocks, xb, n_br: int, multi: bool,
@@ -128,7 +136,7 @@ def _matvec_tiles(arrays, layout: str, xb: jax.Array, n_br: int,
     blocks = arrays["blocks"]
     if backend == "jnp":
         return (ref_bsr_spmm if multi else ref_bsr_spmv)(idx, cols, blocks, xb)
-    return _ell_launch(idx, cols, blocks, xb, multi,
+    return _ell_launch(idx, cols, arrays["valid_counts"], blocks, xb, multi,
                        interpret=(backend == "interpret"))
 
 
@@ -205,9 +213,14 @@ def _plan_matvec(operands, schedule: Optional[Schedule], backend: str, *,
         y = _exec_matvec(st, jnp.asarray(x), backend=backend, rhs_tile=tile)
         return y[:true_rows] if true_rows != pad_rows else y
 
+    stream = None
+    if st.layout == "ell" and ell_streams(st.block_size):
+        st.ell_stream()         # counted now, not in a launch
+        stream = st.ell_stream
     return Plan(op=op, schedule=sched, backend=backend, _run=run,
                 operands=(st,),
-                kernel_rhs=None if st.layout == "dense" else "given")
+                kernel_rhs=None if st.layout == "dense" else "given",
+                ell_stream=stream)
 
 
 # ---------------------------------------------------------------------------
@@ -253,8 +266,9 @@ def _exec_matvec_stacked(arrays, xs: jax.Array, layout: str,
         else:
             y = jnp.stack([
                 _ell_launch(arrays["block_indices"][b] + b * nb,
-                            arrays["block_cols"][b] + b * n_bc, flat_blocks,
-                            flat_x, multi, interpret)
+                            arrays["block_cols"][b] + b * n_bc,
+                            arrays["valid_counts"][b], flat_blocks, flat_x,
+                            multi, interpret)
                 for b in range(xb.shape[0])])
     else:  # sell
         n_br = arrays["row_perm"].shape[1]
@@ -388,7 +402,7 @@ def _stack_resident(sts: List, shape_bucket: bool):
         width = max(a["block_indices"].shape[1] for a in A)
         if shape_bucket:
             n_br, width = bucket_edge(n_br), bucket_edge(width)
-        idx, cols = [], []
+        idx, cols, vcs = [], [], []
         for a in A:
             bi, bc = a["block_indices"], a["block_cols"]
             pad2 = ((0, n_br - bi.shape[0]), (0, width - bi.shape[1]))
@@ -396,8 +410,10 @@ def _stack_resident(sts: List, shape_bucket: bool):
             idx.append(jnp.pad(bi, pad2,
                                constant_values=a["blocks"].shape[0] - 1))
             cols.append(jnp.pad(bc, pad2))
+            vcs.append(jnp.pad(a["valid_counts"], pad2[:1]))
         arrays = {"block_indices": jnp.stack(idx),
-                  "block_cols": jnp.stack(cols), "blocks": blocks}
+                  "block_cols": jnp.stack(cols),
+                  "valid_counts": jnp.stack(vcs), "blocks": blocks}
     else:  # sell
         n_cells = max(a["cell_block"].shape[0] for a in A)
         n_br = max(a["row_perm"].shape[0] for a in A)
@@ -422,7 +438,9 @@ def _stack_resident(sts: List, shape_bucket: bool):
                   "cell_row": jnp.stack(cr), "row_perm": jnp.stack(rp),
                   "blocks": blocks}
     return {"arrays": arrays, "shapes": shapes, "layout": layout,
-            "bs": bs, "width": int(n_bc * bs)}
+            "bs": bs, "width": int(n_bc * bs),
+            "stream_tiles": sum(st.ell_stream()[0] for st in sts)
+            if layout == "ell" else 0}
 
 
 def _members_key(kind: str, members: List, schedule: Schedule,
@@ -492,6 +510,9 @@ def _build_matvec_bucket(members: List, schedule: Schedule, sigma: int,
                     edge_dims=ed2)),
                 "block_cols": jnp.asarray(_stack_pad(
                     [h.block_cols for h in hosts], 0, edge_dims=ed2)),
+                "valid_counts": jnp.asarray(_stack_pad(
+                    [h.valid_counts.astype(np.int32) for h in hosts], 0,
+                    edge_dims=ed)),
                 "blocks": jnp.asarray(_stack_pad(
                     [h.blocks.astype(np.float32) for h in hosts], 0.0,
                     edge_dims=ed)),
@@ -529,7 +550,9 @@ def _build_matvec_bucket(members: List, schedule: Schedule, sigma: int,
             n_bc = bucket_edge(n_bc)
         width = n_bc * bs
     return {"arrays": arrays, "shapes": shapes, "layout": layout,
-            "bs": bs, "width": width}
+            "bs": bs, "width": width,
+            "stream_tiles": sum(int(h.valid_counts.sum()) for h in hosts)
+            if layout == "ell" else 0}
 
 
 def _plan_matvec_rhs_stacked(members: List, schedule: Schedule,
@@ -593,7 +616,19 @@ def _plan_matvec_rhs_stacked(members: List, schedule: Schedule,
 
     return Plan(op=op, schedule=schedule, backend=backend, _run=run,
                 operands=inner.operands, n_members=n,
-                kernel_rhs=inner.kernel_rhs and "stacked")
+                kernel_rhs=inner.kernel_rhs and "stacked",
+                ell_stream=inner.ell_stream)
+
+
+def _built_stream(built: Dict):
+    """``Plan.ell_stream`` of a stacked launch: its members' valid tiles,
+    counted on the host as the stack was built, and the stacked grid's
+    slots; None where its launch does not stream."""
+    if built["layout"] != "ell" or not ell_streams(built["bs"]):
+        return None
+    counts = (built["stream_tiles"],
+              int(built["arrays"]["block_indices"].size))
+    return lambda: counts
 
 
 def _pad_member_axis(built: Dict, b_pad: int) -> Dict:
@@ -675,7 +710,8 @@ def _plan_matvec_bucket(members: List, schedule: Schedule, backend: str, *,
 
     return Plan(op=op, schedule=schedule, backend=backend, _run=run,
                 n_members=len(shapes),
-                kernel_rhs=None if layout == "dense" else "given")
+                kernel_rhs=None if layout == "dense" else "given",
+                ell_stream=_built_stream(built))
 
 
 # ---------------------------------------------------------------------------
@@ -684,9 +720,11 @@ def _plan_matvec_bucket(members: List, schedule: Schedule, backend: str, *,
 
 _SHARDED_EXECS: dict = {}
 
-# the arrays a launch reads, per layout (valid_counts and slice_widths are
-# construction-side bookkeeping the kernels never touch)
-_LAUNCH_FIELDS = {"ell": ("block_indices", "block_cols", "blocks"),
+# the arrays a launch reads, per layout: the ELL SpMV streams each row's
+# valid_counts tiles (slice_widths is construction-side bookkeeping the
+# kernels never touch)
+_LAUNCH_FIELDS = {"ell": ("block_indices", "block_cols", "valid_counts",
+                          "blocks"),
                   "sell": ("cell_block", "cell_col", "cell_row", "row_perm",
                            "blocks"),
                   "dense": ("dense",)}
@@ -801,6 +839,8 @@ def _launch_arrays(host, layout: str, target: Dict[str, Tuple[int, ...]]
                                        target["block_indices"], zero)
         out["block_cols"] = _pad_to(host.block_cols.astype(np.int32),
                                     target["block_cols"], 0)
+        out["valid_counts"] = _pad_to(host.valid_counts.astype(np.int32),
+                                      target["valid_counts"], 0)
         return out
     cr = host.cell_row.astype(np.int32)
     out["cell_block"] = _pad_to(host.cell_block.astype(np.int32),
@@ -842,6 +882,7 @@ def _build_on_mesh(members: List, schedule: Schedule, sigma: int,
         width = (bucket_edge(n_bc) if shape_bucket else n_bc) * bs
     devices = list(mesh.devices.flat)
     pieces: Dict[str, List] = {k: [] for k in target}
+    tiles = 0
     for i, (m, dev) in enumerate(zip(members, devices)):
         with obs_trace.span("shard_build", shard=i, n_shards=len(members)):
             host = (build_host(m, schedule, sigma=sigma,
@@ -850,6 +891,8 @@ def _build_on_mesh(members: List, schedule: Schedule, sigma: int,
             placed = {k: jax.device_put(v[None], dev) for k, v in
                       _launch_arrays(host, layout, target).items()}
             jax.block_until_ready(placed)
+            if layout == "ell":
+                tiles += int(host.valid_counts.sum())
             del host
         for k, v in placed.items():
             pieces[k].append(v)
@@ -857,7 +900,8 @@ def _build_on_mesh(members: List, schedule: Schedule, sigma: int,
         mesh, jax.sharding.PartitionSpec(SHARD_AXIS))
     arrays = {k: jax.make_array_from_single_device_arrays(
         (len(members),) + target[k], sharding, pieces[k]) for k in target}
-    return {"arrays": arrays, "layout": layout, "width": int(width)}
+    return {"arrays": arrays, "layout": layout, "width": int(width),
+            "stream_tiles": tiles}
 
 
 def _plan_matvec_sharded(operands, schedules, backend: str, *, op: str,
@@ -946,6 +990,7 @@ def _plan_matvec_sharded(operands, schedules, backend: str, *, op: str,
         homes = [{d} for d in slots]
         r_max = max(true_rows)
         kernel_rhs = None if layout == "dense" else "given"
+        stream = _built_stream({**built, "bs": schedules[0].block_size})
 
         def run(x):
             x = check_x(x)
@@ -1007,6 +1052,14 @@ def _plan_matvec_sharded(operands, schedules, backend: str, *, op: str,
                for st, s in zip(placed, schedules)]
         kernel_rhs = None if all(q.kernel_rhs is None for q in sub) \
             else "given"
+        streams = [q.ell_stream for q in sub if q.ell_stream is not None]
+
+        def stream():
+            counts = [f() for f in streams]
+            return (sum(c[0] for c in counts), sum(c[1] for c in counts))
+
+        if not streams:
+            stream = None
 
         def run(x):
             x = check_x(x)
@@ -1032,7 +1085,7 @@ def _plan_matvec_sharded(operands, schedules, backend: str, *, op: str,
                 operands=(sst,) if sst is not None else (),
                 n_members=n_shards, n_shards=n_shards,
                 shard_devices=[frozenset(h) for h in homes],
-                kernel_rhs=kernel_rhs)
+                kernel_rhs=kernel_rhs, ell_stream=stream)
 
 
 # ---------------------------------------------------------------------------

@@ -21,13 +21,16 @@ timed: the measurement feeds the ``launch_ms.<op>`` latency histogram, the
 ``Plan.last_measured_s`` field the selector's residual feedback reads.
 A launch whose SpMV/SpMM kernel ran (``pallas`` or ``interpret``) also
 ticks ``kernel.tile_product.vpu`` or ``.mxu``, as ``Plan.tile_product``
-reads it from the launch's runtime input.
+reads it from the launch's runtime input. A VPU launch of the streaming ELL
+SpMV adds the valid tiles it copied to ``kernel.ell_stream.tiles`` and the
+grid slots it did not stream to ``kernel.ell_stream.skipped``, from counts
+the plan holds on the host (``Plan.ell_stream``).
 """
 from __future__ import annotations
 
 import dataclasses
 import time
-from typing import Callable, Dict, List, Optional, Sequence
+from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
@@ -96,6 +99,10 @@ class Plan:
     # multi-RHS launch), None where no such kernel runs (a dense operand,
     # a mesh program). Read by ``tile_product``.
     kernel_rhs: Optional[str] = None
+    # (valid tiles, grid slots) of the operand a vector launch streams
+    # through the ELL SpMV kernel, counted on the host (no device read);
+    # None where no launch streams. Read by ``execute``.
+    ell_stream: Optional[Callable[[], Tuple[int, int]]] = None
     # wall-clock of the most recent execute (set per call). With the NaN
     # guard on (default) the guarded run synchronizes on the result, so
     # this is end-to-end launch latency, not dispatch-only.
@@ -131,6 +138,10 @@ class Plan:
         reg.observe(f"launch_ms.{self.op}", dt * 1e3)
         if tile is not None:
             reg.inc(f"kernel.tile_product.{tile}")
+        if tile == "vpu" and self.ell_stream is not None:
+            tiles, slots = self.ell_stream()
+            reg.inc("kernel.ell_stream.tiles", tiles)
+            reg.inc("kernel.ell_stream.skipped", slots - tiles)
         return out
 
     def tile_product(self, *runtime) -> Optional[str]:

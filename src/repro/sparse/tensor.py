@@ -87,6 +87,9 @@ class SparseTensor:
         # so ``from_csr`` records it pre-pad; ``blocks.shape[0] - 1`` is
         # only correct for unbucketed containers.
         self._zero_idx: Optional[int] = None
+        # Valid tiles of an ELL operand, counted on the host once and kept
+        # current by the delta path's inserts (``ell_stream``).
+        self._stream_tiles: Optional[int] = None
 
     # -------------------------------------------------------------- pytree
     def tree_flatten(self):
@@ -268,6 +271,18 @@ class SparseTensor:
         if isinstance(obj, CSR):
             return cls.from_csr(obj, schedule=schedule)
         return cls.from_layout(obj, schedule=schedule)
+
+    def ell_stream(self) -> Tuple[int, int]:
+        """An ELL operand's (valid tiles, grid slots): the tiles one SpMV
+        launch streams, and the slots of its padded grid. The count comes
+        from the host container, or once from the device's valid-count
+        table where none is kept."""
+        if self._stream_tiles is None:
+            vc = (self._host.valid_counts if self._host is not None
+                  else self.arrays["valid_counts"])
+            self._stream_tiles = int(np.asarray(vc).sum())
+        return (self._stream_tiles,
+                int(np.prod(self.arrays["block_indices"].shape)))
 
     # ----------------------------------------------------------- mutation
     def apply_delta(self, delta) -> "SparseTensor":

@@ -106,9 +106,11 @@ def test_bsr_sell_zipf_allclose(backend):
 
 
 # Block pattern of the row-structure cases, one entry per block-row: a
-# hub row of five cells, one-cell rows, an empty row (ELL pads it with the
-# zero tile, SELL keeps one zero-tile cell) and a two-cell row.
-ROW_BLOCKS = [(0, 1, 2, 3, 4), (1,), (), (0, 4), (2,)]
+# hub row of five cells (ELL's slot width mb, longer than the SpMV stream's
+# ring), one-cell rows, an empty row (ELL pads it with the zero tile and
+# streams nothing, SELL keeps one zero-tile cell), a two-cell row and a row
+# of mb - 1 cells.
+ROW_BLOCKS = [(0, 1, 2, 3, 4), (1,), (), (0, 4), (2,), (1, 2, 3, 5)]
 
 
 def _block_pattern(bs, seed):
@@ -126,6 +128,21 @@ def _block_pattern(bs, seed):
     return CSR.from_dense(d), d.astype(np.float64), x
 
 
+def _ell_dense64(a):
+    """The float64 dense matrix an ELL container holds: the tiles of each
+    row's valid slots, nothing of its padding."""
+    bs = a.block_size
+    n_br, mb = a.block_indices.shape
+    n_bc = -(-a.shape[1] // bs)
+    d = np.zeros((n_br * bs, n_bc * bs))
+    for r in range(n_br):
+        for j in range(int(a.valid_counts[r])):
+            c = int(a.block_cols[r, j])
+            d[r * bs:(r + 1) * bs, c * bs:(c + 1) * bs] += \
+                a.blocks[a.block_indices[r, j]]
+    return d[:a.shape[0], :a.shape[1]]
+
+
 def _assert_f64_close(y, d64, x):
     """Within f32 rounding of the float64 product, row by row on |A||x|."""
     x64 = x.astype(np.float64)
@@ -135,29 +152,39 @@ def _assert_f64_close(y, d64, x):
 
 
 @pytest.mark.parametrize("bs", [32, 128, 192, 256])
-@pytest.mark.parametrize("layout", ["ell", "sell"])
+@pytest.mark.parametrize("layout", ["ell", "ell_capped", "sell"])
 def test_spmv_vpu_row_structures(layout, bs):
     """The SpMV tile product (VPU, f32) against the float64 oracle on rows
     of one cell, of many cells and of zero-tile padding only; SELL row
     changes and the SMEM-split launches (one row, or one cell, a launch:
-    SELL rows then straddle launches) included."""
+    SELL rows then straddle launches) included. ELL rows hold 0, 1, mb - 1
+    and mb valid tiles, which bs 128 and 256 stream and bs 32 and 192 run
+    over the whole slot grid; ``ell_capped`` cuts the long rows at 3 slots
+    (a q < 1 schedule), and the product is that of the tiles kept."""
     from repro.kernels.bsr_spmv import kernel as K
     from repro.sparse.smem import SMEM_BUDGET_BYTES, split_cells, split_rows
     csr, d64, x = _block_pattern(bs, bs)
     n = x.shape[0]
     n_bc = -(-n // bs)
     xb = jnp.asarray(np.pad(x, (0, n_bc * bs - n)).reshape(n_bc, bs))
-    if layout == "ell":
-        a = bsr_spmv.ops.prepare(csr, bs)
+    if layout.startswith("ell"):
+        cap = 3 if layout == "ell_capped" else None
+        a = bsr_spmv.ops.prepare(csr, bs, max_blocks=cap)
         zero = a.blocks.shape[0] - 1
         assert (a.block_indices == zero).sum() >= 4    # padded slots
-        tables = (jnp.asarray(a.block_indices), jnp.asarray(a.block_cols))
+        mb = a.max_blocks
+        assert {0, 1, mb - 1, mb} <= set(a.valid_counts.tolist())
+        if cap:
+            assert a.valid_counts.sum() < sum(map(len, ROW_BLOCKS))
+            d64 = _ell_dense64(a)
+        tables = (jnp.asarray(a.block_indices), jnp.asarray(a.block_cols),
+                  jnp.asarray(a.valid_counts))
         blocks = jnp.asarray(a.blocks)
 
         def launch(budget):
             return split_rows(
-                lambda i, c: K.bsr_spmv_pallas(i, c, blocks, xb,
-                                               interpret=True),
+                lambda i, c, v: K.bsr_spmv_pallas(i, c, v, blocks, xb,
+                                                  interpret=True),
                 tables, budget=budget)
         perm = None
     else:
@@ -180,15 +207,29 @@ def test_spmv_vpu_row_structures(layout, bs):
         if perm is not None:
             y = jnp.zeros_like(y).at[perm].set(y)
         _assert_f64_close(np.asarray(y).reshape(-1)[:n], d64, x)
+    if layout.startswith("ell") and K.ell_streams(bs):
+        # the stream drops only the padding's + 0 terms: bit for bit the
+        # product of the grid over every slot
+        grid = K._ell_call(*tables[:2], blocks, xb.reshape(-1, 1, bs),
+                           vector=True, interpret=True)
+        np.testing.assert_array_equal(np.asarray(y).reshape(-1),
+                                      np.asarray(grid).reshape(-1))
 
 
+@pytest.mark.parametrize("sizes", ["equal", "unequal"])
 @pytest.mark.parametrize("layout", ["ell", "sell"])
-def test_stacked_spmv_vpu_matches_f64(layout):
+def test_stacked_spmv_vpu_matches_f64(layout, sizes):
     """A stacked bucket (one program, B unrolled SpMV launches into one
-    tile stack) at bs 128 against the float64 oracle, member by member."""
+    tile stack) at bs 128 against the float64 oracle, member by member;
+    ``unequal`` cuts the second member to 400 rows, so the stack pads its
+    block-rows (valid count 0 in the ELL stream)."""
     from repro.core.autotune import Schedule
     from repro.sparse import plan_bucket
     mats = [_block_pattern(128, s) for s in (1, 2)]
+    if sizes == "unequal":
+        _, d64, x = mats[1]
+        d64, x = d64[:400, :400], x[:400]
+        mats[1] = (CSR.from_dense(d64.astype(np.float32)), d64, x)
     sched = (Schedule("bsr", 128, 1.0) if layout == "ell"
              else Schedule("bsr", 128, 1.0, layout="sell", slice_height=2))
     ys = plan_bucket("spmv", [m[0] for m in mats], sched,
@@ -251,6 +292,73 @@ def test_tile_product_provenance_and_counters():
     assert counts() == (vpu0 + 5, mxu0 + 3)
     assert [e["args"]["tile_product"] for e in tr.events()
             if e["type"] == "launch"] == ["mxu"]
+
+
+def test_ell_stream_counters_hand_count():
+    """``kernel.ell_stream.tiles`` / ``.skipped`` per streamed SpMV launch,
+    against a hand count of ROW_BLOCKS at bs 128: 13 valid tiles in a
+    bucketed 6 x 6 slot grid (shape bucketing rounds mb 5 up to 6), so 23
+    slots skipped a launch; a stacked bucket of two is 26 of 2 x 36. SpMM,
+    jnp and the bs-32 slot grid stream nothing and count nothing."""
+    from repro.core.autotune import Schedule
+    from repro.obs import default_registry
+    from repro.sparse import plan, plan_bucket
+    csr, _, x = _block_pattern(128, 7)
+    assert sum(map(len, ROW_BLOCKS)) == 13 and len(ROW_BLOCKS) == 6
+    sched = Schedule("bsr", 128, 1.0)
+    reg = default_registry()
+
+    def counts():
+        return (reg.get("kernel.ell_stream.tiles"),
+                reg.get("kernel.ell_stream.skipped"))
+
+    spmv = plan("spmv", (csr,), schedule=sched, backend="interpret")
+    bucket = plan_bucket("spmv", [csr, csr], sched, backend="interpret")
+    quiet = [plan("spmv", (csr,), schedule=sched, backend="jnp"),
+             plan("spmv", (csr,), schedule=Schedule("bsr", 32, 1.0),
+                  backend="interpret")]
+    t0, s0 = counts()
+    spmv.execute(x)
+    spmv.execute(x)
+    assert counts() == (t0 + 26, s0 + 46)
+    bucket.execute([x, -x])
+    assert counts() == (t0 + 52, s0 + 92)
+    spmv.execute(np.stack([x, -x], axis=1))     # a matrix RHS: the SpMM
+    for p in quiet:
+        p.execute(x)
+    assert counts() == (t0 + 52, s0 + 92)
+
+
+def test_ell_stream_after_out_of_order_insert():
+    """A structural insert claims a spare tile that sits after every row's
+    own tiles (out of row order) and a free slot of an empty row: the
+    streamed SpMV on the same live plan reads it through block_indices,
+    matches the float64 product of the updated matrix, and its stream
+    count grows by the inserted tile."""
+    from repro.obs import default_registry
+    from repro.sparse import Delta, MutableMatrix, PreparedStore, plan
+    csr, _, x = _block_pattern(128, 9)
+    store = PreparedStore()
+    mm = MutableMatrix(csr, store=store, slack=2)
+    p = plan("spmv", (csr,), backend="interpret", store=store,
+             block_size=128)
+    st = p.operands[0]
+    tiles0 = st.ell_stream()[0]
+    row = ROW_BLOCKS.index(())             # the empty block-row
+    mm.apply_delta(Delta(np.array([row * 128 + 5]), np.array([3 * 128 + 7]),
+                         np.array([2.5], np.float32)))
+    host = st.to_host()
+    k = int(host.block_indices[row, 0])
+    assert host.valid_counts[row] == 1 and k > host.block_indices[-1].max(
+        where=np.arange(host.max_blocks) < host.valid_counts[-1], initial=0)
+    assert st.ell_stream()[0] == tiles0 + 1
+    reg = default_registry()
+    t0 = reg.get("kernel.ell_stream.tiles")
+    y = p.execute(x)
+    assert reg.get("kernel.ell_stream.tiles") == t0 + tiles0 + 1
+    d64 = np.asarray(csr.to_dense(), np.float64)
+    assert d64[row * 128 + 5, 3 * 128 + 7] == 2.5
+    _assert_f64_close(y, d64, x)
 
 
 def test_sell_padding_beats_global_ell_on_zipf():
@@ -433,8 +541,9 @@ def test_bsr_spmv_dtypes(dtype, tol):
     from repro.kernels.bsr_spmv.kernel import bsr_spmv_pallas
     n_bc = -(-128 // 32)
     xb = jnp.asarray(np.pad(x, (0, n_bc * 32 - 128)).reshape(n_bc, 32), dtype)
-    y = np.asarray(bsr_spmv_pallas(idx, cols, blocks.astype(dtype), xb,
-                                   interpret=True), dtype=np.float32)
+    y = np.asarray(bsr_spmv_pallas(idx, cols, jnp.asarray(ell.valid_counts),
+                                   blocks.astype(dtype), xb, interpret=True),
+                   dtype=np.float32)
     ref = bsr_spmv.ops.spmv_oracle(csr, x)
     scale = max(np.abs(ref).max(), 1e-6)
     np.testing.assert_allclose(y.reshape(-1)[:128] / scale, ref / scale,
@@ -480,11 +589,15 @@ def test_split_matvec_launch_matches_oracle(layout, k):
     xb = jnp.asarray(xp.reshape((n_bc, bs) + X.shape[1:]))
     if layout == "ell":
         a = bsr_spmv.ops.prepare(csr, bs)
-        kern = K.bsr_spmv_pallas if k is None else K.bsr_spmm_pallas
         tables = (jnp.asarray(a.block_indices), jnp.asarray(a.block_cols))
         blocks = jnp.asarray(a.blocks)
-        y = split_rows(lambda i, c: kern(i, c, blocks, xb, interpret=True),
-                       tables, budget=TINY_2D)
+        if k is None:   # the SpMV's valid-count table rides the split
+            tables += (jnp.asarray(a.valid_counts),)
+            y = split_rows(lambda i, c, v: K.bsr_spmv_pallas(
+                i, c, v, blocks, xb, interpret=True), tables, budget=TINY_2D)
+        else:
+            y = split_rows(lambda i, c: K.bsr_spmm_pallas(
+                i, c, blocks, xb, interpret=True), tables, budget=TINY_2D)
         budget = TINY_2D
     else:
         a = bsr_spmv.ops.prepare_sell(csr, bs, 4, 16)

@@ -96,7 +96,9 @@ def _launches(compiled) -> int:
 # ell bs=128: n_br 2048 (two 512 KiB tables unsplit: over SMEM);
 # ell bs=256: the selector's pick for the stencil; sell bs=32 and 128:
 # 196,608 cells (2.25 MiB of cell streams unsplit). spmv runs the VPU tile
-# product (its lane-group accumulator and the row flush), spmm the MXU one.
+# product (its lane-group accumulator and the row flush), spmm the MXU one;
+# the ELL spmv streams each row's valid tiles through its VMEM ring, its
+# valid-count table split beside the slot tables.
 MATVEC = [("ell", 128, "spmv"), ("ell", 128, "spmm"), ("ell", 256, "spmv"),
           ("sell", 32, "spmv"), ("sell", 32, "spmm"), ("sell", 128, "spmv")]
 
@@ -107,7 +109,7 @@ def test_matvec_compiles_for_v5e(one_chip, layout, bs, op):
     if layout == "ell":
         n_br, mb = n // bs, 12
         st = _ell(one_chip, n, bs, n_br, mb, {128: 24576, 256: 12288}[bs])
-        tables = [(n_br, mb)] * 2
+        tables = [(n_br, mb)] * 2 + ([(n_br,)] if op == "spmv" else [])
     else:
         n_br, n_cells = n // bs, 196608
         st = _sell(one_chip, n, bs, n_br, n_cells, n_cells)
@@ -121,15 +123,16 @@ def test_matvec_compiles_for_v5e(one_chip, layout, bs, op):
 
 def test_stacked_matvec_compiles_for_v5e(one_chip):
     """A mixed-content bucket of two 48^3 stencils: one program, each
-    member's ELL launch split by block-row range."""
+    member's streamed ELL SpMV split by block-row range."""
     b, n, bs, n_br, mb, nb = 2, 131072, 128, 1024, 12, 8192
     arrays = {"block_indices": _spec(one_chip, (b, n_br, mb), I32),
               "block_cols": _spec(one_chip, (b, n_br, mb), I32),
+              "valid_counts": _spec(one_chip, (b, n_br), I32),
               "blocks": _spec(one_chip, (b, nb, bs, bs), F32)}
     xs = _spec(one_chip, (b, n), F32)
     compiled = _exec_matvec_stacked.lower(arrays, xs, layout="ell",
                                           backend="pallas").compile()
-    per_member = len(row_ranges(n_br, [(n_br, mb)] * 2))
+    per_member = len(row_ranges(n_br, [(n_br, mb)] * 2 + [(n_br,)]))
     assert per_member > 1
     assert _launches(compiled) == b * per_member
 
@@ -176,11 +179,13 @@ def test_sharded_matvec_compiles_for_four_v5e(four_chips):
     n_br, mb, nb, bs = 4096, 12, 32768, 256
     arrays = {"block_indices": _spec(rows, (4, n_br, mb), I32),
               "block_cols": _spec(rows, (4, n_br, mb), I32),
+              "valid_counts": _spec(rows, (4, n_br), I32),
               "blocks": _spec(rows, (4, nb, bs, bs), F32)}
     xb = _spec(NamedSharding(four_chips, P()), (16384 * bs,), F32)
     compiled = _sharded_matvec_exec(four_chips, "ell", "pallas", False,
                                     886294).lower(arrays, xb).compile()
-    assert _launches(compiled) == len(row_ranges(n_br, [(n_br, mb)] * 2)) > 1
+    assert _launches(compiled) == len(
+        row_ranges(n_br, [(n_br, mb)] * 2 + [(n_br,)])) > 1
     text = compiled.as_text()
     for collective in ("all-gather", "all-reduce", "collective-permute",
                        "all-to-all"):
