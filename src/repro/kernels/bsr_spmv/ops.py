@@ -91,15 +91,14 @@ def bsr_spmv(a: SparseLayout, x: jax.Array, backend: str = "auto") -> jax.Array:
     return plan("spmv", (a,), backend=backend).execute(x)
 
 
-def bsr_spmm(a: SparseLayout, X: jax.Array, backend: str = "auto",
-             rhs_tile: int | None = None) -> jax.Array:
+def bsr_spmm(a: SparseLayout, X: jax.Array, backend: str = "auto"
+             ) -> jax.Array:
     """Y = A @ X for a prepared ELL/SELL container (multi-RHS).
 
     .. deprecated:: use ``plan("spmm", (a,))`` — this shim delegates there.
     """
     from ...sparse import plan
-    return plan("spmm", (a,), backend=backend,
-                rhs_tile=rhs_tile).execute(X)
+    return plan("spmm", (a,), backend=backend).execute(X)
 
 
 def spmv_oracle(csr: CSR, x: np.ndarray) -> np.ndarray:

@@ -13,6 +13,9 @@ from typing import Optional, Sequence
 import jax
 from jax.sharding import AxisType
 
+# the sparse path's shard mesh, owned by ``repro.sparse`` (DESIGN.md §10)
+from ..sparse.partition import SHARD_AXIS, make_shard_mesh  # noqa: F401
+
 
 def auto_mesh(shape: Sequence[int], axes: Sequence[str], devices: Sequence):
     """``jax.make_mesh`` with every axis ``Auto``: the partitioner places
@@ -38,24 +41,6 @@ def make_debug_mesh(data: int = 1, model: int = 1):
     """Tiny mesh over however many local devices exist (tests)."""
     devices = jax.devices()[: data * model]
     return auto_mesh((data, model), ("data", "model"), devices)
-
-
-SHARD_AXIS = "shards"
-
-
-def make_shard_mesh(n_shards: int, devices: Optional[Sequence] = None):
-    """1-D mesh for the sharded sparse path (DESIGN.md §10): one row shard
-    per slot on the ``shards`` axis. Returns None when fewer devices exist
-    than shards — plan_sharded then falls back to round-robin per-shard
-    launches instead of the single shard_map program. Simulate device
-    counts on CPU with ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
-    (the ``launch/dryrun.py`` pattern)."""
-    if devices is None:
-        devices = jax.devices()
-    n_shards = int(n_shards)
-    if n_shards < 1 or len(devices) < n_shards:
-        return None
-    return auto_mesh((n_shards,), (SHARD_AXIS,), devices[:n_shards])
 
 
 def dp_axes(mesh) -> tuple:

@@ -58,7 +58,7 @@ from ..obs import trace as obs_trace
 from .prepared import PreparedStore, raw_content_key
 from .resilience import (GUARDED_EXCEPTIONS, InjectedFault, _note_handled,
                          check_fault, fault_fired, note_recovery)
-from .tensor import SparseTensor
+from .tensor import SparseTensor, pad_arrays
 
 # Spare all-zero blocks reserved per unit of slack: ``slack`` bounds
 # inserts per block-row, SPARE_FACTOR * slack bounds them matrix-wide.
@@ -138,13 +138,12 @@ def add_slack_ell(ell: ELLBSR, slack: int) -> Tuple[ELLBSR, list]:
     old_zero = ell.blocks.shape[0] - 1
     blocks, zero, spare = _grow_blocks(ell.blocks, max(slack, 1) * SPARE_FACTOR)
     n_br, mb = ell.block_indices.shape
-    bi = np.full((n_br, mb + slack), zero, np.int32)
-    bi[:, :mb] = np.where(ell.block_indices == old_zero, zero,
-                          ell.block_indices)
-    bc = np.zeros((n_br, mb + slack), np.int32)
-    bc[:, :mb] = ell.block_cols
-    return (ELLBSR(bi, bc, blocks, ell.shape, ell.block_size,
-                   ell.valid_counts.copy()), spare)
+    grid = {"block_indices": np.where(ell.block_indices == old_zero, zero,
+                                      ell.block_indices).astype(np.int32),
+            "block_cols": ell.block_cols.astype(np.int32)}
+    grid = pad_arrays(grid, dict.fromkeys(grid, (n_br, mb + slack)), zero)
+    return (ELLBSR(grid["block_indices"], grid["block_cols"], blocks,
+                   ell.shape, ell.block_size, ell.valid_counts.copy()), spare)
 
 
 def add_slack_sell(sell: SELLBSR, slack: int) -> Tuple[SELLBSR, list]:
@@ -567,7 +566,7 @@ class MutableMatrix:
     @staticmethod
     def _rekeyable(key, value) -> bool:
         return (isinstance(value, SparseTensor) and isinstance(key, tuple)
-                and len(key) == 7 and key and key[0] == "matvec")
+                and len(key) == 6 and key and key[0] == "matvec")
 
     def _epoch_swap(self, key, new_key, cause: BaseException) -> None:
         """Slack exhausted (or fault injected) on an in-place rekey: the
@@ -592,12 +591,12 @@ class MutableMatrix:
     def _rebuild_entry(self, key) -> Optional[SparseTensor]:
         """Fresh container from the (already mutated) CSR, under the build
         parameters the entry key encodes: ("matvec", ck, sched, layout,
-        sigma, max_blocks, shape_bucket)."""
-        _, _, sched, lay, sigma, max_blocks, shape_bucket = key
+        sigma, max_blocks), always bucket-padded (``ops_builtin.
+        _prepared_matvec``)."""
+        _, _, sched, lay, sigma, max_blocks = key
         return SparseTensor.from_csr(
             self.csr, schedule=sched, layout=lay, sigma=sigma,
-            max_blocks=max_blocks, shape_bucket=bool(shape_bucket),
-            slack=self.slack)
+            max_blocks=max_blocks, shape_bucket=True, slack=self.slack)
 
     def telemetry(self) -> Dict[str, int]:
         return ordered({

@@ -8,16 +8,17 @@ shared across every plan with the same (schedule, backend, shapes), which
 is exactly the schedule-bucket compile-key property the selector batches
 around.
 
-The zero-rebuild serving path (DESIGN.md §9) rides on two hooks threaded
-through every planner:
+The zero-rebuild serving path (DESIGN.md §9) rides on two properties of
+every planner:
 
 * ``store`` — a ``PreparedStore``; a warm hit returns the finished
   device-resident operands (prepared ``SparseTensor``, staged spgemm/spadd
   symbolic products, stacked bucket arrays) and skips host prep entirely.
-* ``shape_bucket`` (default on) — prepared containers are padded up to
-  power-of-two-ish bucket edges so differing matrices present identical
-  leaf shapes + static meta to the jitted executors: one compiled program
-  serves the whole shape bucket instead of retracing per matrix.
+* shape buckets — prepared containers are padded up to power-of-two-ish
+  bucket edges (the launch format's one padding rule, ``tensor.pad_arrays``)
+  so differing matrices present identical leaf shapes + static meta to the
+  jitted executors: one compiled program serves the whole shape bucket
+  instead of retracing per matrix.
 
 All four bsr ops register bucket planners: a whole same-schedule bucket is
 padded to common (edge-rounded) shapes, stacked along a leading axis, and
@@ -29,14 +30,14 @@ tests can assert a bucket compiles once and launches once.
 from __future__ import annotations
 
 import functools
-from typing import Callable, Dict, List, Optional, Sequence, Tuple
+from typing import Callable, Dict, List, NamedTuple, Optional, Sequence, Tuple
 
 import jax
 import jax.numpy as jnp
 import numpy as np
 
 from ..core.autotune import SELL_SIGMA, Schedule, select_moe_block_size
-from ..core.csr import BSR, CSR, ELLBSR, SELLBSR
+from ..core.csr import BSR, CSR
 from ..kernels.bsr_spadd.kernel import bsr_spadd_pallas
 from ..kernels.bsr_spadd.ops import spadd_symbolic
 from ..kernels.bsr_spadd.ref import ref_block_union_add
@@ -57,11 +58,13 @@ from ..kernels.moe_gmm.ref import ref_gmm
 from ..obs import default_registry, trace as obs_trace
 from .plan import Plan, _bump_trace
 from .prepared import PreparedStore, array_key, bucket_edge, content_key
+from .partition import SHARD_AXIS, make_shard_mesh
 from .registry import register_op
 from .resilience import check_fault, dense_ref_cap, register_dense_ref
 from .smem import split_cells, split_rows
-from .tensor import (ShardedMeta, ShardedSparseTensor, SparseTensor,
-                     build_host, container_shapes)
+from .tensor import (LAUNCH_FIELDS, ShardedMeta, ShardedSparseTensor,
+                     SparseTensor, bucket_target, build_host, common_shape,
+                     container_arrays, container_shapes, pad_arrays, pad_to)
 
 MATVEC_LAYOUTS = ("ell", "sell", "dense")
 
@@ -78,6 +81,12 @@ def _cached(store: Optional[PreparedStore], key, builder):
 # ---------------------------------------------------------------------------
 # spmv / spmm — single-operand executor
 # ---------------------------------------------------------------------------
+
+def _rhs_tile(backend: str) -> int:
+    """The static tile a multi-RHS launch pads k to: a lane row on the
+    chip, 8 elsewhere."""
+    return 128 if backend == "pallas" else 8
+
 
 def _block_x(x: jax.Array, n_cols: int, n_bc: int, bs: int,
              rhs_tile: int) -> jax.Array:
@@ -117,9 +126,13 @@ def _sell_launch(cb, cc, cr, blocks, xb, n_br: int, multi: bool,
 
 def _matvec_tiles(arrays, layout: str, xb: jax.Array, n_br: int,
                   backend: str, multi: bool) -> jax.Array:
-    """The ell/sell product on the blocked RHS, as block rows (n_br, bs) or
-    (n_br, bs, k): the Pallas kernels on pallas/interpret, the jnp reference
-    otherwise. One body for the one-chip launch and for each shard."""
+    """The product of one operand's launch arrays: on the blocked RHS as
+    block rows (n_br, bs) or (n_br, bs, k) for ell/sell (the Pallas kernels
+    on pallas/interpret, the jnp reference otherwise), on the flat RHS for
+    dense. One body for the one-chip launch, each member of a stacked
+    bucket and each shard."""
+    if layout == "dense":
+        return arrays["dense"] @ xb.astype(jnp.float32)
     if layout == "sell":
         cb, cc, cr = (arrays["cell_block"], arrays["cell_col"],
                       arrays["cell_row"])
@@ -146,13 +159,13 @@ def _exec_matvec(st: SparseTensor, x: jax.Array, backend: str,
     """y = A @ x (or Y = A @ X for 2-D x) for an ell/sell/dense operand."""
     _bump_trace("matvec")
     meta = st.meta
+    multi = x.ndim == 2
     if meta.layout == "dense":
-        return st.arrays["dense"] @ x.astype(jnp.float32)
+        return _matvec_tiles(st.arrays, "dense", x, 0, backend, multi)
     if meta.layout not in ("ell", "sell"):
         raise ValueError(f"spmv/spmm cannot execute layout {meta.layout!r}")
     bs = meta.block_size
     n_bc = -(-meta.shape[1] // bs)
-    multi = x.ndim == 2
     xb = _block_x(x, meta.shape[1], n_bc, bs, rhs_tile)
     y = _matvec_tiles(st.arrays, meta.layout, xb, meta.n_block_rows, backend,
                       multi)
@@ -162,13 +175,29 @@ def _exec_matvec(st: SparseTensor, x: jax.Array, backend: str,
     return y.reshape(-1)[: meta.shape[0]]
 
 
+def _prepared_matvec(store: Optional[PreparedStore], a: CSR,
+                     ck: Optional[str], schedule: Schedule,
+                     layout: Optional[str] = None, sigma: int = SELL_SIGMA,
+                     max_blocks: Optional[int] = None) -> SparseTensor:
+    """``a`` prepared under ``schedule``, bucket-padded, through the one
+    store key of a prepared matvec operand: the solo and bucket planners
+    share it, so a tenant either warms is warm for both (the serving
+    engine's ``resident(ck)`` slot bit predicts exactly this hit), and
+    ``MutableMatrix`` reads it back to rebuild an entry. ``ck`` is the
+    operand's content key where the caller already hashed it."""
+    key = None if store is None else (
+        "matvec", ck or content_key(a), schedule, layout, sigma, max_blocks)
+    return _cached(store, key, lambda: SparseTensor.from_csr(
+        a, schedule=schedule, layout=layout, sigma=sigma,
+        max_blocks=max_blocks, shape_bucket=True,
+        slack=getattr(a, "mutation_slack", 0)))
+
+
 def _plan_matvec(operands, schedule: Optional[Schedule], backend: str, *,
-                 op: str, rhs_tile: Optional[int] = None,
-                 block_size: int = 128, layout: str = "ell",
+                 op: str, block_size: int = 128, layout: str = "ell",
                  slice_height: int = 8, sigma: int = SELL_SIGMA,
                  max_blocks: Optional[int] = None,
                  store: Optional[PreparedStore] = None,
-                 shape_bucket: bool = True,
                  operand_key: Optional[str] = None, **_) -> Plan:
     (a,) = operands
     if isinstance(a, CSR):
@@ -178,21 +207,15 @@ def _plan_matvec(operands, schedule: Optional[Schedule], backend: str, *,
                                                    slice_height))
         # operand_key: the selector already hashed the matrix bytes for its
         # fingerprint memo — reuse it instead of a second O(nnz) sha1 pass
-        key = None if store is None else (
-            "matvec", operand_key or content_key(a), sched, lay, sigma,
-            max_blocks, bool(shape_bucket))
-        st = _cached(store, key, lambda: SparseTensor.from_csr(
-            a, schedule=sched, layout=lay, slice_height=slice_height,
-            sigma=sigma, max_blocks=max_blocks, shape_bucket=shape_bucket,
-            slack=getattr(a, "mutation_slack", 0)))
+        st = _prepared_matvec(store, a, operand_key, sched, lay, sigma,
+                              max_blocks)
     else:
         st = SparseTensor.wrap(a, schedule)
     if st.layout not in MATVEC_LAYOUTS:
         raise ValueError(f"{op} needs an ell/sell/dense operand, got a "
                          f"{st.layout!r} SparseTensor")
     sched = schedule if schedule is not None else st.meta.schedule
-    tile = rhs_tile if rhs_tile is not None else (128 if backend == "pallas"
-                                                  else 8)
+    tile = _rhs_tile(backend)
     true_rows, true_cols = st.true_shape
     pad_rows, pad_cols = st.meta.shape
 
@@ -233,214 +256,73 @@ def _exec_matvec_stacked(arrays, xs: jax.Array, layout: str,
     """One launch for a whole same-schedule bucket: member axis leading.
 
     ``xs`` is (B, n_bc*bs) or (B, n_bc*bs, k); returns (B, n_br*bs[, k]).
-    One jitted program, one dispatch, every member in flight: the jnp
-    backend vmaps the fused formulation over the member axis; the
-    interpret/pallas backends run the per-member kernel schedule unrolled
-    inside the same program (padding made the member shapes identical).
+    One jitted program, one dispatch, every member in flight, each through
+    the one-operand body ``_matvec_tiles``: vmapped over the member axis on
+    the jnp backend (and for dense), unrolled per member on
+    interpret/pallas (padding made the member shapes identical).
     """
     _bump_trace("matvec_stacked")
     multi = xs.ndim == 3
+    n_br = arrays["row_perm"].shape[1] if layout == "sell" else 0
     if layout == "dense":
-        dense = arrays["dense"]
-        eq = "bij,bjk->bik" if multi else "bij,bj->bi"
-        return jnp.einsum(eq, dense, xs.astype(jnp.float32))
-    bs = arrays["blocks"].shape[-1]
-    n_bc = xs.shape[1] // bs
-    xb = (xs.reshape(xs.shape[0], n_bc, bs, xs.shape[-1]) if multi
-          else xs.reshape(xs.shape[0], n_bc, bs))
-    interpret = backend == "interpret"
-    if backend != "jnp":
-        # the kernels index one flat tile stack and one flat RHS: member b's
-        # tables are offset into them, where slicing member b out of the
-        # stack would copy its tiles for every launch
-        nb = arrays["blocks"].shape[1]
-        flat_blocks = arrays["blocks"].reshape((-1, bs, bs))
-        flat_x = xb.reshape((-1,) + xb.shape[2:])
-    if layout == "ell":
-        if backend == "jnp":
-            def one(idx, cols, blocks, x1):
-                eq = "rmab,rmbk->rak" if multi else "rmab,rmb->ra"
-                return jnp.einsum(eq, blocks[idx], x1[cols])
-            y = jax.vmap(one)(arrays["block_indices"], arrays["block_cols"],
-                              arrays["blocks"], xb)
-        else:
-            y = jnp.stack([
-                _ell_launch(arrays["block_indices"][b] + b * nb,
-                            arrays["block_cols"][b] + b * n_bc,
-                            arrays["valid_counts"][b], flat_blocks, flat_x,
-                            multi, interpret)
-                for b in range(xb.shape[0])])
-    else:  # sell
-        n_br = arrays["row_perm"].shape[1]
-        if backend == "jnp":
-            def one(cb, cc, cr, blocks, perm, x1):
-                eq = "tab,tbk->tak" if multi else "tab,tb->ta"
-                prods = jnp.einsum(eq, blocks[cb], x1[cc])
-                ys = jax.ops.segment_sum(prods, cr, num_segments=n_br)
-                return jnp.zeros_like(ys).at[perm].set(ys)
-            y = jax.vmap(one)(arrays["cell_block"], arrays["cell_col"],
-                              arrays["cell_row"], arrays["blocks"],
-                              arrays["row_perm"], xb)
-        else:
-            outs = []
-            for b in range(xb.shape[0]):
-                ys = _sell_launch(arrays["cell_block"][b] + b * nb,
-                                  arrays["cell_col"][b] + b * n_bc,
-                                  arrays["cell_row"][b], flat_blocks, flat_x,
-                                  n_br, multi, interpret)
-                outs.append(jnp.zeros_like(ys).at[arrays["row_perm"][b]]
-                            .set(ys))
-            y = jnp.stack(outs)
-    if multi:
-        return y.reshape(y.shape[0], y.shape[1] * y.shape[2], y.shape[3])
-    return y.reshape(y.shape[0], -1)
+        xb = xs
+    else:
+        bs = arrays["blocks"].shape[-1]
+        xb = xs.reshape((xs.shape[0], xs.shape[1] // bs, bs) + xs.shape[2:])
+    if backend == "jnp" or layout == "dense":
+        y = jax.vmap(lambda a, x: _matvec_tiles(a, layout, x, n_br, backend,
+                                                multi))(arrays, xb)
+        return y if layout == "dense" else \
+            y.reshape((y.shape[0], y.shape[1] * y.shape[2]) + y.shape[3:])
+    # the kernels index one flat tile stack and one flat RHS: member b's
+    # tile and column tables are offset into them, where slicing member b
+    # out of the stack would copy its tiles for every launch
+    nb, n_bc = arrays["blocks"].shape[1], xb.shape[1]
+    step = {"block_indices": nb, "cell_block": nb, "block_cols": n_bc,
+            "cell_col": n_bc}
+    flat = {"blocks": arrays["blocks"].reshape((-1, bs, bs))}
+    flat_x = xb.reshape((-1,) + xb.shape[2:])
+
+    def member(b):
+        a = {k: v[b] + b * step[k] if k in step else v[b]
+             for k, v in arrays.items() if k != "blocks"}
+        return _matvec_tiles({**a, **flat}, layout, flat_x, n_br, backend,
+                             multi)
+
+    y = jnp.stack([member(b) for b in range(xb.shape[0])])
+    return y.reshape((y.shape[0], y.shape[1] * y.shape[2]) + y.shape[3:])
 
 
-def _stack_pad(mats: Sequence[np.ndarray], fill,
-               edge_dims: Tuple[int, ...] = ()) -> np.ndarray:
-    """Stack host arrays along a new axis 0, padding each to the common max
-    shape with ``fill`` (scalar or per-member list). Dims listed in
-    ``edge_dims`` are additionally rounded up to bucket edges so repeat
-    buckets with nearby member sizes share one stacked jit key."""
-    shape = [max(m.shape[d] for m in mats) for d in range(mats[0].ndim)]
-    for d in edge_dims:
-        shape[d] = bucket_edge(shape[d])
-    fills = fill if isinstance(fill, (list, tuple)) else [fill] * len(mats)
-    out = np.stack([np.full(tuple(shape), f, dtype=mats[0].dtype)
-                    for f in fills])
-    for i, m in enumerate(mats):
-        out[(i,) + tuple(slice(0, s) for s in m.shape)] = m
-    return out
+class _Member(NamedTuple):
+    """A bucket member or shard as a launch sees it."""
+
+    layout: str
+    arrays: Dict          # leaf -> numpy (host) or jax (device) array
+    shape: Tuple[int, int]    # true (unbucketed) shape
+    tiles: int            # valid tiles an ELL SpMV streams (0 otherwise)
 
 
-def _bucket_hosts(members: List, schedule: Schedule, sigma: int) -> List:
-    """Per-member host containers WITHOUT device staging — the stacked
-    launch uploads only the padded stacks, so staging each member's own
-    arrays too would double the host->device traffic."""
-    hosts = []
-    for m in members:
-        if isinstance(m, SparseTensor):
-            hosts.append(m.to_host())
-        elif isinstance(m, CSR):
-            hosts.append(SparseTensor.build_container(m, schedule,
-                                                      sigma=sigma))
-        else:
-            hosts.append(m)   # already an ELLBSR/SELLBSR/dense container
-    return hosts
+def _launch_member(m, schedule: Schedule, sigma: int) -> _Member:
+    """``m`` as its launch arrays: a SparseTensor's device arrays, the
+    bucketed host container a CSR prepares into, or a host container's
+    own. Its valid tiles are counted here, once, on the host."""
+    if isinstance(m, SparseTensor):
+        return _Member(m.layout, m.arrays, m.true_shape,
+                       m.ell_stream()[0] if m.layout == "ell" else 0)
+    shape = tuple(m.shape)
+    if isinstance(m, CSR):
+        m = build_host(m, schedule, sigma=sigma, shape_bucket=True)[0]
+    layout, arrays = container_arrays(m)
+    return _Member(layout, arrays, shape,
+                   int(arrays["valid_counts"].sum()) if layout == "ell"
+                   else 0)
 
 
-def _member_tensors(members: List, schedule: Schedule, sigma: int,
-                    shape_bucket: bool, store, member_keys):
-    """Device-resident prepared ``SparseTensor`` per member, through the
-    SAME store key the single-request planner uses — or None when the
-    bucket cannot take the resident-stacking path (no store, unkeyed or
-    non-CSR members).
-
-    Sharing the single-request key is the point: a tenant warmed by either
-    path (a solo ``plan()`` or any earlier bucket) is warm for both, and
-    the serving engine's ``resident(ck)`` slot bit predicts exactly this
-    hit."""
-    if store is None or member_keys is None:
-        return None
-    keys = list(member_keys)
-    if len(keys) != len(members) or not all(keys):
-        return None
-    if not all(isinstance(m, CSR) for m in members):
-        return None
-    sts = []
-    for m, ck in zip(members, keys):
-        skey = ("matvec", ck, schedule, None, sigma, None,
-                bool(shape_bucket))
-        sts.append(_cached(store, skey, lambda m=m: SparseTensor.from_csr(
-            m, schedule=schedule, sigma=sigma,
-            shape_bucket=bool(shape_bucket),
-            slack=getattr(m, "mutation_slack", 0))))
-    if len({st.layout for st in sts}) != 1:
-        return None
-    return sts
-
-
-def _stack_resident(sts: List, shape_bucket: bool):
-    """Stacked bucket arrays built ON DEVICE from per-member prepared
-    containers (``jnp.pad`` to common edge dims + ``jnp.stack``), or None
-    for layouts without a device formulation.
-
-    This is what makes continuous batching (DESIGN.md §13) pay: under Zipf
-    traffic the exact member composition of a bucket rarely repeats, so the
-    whole-composition cache alone misses constantly — but a composition of
-    *warm members* only costs a device-side stack here (~memcpy), never the
-    host container rebuild + re-upload of the cold path. Pad fills mirror
-    ``_build_matvec_bucket`` exactly: extra ell/sell cells point at the
-    member's own all-zeros block, ``cell_row`` extends the last sorted row
-    (edge mode), ``row_perm`` extends with identity."""
-    layout = sts[0].layout
-    if layout not in ("ell", "sell", "dense"):
-        return None
-    shapes = [st.true_shape for st in sts]
+def _launch_width(layout: str, target: Dict, shapes, bs: int) -> int:
+    """The padded RHS length a stack or mesh of members reads."""
     if layout == "dense":
-        ds = [st.arrays["dense"] for st in sts]
-        tgt = [max(d.shape[i] for d in ds) for i in (0, 1)]
-        if shape_bucket:
-            tgt = [bucket_edge(t) for t in tgt]
-        arrays = {"dense": jnp.stack([
-            jnp.pad(d, ((0, tgt[0] - d.shape[0]), (0, tgt[1] - d.shape[1])))
-            .astype(jnp.float32) for d in ds])}
-        return {"arrays": arrays, "shapes": shapes, "layout": layout,
-                "bs": sts[0].block_size, "width": int(tgt[1])}
-    bs = sts[0].block_size
-    A = [st.arrays for st in sts]
-    nb = max(a["blocks"].shape[0] for a in A)
-    n_bc = -(-max(s[1] for s in shapes) // bs)
-    if shape_bucket:
-        nb, n_bc = bucket_edge(nb), bucket_edge(n_bc)
-    blocks = jnp.stack([
-        jnp.pad(a["blocks"].astype(jnp.float32),
-                ((0, nb - a["blocks"].shape[0]), (0, 0), (0, 0)))
-        for a in A])
-    if layout == "ell":
-        n_br = max(a["block_indices"].shape[0] for a in A)
-        width = max(a["block_indices"].shape[1] for a in A)
-        if shape_bucket:
-            n_br, width = bucket_edge(n_br), bucket_edge(width)
-        idx, cols, vcs = [], [], []
-        for a in A:
-            bi, bc = a["block_indices"], a["block_cols"]
-            pad2 = ((0, n_br - bi.shape[0]), (0, width - bi.shape[1]))
-            # pad slots point at this member's own all-zeros block
-            idx.append(jnp.pad(bi, pad2,
-                               constant_values=a["blocks"].shape[0] - 1))
-            cols.append(jnp.pad(bc, pad2))
-            vcs.append(jnp.pad(a["valid_counts"], pad2[:1]))
-        arrays = {"block_indices": jnp.stack(idx),
-                  "block_cols": jnp.stack(cols),
-                  "valid_counts": jnp.stack(vcs), "blocks": blocks}
-    else:  # sell
-        n_cells = max(a["cell_block"].shape[0] for a in A)
-        n_br = max(a["row_perm"].shape[0] for a in A)
-        if shape_bucket:
-            n_cells, n_br = bucket_edge(n_cells), bucket_edge(n_br)
-        cb, cc, cr, rp = [], [], [], []
-        for a in A:
-            pad1 = ((0, n_cells - a["cell_block"].shape[0]),)
-            cb.append(jnp.pad(a["cell_block"], pad1,
-                              constant_values=a["blocks"].shape[0] - 1))
-            cc.append(jnp.pad(a["cell_col"], pad1))
-            # pad cells extend the member's LAST sorted row (see the host
-            # builder: cell_row must stay nondecreasing for the Pallas
-            # output-residency contract)
-            cr.append(jnp.pad(a["cell_row"], pad1, mode="edge")
-                      if a["cell_row"].shape[0] else
-                      jnp.zeros((n_cells,), a["cell_row"].dtype))
-            perm = a["row_perm"]
-            rp.append(jnp.concatenate([
-                perm, jnp.arange(perm.shape[0], n_br, dtype=perm.dtype)]))
-        arrays = {"cell_block": jnp.stack(cb), "cell_col": jnp.stack(cc),
-                  "cell_row": jnp.stack(cr), "row_perm": jnp.stack(rp),
-                  "blocks": blocks}
-    return {"arrays": arrays, "shapes": shapes, "layout": layout,
-            "bs": bs, "width": int(n_bc * bs),
-            "stream_tiles": sum(st.ell_stream()[0] for st in sts)
-            if layout == "ell" else 0}
+        return int(target["dense"][1])
+    return bucket_edge(max(-(-int(s[1]) // bs) for s in shapes)) * bs
 
 
 def _members_key(kind: str, members: List, schedule: Schedule,
@@ -471,93 +353,47 @@ def _members_key(kind: str, members: List, schedule: Schedule,
 
 
 def _build_matvec_bucket(members: List, schedule: Schedule, sigma: int,
-                         shape_bucket: bool, store=None, member_keys=None):
-    sts = _member_tensors(members, schedule, sigma, shape_bucket, store,
-                          member_keys)
-    if sts is not None:
-        built = _stack_resident(sts, shape_bucket)
-        if built is not None:
-            return built
-    hosts = _bucket_hosts(members, schedule, sigma)
-    kinds = {("dense" if isinstance(h, np.ndarray) else
-              "sell" if isinstance(h, SELLBSR) else "ell") for h in hosts}
-    if len(kinds) != 1:
-        raise ValueError(f"bucket mixes layouts {sorted(kinds)}; a bucket "
+                         store=None, member_keys=None) -> Dict:
+    """The bucket's launch arrays, padded to the shapes its members share
+    and stacked along a leading member axis.
+
+    With a store and a key per member of an all-CSR bucket, the members
+    are the store's device-resident ``SparseTensor``s (``_prepared_matvec``,
+    the solo planner's key) and the stack is built on the device. This is
+    what makes continuous batching (DESIGN.md §13) pay: under Zipf traffic
+    the exact member composition of a bucket rarely repeats, so the
+    whole-composition cache alone misses constantly — but a composition of
+    warm members only costs a device-side stack, never the host container
+    rebuild + re-upload. Otherwise each member pads on the host."""
+    keys = list(member_keys) if member_keys is not None else []
+    if (store is not None and len(keys) == len(members) and all(keys)
+            and all(isinstance(m, CSR) for m in members)):
+        members = [_prepared_matvec(store, m, ck, schedule, sigma=sigma)
+                   for m, ck in zip(members, keys)]
+    views = [_launch_member(m, schedule, sigma) for m in members]
+    layouts = {v.layout for v in views}
+    if len(layouts) != 1:
+        raise ValueError(f"bucket mixes layouts {sorted(layouts)}; a bucket "
                          "shares one Schedule by construction")
-    layout = kinds.pop()
-    # True (unbucketed) output shapes: a SparseTensor member may itself be
-    # shape-bucketed, in which case its host container carries the padded
-    # shape and ``true_shape`` the logical one.
-    shapes = [m.true_shape if isinstance(m, SparseTensor) else h.shape
-              for m, h in zip(members, hosts)]
-    ed = (0,) if shape_bucket else ()
-    ed2 = (0, 1) if shape_bucket else ()
-    if layout == "dense":
-        arrays = {"dense": jnp.asarray(_stack_pad(
-            [np.asarray(h, np.float32) for h in hosts], 0.0,
-            edge_dims=ed2))}
-        bs = schedule.block_size
-        width = int(arrays["dense"].shape[2])
-    else:
-        bs = hosts[0].block_size
-        # Per-member pad slots must keep pointing at that member's own
-        # all-zeros block (its index differs member to member).
-        zero_idx = [h.blocks.shape[0] - 1 for h in hosts]
-        if layout == "ell":
-            arrays = {
-                "block_indices": jnp.asarray(_stack_pad(
-                    [h.block_indices for h in hosts], zero_idx,
-                    edge_dims=ed2)),
-                "block_cols": jnp.asarray(_stack_pad(
-                    [h.block_cols for h in hosts], 0, edge_dims=ed2)),
-                "valid_counts": jnp.asarray(_stack_pad(
-                    [h.valid_counts.astype(np.int32) for h in hosts], 0,
-                    edge_dims=ed)),
-                "blocks": jnp.asarray(_stack_pad(
-                    [h.blocks.astype(np.float32) for h in hosts], 0.0,
-                    edge_dims=ed)),
-            }
-        else:
-            n_br = max(h.n_block_rows for h in hosts)
-            if shape_bucket:
-                n_br = bucket_edge(n_br)
-            arrays = {
-                "cell_block": jnp.asarray(_stack_pad(
-                    [h.cell_block for h in hosts], zero_idx, edge_dims=ed)),
-                "cell_col": jnp.asarray(_stack_pad(
-                    [h.cell_col for h in hosts], 0, edge_dims=ed)),
-                # pad cells extend the member's LAST sorted row (+0 from the
-                # zero block), keeping cell_row nondecreasing — the Pallas
-                # output-residency contract; padding with row 0 would
-                # re-initialize (and zero) row 0's accumulated tile.
-                "cell_row": jnp.asarray(_stack_pad(
-                    [h.cell_row for h in hosts],
-                    [int(h.cell_row[-1]) if h.cell_row.size else 0
-                     for h in hosts], edge_dims=ed)),
-                # identity-extend each member's permutation so padded sorted
-                # rows scatter onto padded (sliced-away) output rows
-                "row_perm": jnp.asarray(np.stack([
-                    np.concatenate([h.row_perm,
-                                    np.arange(h.n_block_rows, n_br,
-                                              dtype=np.int32)])
-                    for h in hosts])),
-                "blocks": jnp.asarray(_stack_pad(
-                    [h.blocks.astype(np.float32) for h in hosts], 0.0,
-                    edge_dims=ed)),
-            }
-        n_bc = -(-max(h.shape[1] for h in hosts) // bs)
-        if shape_bucket:
-            n_bc = bucket_edge(n_bc)
-        width = n_bc * bs
-    return {"arrays": arrays, "shapes": shapes, "layout": layout,
-            "bs": bs, "width": width,
-            "stream_tiles": sum(int(h.valid_counts.sum()) for h in hosts)
-            if layout == "ell" else 0}
+    layout = layouts.pop()
+    target = bucket_target([{k: v.arrays[k].shape for k in v.arrays}
+                            for v in views], LAUNCH_FIELDS[layout])
+    padded = [pad_arrays(v.arrays, target) for v in views]
+    arrays = {}
+    for k in target:
+        leaves = [p[k] for p in padded]
+        arrays[k] = (jnp.stack(leaves)
+                     if any(isinstance(a, jax.Array) for a in leaves)
+                     else jnp.asarray(np.stack(leaves)))
+    bs = target["blocks"][-1] if "blocks" in target else schedule.block_size
+    shapes = [v.shape for v in views]
+    return {"arrays": arrays, "shapes": shapes, "layout": layout, "bs": bs,
+            "width": _launch_width(layout, target, shapes, bs),
+            "stream_tiles": sum(v.tiles for v in views)}
 
 
 def _plan_matvec_rhs_stacked(members: List, schedule: Schedule,
-                             backend: str, *, op: str, rhs_tile,
-                             sigma: int, store, shape_bucket: bool,
+                             backend: str, *, op: str, sigma: int, store,
                              member_keys) -> Plan:
     """Same-matrix bucket as ONE multi-RHS launch (DESIGN.md §13).
 
@@ -571,8 +407,7 @@ def _plan_matvec_rhs_stacked(members: List, schedule: Schedule,
     dimension is padded to bucket edges so every occupancy in an edge
     bucket shares one jit key."""
     inner = _plan_matvec((members[0],), schedule, backend, op=op,
-                         rhs_tile=rhs_tile, sigma=sigma, store=store,
-                         shape_bucket=shape_bucket,
+                         sigma=sigma, store=store,
                          operand_key=member_keys[0])
     n = len(members)
 
@@ -599,7 +434,7 @@ def _plan_matvec_rhs_stacked(members: List, schedule: Schedule,
             # is half the keys of the 1.5x edge ladder — occupancy jitter
             # under live traffic then never compiles mid-replay once the
             # pow2 rungs are warm
-            k_pad = (1 << (k - 1).bit_length()) if shape_bucket else k
+            k_pad = 1 << (k - 1).bit_length()
             if k_pad != k:
                 X = np.concatenate(
                     [X, np.zeros((X.shape[0], k_pad - k), np.float32)],
@@ -650,10 +485,8 @@ def _pad_member_axis(built: Dict, b_pad: int) -> Dict:
 
 
 def _plan_matvec_bucket(members: List, schedule: Schedule, backend: str, *,
-                        op: str = "spmv", rhs_tile: Optional[int] = None,
-                        sigma: int = SELL_SIGMA,
+                        op: str = "spmv", sigma: int = SELL_SIGMA,
                         store: Optional[PreparedStore] = None,
-                        shape_bucket: bool = True,
                         member_keys=None, **_) -> Plan:
     if (store is not None and member_keys is not None
             and all(member_keys) and len(set(member_keys)) == 1
@@ -662,20 +495,18 @@ def _plan_matvec_bucket(members: List, schedule: Schedule, backend: str, *,
         # case under Zipf traffic): one prepared container, RHS columns
         # stacked — no member stacking, no composition cache entry
         return _plan_matvec_rhs_stacked(
-            members, schedule, backend, op=op, rhs_tile=rhs_tile,
-            sigma=sigma, store=store, shape_bucket=bool(shape_bucket),
+            members, schedule, backend, op=op, sigma=sigma, store=store,
             member_keys=member_keys)
     key = None if store is None else _members_key(
-        "matvec_bucket", members, schedule,
-        extra=(op, sigma, bool(shape_bucket)), member_keys=member_keys)
-    b_pad = bucket_edge(len(members)) if shape_bucket else len(members)
+        "matvec_bucket", members, schedule, extra=(op, sigma),
+        member_keys=member_keys)
+    b_pad = bucket_edge(len(members))
     built = _cached(store, key, lambda: _pad_member_axis(
-        _build_matvec_bucket(members, schedule, sigma, shape_bucket,
-                             store=store, member_keys=member_keys), b_pad))
+        _build_matvec_bucket(members, schedule, sigma, store=store,
+                             member_keys=member_keys), b_pad))
     arrays, shapes = built["arrays"], built["shapes"]
     layout, width = built["layout"], built["width"]
-    tile = rhs_tile if rhs_tile is not None else (128 if backend == "pallas"
-                                                  else 8)
+    tile = _rhs_tile(backend)
 
     def run(xs):
         if len(xs) != len(shapes):
@@ -720,16 +551,6 @@ def _plan_matvec_bucket(members: List, schedule: Schedule, backend: str, *,
 
 _SHARDED_EXECS: dict = {}
 
-# the arrays a launch reads, per layout: the ELL SpMV streams each row's
-# valid_counts tiles (slice_widths is construction-side bookkeeping the
-# kernels never touch)
-_LAUNCH_FIELDS = {"ell": ("block_indices", "block_cols", "valid_counts",
-                          "blocks"),
-                  "sell": ("cell_block", "cell_col", "cell_row", "row_perm",
-                           "blocks"),
-                  "dense": ("dense",)}
-
-
 def _sharded_matvec_exec(mesh, layout: str, backend: str, multi: bool,
                          rows: int):
     """One jitted shard_map program per (mesh, layout, backend, arity, rows).
@@ -748,19 +569,17 @@ def _sharded_matvec_exec(mesh, layout: str, backend: str, multi: bool,
     if fn is not None:
         return fn
     from jax import shard_map
-    from ..launch.mesh import SHARD_AXIS
     P = jax.sharding.PartitionSpec
 
     def local(arrays, xb):
         # local leading dim is 1: this slot's single shard
         a = {k: v[0] for k, v in arrays.items()}
-        if layout == "dense":
-            y = a["dense"] @ xb
-        else:
+        n_br = a["row_perm"].shape[0] if layout == "sell" else 0
+        if layout != "dense":
             bs = a["blocks"].shape[-1]
-            xblk = xb.reshape((xb.shape[0] // bs, bs) + xb.shape[1:])
-            n_br = a["row_perm"].shape[0] if layout == "sell" else 0
-            y = _matvec_tiles(a, layout, xblk, n_br, backend, multi)
+            xb = xb.reshape((xb.shape[0] // bs, bs) + xb.shape[1:])
+        y = _matvec_tiles(a, layout, xb, n_br, backend, multi)
+        if layout != "dense":
             y = y.reshape((y.shape[0] * y.shape[1],) + y.shape[2:])
         return y[None, :rows]
 
@@ -809,91 +628,34 @@ def _count_exchange(nbytes: int) -> None:
     reg.inc("shard.exchange_bytes", float(nbytes))
 
 
-def _pad_to(arr: np.ndarray, shape: Tuple[int, ...], fill) -> np.ndarray:
-    """``arr`` zero-copy when it already has ``shape``, else padded to it
-    with ``fill`` after its own entries."""
-    if arr.shape == tuple(shape):
-        return arr
-    if any(a > b for a, b in zip(arr.shape, shape)):
-        raise ValueError(f"shard array {arr.shape} exceeds the common shape "
-                         f"{tuple(shape)}")
-    out = np.full(shape, fill, arr.dtype)
-    out[tuple(slice(0, n) for n in arr.shape)] = arr
-    return out
-
-
-def _launch_arrays(host, layout: str, target: Dict[str, Tuple[int, ...]]
-                   ) -> Dict[str, np.ndarray]:
-    """A shard's host container as the arrays a launch reads, padded to the
-    common shapes with ``_build_matvec_bucket``'s fills: pad slots point at
-    the shard's own last (all-zeros) tile, pad cells extend its last sorted
-    row, ``row_perm`` extends with identity."""
-    if layout == "dense":
-        return {"dense": _pad_to(np.asarray(host, np.float32),
-                                 target["dense"], 0.0)}
-    zero = host.blocks.shape[0] - 1
-    out = {"blocks": _pad_to(host.blocks.astype(np.float32, copy=False),
-                             target["blocks"], 0.0)}
-    if layout == "ell":
-        out["block_indices"] = _pad_to(host.block_indices.astype(np.int32),
-                                       target["block_indices"], zero)
-        out["block_cols"] = _pad_to(host.block_cols.astype(np.int32),
-                                    target["block_cols"], 0)
-        out["valid_counts"] = _pad_to(host.valid_counts.astype(np.int32),
-                                      target["valid_counts"], 0)
-        return out
-    cr = host.cell_row.astype(np.int32)
-    out["cell_block"] = _pad_to(host.cell_block.astype(np.int32),
-                                target["cell_block"], zero)
-    out["cell_col"] = _pad_to(host.cell_col.astype(np.int32),
-                              target["cell_col"], 0)
-    out["cell_row"] = _pad_to(cr, target["cell_row"],
-                              int(cr[-1]) if cr.size else 0)
-    perm = host.row_perm.astype(np.int32)
-    out["row_perm"] = np.concatenate([perm, np.arange(
-        perm.size, target["row_perm"][0], dtype=np.int32)])
-    return out
-
-
 def _build_on_mesh(members: List, schedule: Schedule, sigma: int,
-                   shape_bucket: bool, mesh) -> Dict:
+                   mesh) -> Dict:
     """The shards' launch arrays, stacked along a leading axis sharded over
     the mesh, built one shard at a time: each shard's container is built
     on the host, padded to the shapes every shard shares, uploaded to its
     own device and dropped before the next starts, so the host holds one
     shard's build at a time. The shared shapes come first, from each
     shard's tile counts (``container_shapes``), without building a tile."""
-    from ..launch.mesh import SHARD_AXIS
     layout = ("dense" if schedule.backend == "dense" else
               "sell" if schedule.layout == "sell" else "ell")
-    shapes = [container_shapes(m, schedule, sigma=sigma,
-                               shape_bucket=shape_bucket)
+    shapes = [container_shapes(m, schedule, sigma=sigma, shape_bucket=True)
               if isinstance(m, CSR) else
               {k: tuple(v.shape) for k, v in m.arrays.items()}
               for m in members]
-    target = {k: tuple(max(s[k][d] for s in shapes)
-                       for d in range(len(shapes[0][k])))
-              for k in _LAUNCH_FIELDS[layout]}
-    if layout == "dense":
-        width = target["dense"][1]
-    else:
-        bs = schedule.block_size
-        n_bc = max(-(-m.shape[1] // bs) for m in members)
-        width = (bucket_edge(n_bc) if shape_bucket else n_bc) * bs
+    target = bucket_target(shapes, LAUNCH_FIELDS[layout])
+    width = _launch_width(layout, target, [m.shape for m in members],
+                          schedule.block_size)
     devices = list(mesh.devices.flat)
     pieces: Dict[str, List] = {k: [] for k in target}
     tiles = 0
     for i, (m, dev) in enumerate(zip(members, devices)):
         with obs_trace.span("shard_build", shard=i, n_shards=len(members)):
-            host = (build_host(m, schedule, sigma=sigma,
-                               shape_bucket=shape_bucket)[0]
-                    if isinstance(m, CSR) else m.to_host())
+            view = _launch_member(m, schedule, sigma)
             placed = {k: jax.device_put(v[None], dev) for k, v in
-                      _launch_arrays(host, layout, target).items()}
+                      pad_arrays(view.arrays, target).items()}
             jax.block_until_ready(placed)
-            if layout == "ell":
-                tiles += int(host.valid_counts.sum())
-            del host
+            tiles += view.tiles
+            del view
         for k, v in placed.items():
             pieces[k].append(v)
     sharding = jax.sharding.NamedSharding(
@@ -906,10 +668,8 @@ def _build_on_mesh(members: List, schedule: Schedule, sigma: int,
 
 def _plan_matvec_sharded(operands, schedules, backend: str, *, op: str,
                          part=None, shard_csrs: Optional[List] = None,
-                         mesh=None, rhs_tile: Optional[int] = None,
-                         sigma: int = SELL_SIGMA,
+                         mesh=None, sigma: int = SELL_SIGMA,
                          store: Optional[PreparedStore] = None,
-                         shape_bucket: bool = True,
                          operand_key: Optional[str] = None, **_) -> Plan:
     """Distributed matvec plan: one prepared shard per mesh slot.
 
@@ -954,13 +714,11 @@ def _plan_matvec_sharded(operands, schedules, backend: str, *, op: str,
     n_shards = len(bounds) - 1
     true_rows = tuple(int(bounds[i + 1] - bounds[i]) for i in range(n_shards))
     n_cols = int(shape[1])
-    tile = rhs_tile if rhs_tile is not None else (128 if backend == "pallas"
-                                                  else 8)
+    tile = _rhs_tile(backend)
     uniform = len(set(schedules)) == 1 and schedules[0] is not None
     default_registry().set_gauge("shard.count", n_shards)
 
     if uniform and mesh is None:
-        from ..launch.mesh import make_shard_mesh
         mesh = make_shard_mesh(n_shards)
     elif not uniform:
         mesh = None
@@ -980,11 +738,10 @@ def _plan_matvec_sharded(operands, schedules, backend: str, *, op: str,
         # per-shard staging, so the store pins one entry for the launch.
         stack_key = None if store is None or not isinstance(a, CSR) else (
             "matvec_shards_stacked", operand_key or content_key(a),
-            strategy, bounds, tuple(schedules), sigma,
-            bool(shape_bucket), n_shards)
+            strategy, bounds, tuple(schedules), sigma, n_shards)
         members = list(sst.shards) if sst is not None else shard_csrs
         built = _cached(store, stack_key, lambda: _build_on_mesh(
-            members, schedules[0], sigma, shape_bucket, mesh))
+            members, schedules[0], sigma, mesh))
         arrays, width, layout = built["arrays"], built["width"], built["layout"]
         slots = list(mesh.devices.flat)
         homes = [{d} for d in slots]
@@ -1018,7 +775,7 @@ def _plan_matvec_sharded(operands, schedules, backend: str, *, op: str,
         if sst is None:
             key = None if store is None else (
                 "matvec_shards", operand_key or content_key(a), strategy,
-                bounds, tuple(schedules), sigma, bool(shape_bucket))
+                bounds, tuple(schedules), sigma)
 
             def build_shards():
                 shards = []
@@ -1027,8 +784,8 @@ def _plan_matvec_sharded(operands, schedules, backend: str, *, op: str,
                     with obs_trace.span("shard_build", shard=i,
                                         n_shards=n_shards):
                         st = SparseTensor.from_csr(
-                            c, schedule=s, sigma=sigma,
-                            shape_bucket=shape_bucket, device=dev)
+                            c, schedule=s, sigma=sigma, shape_bucket=True,
+                            device=dev)
                         jax.block_until_ready(st.arrays)
                         st._host = None
                     shards.append(st)
@@ -1048,7 +805,7 @@ def _plan_matvec_sharded(operands, schedules, backend: str, *, op: str,
             placed.append(nst)
         homes = [{d for v in st.arrays.values() for d in v.devices()}
                  for st in placed]
-        sub = [_plan_matvec((st,), s, backend, op=op, rhs_tile=rhs_tile)
+        sub = [_plan_matvec((st,), s, backend, op=op)
                for st, s in zip(placed, schedules)]
         kernel_rhs = None if all(q.kernel_rhs is None for q in sub) \
             else "given"
@@ -1141,13 +898,17 @@ def _with_zero_block(blocks: np.ndarray, bs: int) -> np.ndarray:
         [blocks.astype(np.float32), np.zeros((1, bs, bs), np.float32)])
 
 
-def _pad_rows(arr: np.ndarray, n: int, fill) -> np.ndarray:
-    """Pad axis 0 of a host array to ``n`` rows with ``fill``."""
-    if arr.shape[0] >= n:
-        return arr
-    out = np.full((n,) + arr.shape[1:], fill, dtype=arr.dtype)
-    out[: arr.shape[0]] = arr
-    return out
+def _edge_pad(arr: np.ndarray, fill=0) -> np.ndarray:
+    """A host array padded to its own bucket-edged shape with ``fill``."""
+    return pad_to(arr, common_shape([arr.shape]), fill)
+
+
+def _stack_padded(arrs: Sequence[np.ndarray], fills) -> jax.Array:
+    """Host arrays padded to their shared bucket-edged shape, each with its
+    own fill, and stacked along a new leading member axis."""
+    shape = common_shape([a.shape for a in arrs])
+    return jnp.asarray(np.stack([pad_to(a, shape, f)
+                                 for a, f in zip(arrs, fills)]))
 
 
 def _as_bsr(a, bs: int, op: str) -> BSR:
@@ -1194,51 +955,36 @@ def _spgemm_host_products(a, b, schedule: Schedule):
 
 
 def _prepare_spgemm(a, b, schedule: Schedule,
-                    store: Optional[PreparedStore], shape_bucket: bool,
+                    store: Optional[PreparedStore],
                     operand_key: Optional[str] = None):
-    """Device-staged (and optionally bucket-padded) spgemm symbolic-phase
-    products; cached in the PreparedStore keyed by exact matrix bytes."""
+    """Device-staged, bucket-padded spgemm symbolic-phase products; cached
+    in the PreparedStore keyed by exact matrix bytes."""
     key = None
     if store is not None and isinstance(a, CSR) and isinstance(b, CSR):
         key = ("spgemm", schedule.block_size, schedule.layout,
-               bool(shape_bucket), operand_key or content_key(a),
-               content_key(b))
-    return _cached(store, key,
-                   lambda: _build_spgemm(a, b, schedule, shape_bucket))
+               operand_key or content_key(a), content_key(b))
+    return _cached(store, key, lambda: _build_spgemm(a, b, schedule))
 
 
-def _build_spgemm(a, b, schedule: Schedule, shape_bucket: bool):
+def _build_spgemm(a, b, schedule: Schedule):
     h = _spgemm_host_products(a, b, schedule)
     n_c, bs = h["n_c"], h["bs"]
     if h["mode"] == "cells":
-        ca, cb, cc = h["cell_a"], h["cell_b"], h["cell_c"]
-        n_c_pad = n_c
-        if shape_bucket:
-            n_cells_p = bucket_edge(ca.size)
-            n_c_pad = bucket_edge(n_c)
-            ca = _pad_rows(ca, n_cells_p, h["zero_a"])
-            cb = _pad_rows(cb, n_cells_p, h["zero_b"])
-            cc = _pad_rows(cc, n_cells_p, max(n_c - 1, 0))
-            h["a_blocks"] = _pad_rows(h["a_blocks"],
-                                      bucket_edge(h["a_blocks"].shape[0]), 0.0)
-            h["b_blocks"] = _pad_rows(h["b_blocks"],
-                                      bucket_edge(h["b_blocks"].shape[0]), 0.0)
+        n_c_pad = bucket_edge(n_c)
+        ca = _edge_pad(h["cell_a"], h["zero_a"])
+        cb = _edge_pad(h["cell_b"], h["zero_b"])
+        cc = _edge_pad(h["cell_c"], max(n_c - 1, 0))
         dev = (jnp.asarray(ca), jnp.asarray(cb), jnp.asarray(cc),
-               jnp.asarray(h["a_blocks"]), jnp.asarray(h["b_blocks"]))
+               jnp.asarray(_edge_pad(h["a_blocks"])),
+               jnp.asarray(_edge_pad(h["b_blocks"])))
         prep = {"mode": "cells", "dev": dev, "n_c_pad": n_c_pad}
     else:
         pa, pb = h["pair_a"], h["pair_b"]
-        if shape_bucket and pa.size:
-            n_c_p, mp_p = bucket_edge(pa.shape[0]), bucket_edge(pa.shape[1])
-            pa2 = np.full((n_c_p, mp_p), h["zero_a"], np.int32)
-            pa2[: pa.shape[0], : pa.shape[1]] = pa
-            pb2 = np.full((n_c_p, mp_p), h["zero_b"], np.int32)
-            pb2[: pb.shape[0], : pb.shape[1]] = pb
-            pa, pb = pa2, pb2
-            h["a_blocks"] = _pad_rows(h["a_blocks"],
-                                      bucket_edge(h["a_blocks"].shape[0]), 0.0)
-            h["b_blocks"] = _pad_rows(h["b_blocks"],
-                                      bucket_edge(h["b_blocks"].shape[0]), 0.0)
+        if pa.size:
+            pa = _edge_pad(pa, h["zero_a"])
+            pb = _edge_pad(pb, h["zero_b"])
+            h["a_blocks"] = _edge_pad(h["a_blocks"])
+            h["b_blocks"] = _edge_pad(h["b_blocks"])
         dev = (jnp.asarray(pa), jnp.asarray(pb),
                jnp.asarray(h["a_blocks"]), jnp.asarray(h["b_blocks"]))
         prep = {"mode": "pairs", "dev": dev, "n_c_pad": n_c}
@@ -1250,7 +996,6 @@ def _build_spgemm(a, b, schedule: Schedule, shape_bucket: bool):
 def _plan_spgemm(operands, schedule: Optional[Schedule], backend: str, *,
                  block_size: int = 128,
                  store: Optional[PreparedStore] = None,
-                 shape_bucket: bool = True,
                  operand_key: Optional[str] = None, **_) -> Plan:
     a, b = operands
     if schedule is None:
@@ -1260,7 +1005,7 @@ def _plan_spgemm(operands, schedule: Optional[Schedule], backend: str, *,
                          "dense matmul instead")
     if a.shape[1] != b.shape[0]:
         raise ValueError(f"inner dims mismatch {a.shape} @ {b.shape}")
-    prep = _prepare_spgemm(a, b, schedule, store, shape_bucket, operand_key)
+    prep = _prepare_spgemm(a, b, schedule, store, operand_key)
     n_c, bs = prep["n_c"], prep["bs"]
 
     if prep["mode"] == "cells":
@@ -1346,7 +1091,6 @@ def _pair_members(members: List, op: str) -> List[Tuple[CSR, CSR]]:
 
 def _plan_spgemm_bucket(members: List, schedule: Schedule, backend: str, *,
                         store: Optional[PreparedStore] = None,
-                        shape_bucket: bool = True,
                         member_keys=None, **_) -> Plan:
     """ONE stacked launch for a same-schedule spgemm bucket: per-member
     symbolic products are padded to common (edge-rounded) shapes, stacked
@@ -1360,44 +1104,36 @@ def _plan_spgemm_bucket(members: List, schedule: Schedule, backend: str, *,
             raise ValueError(f"bucket member {i}: inner dims mismatch "
                              f"{a.shape} @ {b.shape}")
     key = None if store is None else _members_key(
-        "spgemm_bucket", members, schedule, extra=(bool(shape_bucket),),
-        member_keys=member_keys)
-    ed = (0,) if shape_bucket else ()
+        "spgemm_bucket", members, schedule, member_keys=member_keys)
 
     def build():
         hs = [_spgemm_host_products(a, b, schedule) for a, b in pairs]
         mode = hs[0]["mode"]
+        zeros = [0] * len(hs)
         if mode == "cells":
             stacked = {
-                "cell_a": jnp.asarray(_stack_pad(
-                    [h["cell_a"] for h in hs], [h["zero_a"] for h in hs],
-                    edge_dims=ed)),
-                "cell_b": jnp.asarray(_stack_pad(
-                    [h["cell_b"] for h in hs], [h["zero_b"] for h in hs],
-                    edge_dims=ed)),
+                "cell_a": _stack_padded([h["cell_a"] for h in hs],
+                                        [h["zero_a"] for h in hs]),
+                "cell_b": _stack_padded([h["cell_b"] for h in hs],
+                                        [h["zero_b"] for h in hs]),
                 # pad cells accumulate zero products onto the member's LAST
                 # output block, keeping cell_c nondecreasing
-                "cell_c": jnp.asarray(_stack_pad(
-                    [h["cell_c"] for h in hs],
-                    [max(h["n_c"] - 1, 0) for h in hs], edge_dims=ed)),
+                "cell_c": _stack_padded([h["cell_c"] for h in hs],
+                                        [max(h["n_c"] - 1, 0) for h in hs]),
             }
-            n_c_pad = max(h["n_c"] for h in hs)
-            if shape_bucket:
-                n_c_pad = bucket_edge(n_c_pad)
+            n_c_pad = bucket_edge(max(h["n_c"] for h in hs))
         else:
             stacked = {
-                "pair_a": jnp.asarray(_stack_pad(
-                    [h["pair_a"] for h in hs], [h["zero_a"] for h in hs],
-                    edge_dims=(0, 1) if shape_bucket else ())),
-                "pair_b": jnp.asarray(_stack_pad(
-                    [h["pair_b"] for h in hs], [h["zero_b"] for h in hs],
-                    edge_dims=(0, 1) if shape_bucket else ())),
+                "pair_a": _stack_padded([h["pair_a"] for h in hs],
+                                        [h["zero_a"] for h in hs]),
+                "pair_b": _stack_padded([h["pair_b"] for h in hs],
+                                        [h["zero_b"] for h in hs]),
             }
             n_c_pad = 0
-        stacked["a_blocks"] = jnp.asarray(_stack_pad(
-            [h["a_blocks"] for h in hs], 0.0, edge_dims=ed))
-        stacked["b_blocks"] = jnp.asarray(_stack_pad(
-            [h["b_blocks"] for h in hs], 0.0, edge_dims=ed))
+        stacked["a_blocks"] = _stack_padded([h["a_blocks"] for h in hs],
+                                            zeros)
+        stacked["b_blocks"] = _stack_padded([h["b_blocks"] for h in hs],
+                                            zeros)
         return {"mode": mode, "stacked": stacked, "n_c_pad": n_c_pad,
                 "c_ptrs": [h["c_ptrs"] for h in hs],
                 "c_cols": [h["c_cols"] for h in hs],
@@ -1452,32 +1188,24 @@ def _spadd_host_products(a, b, schedule: Schedule):
 
 
 def _prepare_spadd(a, b, schedule: Schedule,
-                   store: Optional[PreparedStore], shape_bucket: bool,
+                   store: Optional[PreparedStore],
                    operand_key: Optional[str] = None):
     key = None
     if store is not None and isinstance(a, CSR) and isinstance(b, CSR):
         # layout is irrelevant to spadd prep (only block_size is consumed),
         # so the key deliberately omits it: sell- and ell-schedule plans of
         # the same block size share one cached entry.
-        key = ("spadd", schedule.block_size, bool(shape_bucket),
-               operand_key or content_key(a), content_key(b))
-    return _cached(store, key,
-                   lambda: _build_spadd(a, b, schedule, shape_bucket))
+        key = ("spadd", schedule.block_size, operand_key or content_key(a),
+               content_key(b))
+    return _cached(store, key, lambda: _build_spadd(a, b, schedule))
 
 
-def _build_spadd(a, b, schedule: Schedule, shape_bucket: bool):
+def _build_spadd(a, b, schedule: Schedule):
     h = _spadd_host_products(a, b, schedule)
-    ia, ib = h["ia"], h["ib"]
-    if shape_bucket:
-        n_c_p = bucket_edge(h["n_c"])
-        ia = _pad_rows(ia, n_c_p, h["zero_a"])
-        ib = _pad_rows(ib, n_c_p, h["zero_b"])
-        h["a_blocks"] = _pad_rows(h["a_blocks"],
-                                  bucket_edge(h["a_blocks"].shape[0]), 0.0)
-        h["b_blocks"] = _pad_rows(h["b_blocks"],
-                                  bucket_edge(h["b_blocks"].shape[0]), 0.0)
-    return {"dev": (jnp.asarray(ia), jnp.asarray(ib),
-                    jnp.asarray(h["a_blocks"]), jnp.asarray(h["b_blocks"])),
+    return {"dev": (jnp.asarray(_edge_pad(h["ia"], h["zero_a"])),
+                    jnp.asarray(_edge_pad(h["ib"], h["zero_b"])),
+                    jnp.asarray(_edge_pad(h["a_blocks"])),
+                    jnp.asarray(_edge_pad(h["b_blocks"]))),
             "c_ptrs": h["c_ptrs"], "c_cols": h["c_cols"], "n_c": h["n_c"],
             "out_shape": h["out_shape"], "bs": h["bs"]}
 
@@ -1485,7 +1213,6 @@ def _build_spadd(a, b, schedule: Schedule, shape_bucket: bool):
 def _plan_spadd(operands, schedule: Optional[Schedule], backend: str, *,
                 block_size: int = 128,
                 store: Optional[PreparedStore] = None,
-                shape_bucket: bool = True,
                 operand_key: Optional[str] = None, **_) -> Plan:
     a, b = operands
     if schedule is None:
@@ -1495,7 +1222,7 @@ def _plan_spadd(operands, schedule: Optional[Schedule], backend: str, *,
                          "dense matmul instead")
     if a.shape != b.shape:
         raise ValueError(f"shape mismatch {a.shape} vs {b.shape}")
-    prep = _prepare_spadd(a, b, schedule, store, shape_bucket, operand_key)
+    prep = _prepare_spadd(a, b, schedule, store, operand_key)
     n_c, bs = prep["n_c"], prep["bs"]
 
     def run():
@@ -1512,7 +1239,6 @@ def _plan_spadd(operands, schedule: Optional[Schedule], backend: str, *,
 
 def _plan_spadd_bucket(members: List, schedule: Schedule, backend: str, *,
                        store: Optional[PreparedStore] = None,
-                       shape_bucket: bool = True,
                        member_keys=None, **_) -> Plan:
     """ONE stacked launch for a same-schedule spadd bucket."""
     if schedule.backend == "dense":
@@ -1523,23 +1249,18 @@ def _plan_spadd_bucket(members: List, schedule: Schedule, backend: str, *,
             raise ValueError(f"bucket member {i}: shape mismatch "
                              f"{a.shape} vs {b.shape}")
     key = None if store is None else _members_key(
-        "spadd_bucket", members, schedule, extra=(bool(shape_bucket),),
-        member_keys=member_keys)
+        "spadd_bucket", members, schedule, member_keys=member_keys)
 
     def build():
         hs = [_spadd_host_products(a, b, schedule) for a, b in pairs]
-        ed = (0,) if shape_bucket else ()
+        zeros = [0] * len(hs)
         stacked = {
-            "ia": jnp.asarray(_stack_pad(
-                [h["ia"] for h in hs], [h["zero_a"] for h in hs],
-                edge_dims=ed)),
-            "ib": jnp.asarray(_stack_pad(
-                [h["ib"] for h in hs], [h["zero_b"] for h in hs],
-                edge_dims=ed)),
-            "a_blocks": jnp.asarray(_stack_pad(
-                [h["a_blocks"] for h in hs], 0.0, edge_dims=ed)),
-            "b_blocks": jnp.asarray(_stack_pad(
-                [h["b_blocks"] for h in hs], 0.0, edge_dims=ed)),
+            "ia": _stack_padded([h["ia"] for h in hs],
+                                [h["zero_a"] for h in hs]),
+            "ib": _stack_padded([h["ib"] for h in hs],
+                                [h["zero_b"] for h in hs]),
+            "a_blocks": _stack_padded([h["a_blocks"] for h in hs], zeros),
+            "b_blocks": _stack_padded([h["b_blocks"] for h in hs], zeros),
         }
         return {"stacked": stacked,
                 "c_ptrs": [h["c_ptrs"] for h in hs],

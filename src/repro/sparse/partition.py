@@ -13,18 +13,40 @@ than the equal-row split under the Eq. 5 metric, so the property test
 
 Everything host-side numpy: partitioning is prep, and warm sharded plans
 skip it through the PreparedStore (ops_builtin caches the ``RowPartition``
-plus the sliced shard CSRs under the matrix's content key).
+plus the sliced shard CSRs under the matrix's content key). The one-axis
+device mesh the shards are laid out on (``make_shard_mesh``) lives here
+too.
 """
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, List, Tuple
+from typing import Dict, List, Optional, Sequence, Tuple
 
+import jax
 import numpy as np
+from jax.sharding import AxisType
 
 from ..core.csr import CSR
 
 STRATEGIES = ("nnz", "rows")
+SHARD_AXIS = "shards"
+
+
+def make_shard_mesh(n_shards: int, devices: Optional[Sequence] = None):
+    """1-D mesh for the sharded sparse path (DESIGN.md §10): one row shard
+    per slot on the ``shards`` axis, an ``Auto`` axis. Returns None when
+    fewer devices exist than shards — plan_sharded then falls back to
+    round-robin per-shard launches instead of the single shard_map
+    program. Simulate device counts on CPU with
+    ``XLA_FLAGS=--xla_force_host_platform_device_count=N``."""
+    if devices is None:
+        devices = jax.devices()
+    n_shards = int(n_shards)
+    if n_shards < 1 or len(devices) < n_shards:
+        return None
+    return jax.make_mesh((n_shards,), (SHARD_AXIS,),
+                         devices=devices[:n_shards],
+                         axis_types=(AxisType.Auto,))
 
 
 def slice_rows(csr: CSR, lo: int, hi: int) -> CSR:

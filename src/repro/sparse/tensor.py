@@ -19,7 +19,8 @@ unflattened copies inside traced code both work.
 from __future__ import annotations
 
 import dataclasses
-from typing import Dict, Optional, Tuple, Union
+import functools
+from typing import Dict, Optional, Sequence, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -40,6 +41,23 @@ LAYOUT_FIELDS: Dict[str, Tuple[str, ...]] = {
     "bsr": ("block_ptrs", "block_cols", "blocks"),
     "dense": ("dense",),
 }
+
+# The launch format (DESIGN.md §9): the leaves an ell/sell/dense launch
+# reads (all but ``slice_widths``, which only construction reads), and how
+# each pads when a member grows to a bucket edge or to the shapes a stack
+# shares. Slots and cells past a member's own point at its trailing
+# all-zero tile; ``cell_row`` repeats its last row, so it stays
+# nondecreasing (the Pallas kernels keep a row's output tile resident until
+# the row changes); ``row_perm`` extends as the identity, so padded rows
+# land on sliced-away output rows; ``slice_widths`` pads with 1, an empty
+# slice's width. Every other leaf pads with 0.
+LAUNCH_FIELDS: Dict[str, Tuple[str, ...]] = {
+    lay: tuple(f for f in LAYOUT_FIELDS[lay] if f != "slice_widths")
+    for lay in ("ell", "sell", "dense")}
+ZERO_TILE, LAST, IDENTITY = "zero tile", "last", "identity"
+PAD_FILL: Dict[str, object] = {"block_indices": ZERO_TILE,
+                               "cell_block": ZERO_TILE, "cell_row": LAST,
+                               "row_perm": IDENTITY, "slice_widths": 1}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -204,63 +222,32 @@ class SparseTensor:
                     device: Optional[jax.Device] = None) -> "SparseTensor":
         """Wrap an existing host container (ELLBSR/SELLBSR/BSR/dense), its
         arrays uploaded to ``device`` (the default device when None)."""
-        if device is None:
-            put = jnp.asarray
+        layout, host = container_arrays(container)
+        if layout == "dense":
+            if host["dense"].ndim != 2:
+                raise TypeError(f"cannot wrap {type(container).__name__} "
+                                "as a SparseTensor")
+            if schedule is None:
+                schedule = Schedule("dense", 128, 1.0)
+            meta = SparseMeta("dense", host["dense"].shape,
+                              schedule.block_size, schedule=schedule)
+            container = host["dense"]
         else:
-            def put(a, dtype):
-                return jax.device_put(np.asarray(a, dtype), device)
-        if isinstance(container, ELLBSR):
+            bs = container.block_size
             if schedule is None:
-                schedule = Schedule("bsr", container.block_size, 1.0)
-            meta = SparseMeta("ell", container.shape, container.block_size,
-                              n_block_rows=container.block_indices.shape[0],
-                              schedule=schedule)
-            arrays = {
-                "block_indices": put(container.block_indices, jnp.int32),
-                "block_cols": put(container.block_cols, jnp.int32),
-                "blocks": put(container.blocks, jnp.float32),
-                "valid_counts": put(container.valid_counts, jnp.int32),
-            }
-            return cls(meta, arrays, host=container)
-        if isinstance(container, SELLBSR):
-            if schedule is None:
-                schedule = Schedule("bsr", container.block_size, 1.0,
-                                    layout="sell",
-                                    slice_height=container.slice_height)
-            meta = SparseMeta("sell", container.shape, container.block_size,
-                              n_block_rows=container.n_block_rows,
-                              slice_height=container.slice_height,
-                              sigma=container.sigma, schedule=schedule)
-            arrays = {
-                "cell_block": put(container.cell_block, jnp.int32),
-                "cell_col": put(container.cell_col, jnp.int32),
-                "cell_row": put(container.cell_row, jnp.int32),
-                "row_perm": put(container.row_perm, jnp.int32),
-                "slice_widths": put(container.slice_widths, jnp.int32),
-                "blocks": put(container.blocks, jnp.float32),
-            }
-            return cls(meta, arrays, host=container)
-        if isinstance(container, BSR):
-            if schedule is None:
-                schedule = Schedule("bsr", container.block_size, 1.0)
-            meta = SparseMeta("bsr", container.shape, container.block_size,
-                              n_block_rows=container.n_block_rows,
-                              schedule=schedule)
-            arrays = {
-                "block_ptrs": put(container.block_ptrs, jnp.int32),
-                "block_cols": put(container.block_cols, jnp.int32),
-                "blocks": put(container.blocks, jnp.float32),
-            }
-            return cls(meta, arrays, host=container)
-        dense = np.asarray(container, np.float32)
-        if dense.ndim != 2:
-            raise TypeError(f"cannot wrap {type(container).__name__} as a "
-                            "SparseTensor")
-        if schedule is None:
-            schedule = Schedule("dense", 128, 1.0)
-        meta = SparseMeta("dense", dense.shape, schedule.block_size,
-                          schedule=schedule)
-        return cls(meta, {"dense": put(dense, jnp.float32)}, host=dense)
+                schedule = (Schedule("bsr", bs, 1.0, layout="sell",
+                                     slice_height=container.slice_height)
+                            if layout == "sell" else Schedule("bsr", bs, 1.0))
+            n_br = (container.block_indices.shape[0] if layout == "ell"
+                    else container.n_block_rows)
+            meta = SparseMeta(
+                layout, container.shape, bs, n_block_rows=n_br,
+                slice_height=getattr(container, "slice_height", 0),
+                sigma=getattr(container, "sigma", 0), schedule=schedule)
+        put = (jnp.asarray if device is None
+               else functools.partial(jax.device_put, device=device))
+        return cls(meta, {k: put(v) for k, v in host.items()},
+                   host=container)
 
     @classmethod
     def wrap(cls, obj, schedule: Optional[Schedule] = None) -> "SparseTensor":
@@ -305,24 +292,19 @@ class SparseTensor:
         came out of a pytree unflatten)."""
         if self._host is not None:
             return self._host
-        m, a = self.meta, self.arrays
+        m = self.meta
+        a = {f: np.asarray(self.arrays[f]) for f in LAYOUT_FIELDS[m.layout]}
         if m.layout == "ell":
-            host: HostLayout = ELLBSR(
-                np.asarray(a["block_indices"]), np.asarray(a["block_cols"]),
-                np.asarray(a["blocks"]), m.shape, m.block_size,
-                np.asarray(a["valid_counts"]))
+            host: HostLayout = ELLBSR(**a, shape=m.shape,
+                                      block_size=m.block_size)
         elif m.layout == "sell":
-            host = SELLBSR(
-                np.asarray(a["cell_block"]), np.asarray(a["cell_col"]),
-                np.asarray(a["cell_row"]), np.asarray(a["row_perm"]),
-                np.asarray(a["slice_widths"]), np.asarray(a["blocks"]),
-                m.shape, m.block_size, m.slice_height, m.sigma)
+            host = SELLBSR(**a, shape=m.shape, block_size=m.block_size,
+                           slice_height=m.slice_height, sigma=m.sigma)
         elif m.layout == "bsr":
-            host = BSR(np.asarray(a["block_ptrs"], np.int64),
-                       np.asarray(a["block_cols"]), np.asarray(a["blocks"]),
-                       m.shape, m.block_size)
+            a["block_ptrs"] = a["block_ptrs"].astype(np.int64)
+            host = BSR(**a, shape=m.shape, block_size=m.block_size)
         else:
-            host = np.asarray(a["dense"])
+            host = a["dense"]
         self._host = host
         return host
 
@@ -415,87 +397,105 @@ def _bucketed_ell(csr: CSR, schedule: Schedule,
     bsr = BSR.from_csr(csr, bs, alloc=alloc)
     ell = ELLBSR.from_bsr(bsr, _ell_cap(bsr, schedule, max_blocks, False),
                           blocks=tiles[0][: bsr.n_blocks + 1])
-    return _pad_ell_to_bucket(ell, tiles=tiles[0]), bsr.n_blocks
+    return pad_container_to_bucket(ell, tiles=tiles[0]), bsr.n_blocks
 
 
-def _pad_ell_to_bucket(ell: ELLBSR,
-                       tiles: Optional[np.ndarray] = None) -> ELLBSR:
-    """Pad an ELL container's dims (block-rows, slot width, block count,
-    block-columns) up to bucket edges; numerics unchanged — pad slots point
-    at the existing all-zeros block and pad output rows are sliced away.
-    ``tiles``, given, is the bucket-sized tile array that already starts
-    with ``ell.blocks``; it becomes the container's, uncopied."""
-    n_br, mb = ell.block_indices.shape
-    nb = ell.blocks.shape[0]            # includes the trailing zero block
-    bs = ell.block_size
-    zero_idx = nb - 1
-    n_bc = -(-ell.shape[1] // bs)
-    n_br_p, mb_p = bucket_edge(n_br), bucket_edge(mb)
-    nb_p, n_bc_p = bucket_edge(nb), bucket_edge(n_bc)
-    bi = np.full((n_br_p, mb_p), zero_idx, np.int32)
-    bi[:n_br, :mb] = ell.block_indices
-    bc = np.zeros((n_br_p, mb_p), np.int32)
-    bc[:n_br, :mb] = ell.block_cols
-    if tiles is None:
-        blocks = np.zeros((nb_p, bs, bs), np.float32)
-        blocks[:nb] = ell.blocks
-    else:
-        assert tiles.shape[0] == nb_p
-        blocks = tiles
-    vc = np.zeros(n_br_p, np.int32)
-    vc[:n_br] = ell.valid_counts
-    return ELLBSR(bi, bc, blocks, (n_br_p * bs, n_bc_p * bs), bs, vc)
+def container_arrays(container: HostLayout
+                     ) -> Tuple[str, Dict[str, np.ndarray]]:
+    """A host container's layout and its leaves by name, as float32 tiles
+    and int32 tables (no copy where they already are)."""
+    if isinstance(container, (ELLBSR, SELLBSR, BSR)):
+        layout = ("ell" if isinstance(container, ELLBSR) else
+                  "sell" if isinstance(container, SELLBSR) else "bsr")
+        return layout, {f: np.asarray(getattr(container, f),
+                                      np.float32 if f == "blocks"
+                                      else np.int32)
+                        for f in LAYOUT_FIELDS[layout]}
+    return "dense", {"dense": np.asarray(container, np.float32)}
 
 
-def _pad_sell_to_bucket(sell: SELLBSR) -> SELLBSR:
-    """Pad a SELL container (cells, block-rows, block count, block-columns)
-    up to bucket edges. Pad cells extend the LAST sorted row with zero-block
-    contributions, keeping ``cell_row`` nondecreasing (the Pallas
-    output-residency contract); ``row_perm`` is identity-extended so padded
-    sorted rows scatter onto padded (sliced-away) output rows."""
-    n_cells, n_br = sell.n_cells, sell.n_block_rows
-    nb = sell.blocks.shape[0]           # includes the trailing zero block
-    bs = sell.block_size
-    zero_idx = nb - 1
-    n_bc = -(-sell.shape[1] // bs)
-    n_cells_p, n_br_p = bucket_edge(n_cells), bucket_edge(n_br)
-    nb_p, n_bc_p = bucket_edge(nb), bucket_edge(n_bc)
-    cb = np.full(n_cells_p, zero_idx, np.int32)
-    cb[:n_cells] = sell.cell_block
-    cc = np.zeros(n_cells_p, np.int32)
-    cc[:n_cells] = sell.cell_col
-    last_row = int(sell.cell_row[-1]) if n_cells else 0
-    cr = np.full(n_cells_p, last_row, np.int32)
-    cr[:n_cells] = sell.cell_row
-    perm = np.concatenate([sell.row_perm,
-                           np.arange(n_br, n_br_p, dtype=np.int32)])
-    n_sl = sell.n_slices
-    sw = np.ones(bucket_edge(n_sl), np.int32)   # empty-slice width-1 rule
-    sw[:n_sl] = sell.slice_widths
-    blocks = np.zeros((nb_p, bs, bs), np.float32)
-    blocks[:nb] = sell.blocks
-    return SELLBSR(cb, cc, cr, perm, sw, blocks,
-                   (n_br_p * bs, n_bc_p * bs), bs, sell.slice_height,
-                   sell.sigma)
+def common_shape(shapes: Sequence[Tuple[int, ...]]) -> Tuple[int, ...]:
+    """Each dimension's maximum over ``shapes``, rounded up to a bucket
+    edge, but for a tile array's trailing (bs, bs)."""
+    dims = [max(int(s[d]) for s in shapes) for d in range(len(shapes[0]))]
+    edged = 1 if len(dims) == 3 else len(dims)
+    return tuple(bucket_edge(n) if d < edged else n
+                 for d, n in enumerate(dims))
 
 
-def pad_container_to_bucket(container: HostLayout) -> HostLayout:
-    """Bucket-edge padding rule per layout (no-op for raw BSR, whose exec
-    paths consume symbolic products that are bucketed separately)."""
-    if isinstance(container, ELLBSR):
-        return _pad_ell_to_bucket(container)
-    if isinstance(container, SELLBSR):
-        return _pad_sell_to_bucket(container)
+def bucket_target(shapes: Sequence[Dict[str, Tuple[int, ...]]],
+                  fields: Optional[Sequence[str]] = None
+                  ) -> Dict[str, Tuple[int, ...]]:
+    """The shapes a set of members pads to (``common_shape`` per leaf),
+    over ``fields`` or every leaf of the first member."""
+    return {k: common_shape([s[k] for s in shapes])
+            for k in (fields if fields is not None else shapes[0])}
+
+
+def pad_to(a, shape: Tuple[int, ...], fill=0):
+    """``a`` padded after its own entries to ``shape`` with ``fill``, in the
+    array module of ``a`` (numpy stays on the host, a jax array on its
+    device); ``a`` itself, uncopied, where it already has ``shape``."""
+    if tuple(a.shape) == tuple(shape):
+        return a
+    xp = jnp if isinstance(a, jax.Array) else np
+    return xp.pad(a, [(0, t - n) for n, t in zip(a.shape, shape)],
+                  constant_values=fill)
+
+
+def pad_arrays(arrays: Dict, target: Dict[str, Tuple[int, ...]],
+               zero_tile: Optional[int] = None) -> Dict:
+    """A member's leaves padded to ``target`` (leaf -> shape) by the launch
+    format's rule (``PAD_FILL``), the one padding of the format: bucket
+    edges, stacked buckets and mesh shards all pad through here. Numpy or
+    jax arrays alike, each in its own module; a leaf already at its target
+    is returned uncopied. ``zero_tile`` is the index pad slots point at,
+    ``blocks``' last by default."""
+    if zero_tile is None and "blocks" in arrays:
+        zero_tile = arrays["blocks"].shape[0] - 1
+    out = {}
+    for k, shape in target.items():
+        a, rule = arrays[k], PAD_FILL.get(k, 0)
+        if any(n > t for n, t in zip(a.shape, shape)):
+            raise ValueError(f"{k} {tuple(a.shape)} exceeds its target "
+                             f"{tuple(shape)}")
+        if tuple(a.shape) == tuple(shape):
+            out[k] = a
+        elif rule == IDENTITY:
+            xp = jnp if isinstance(a, jax.Array) else np
+            out[k] = xp.concatenate([a, xp.arange(a.shape[0], shape[0],
+                                                  dtype=a.dtype)])
+        else:
+            fill = (zero_tile if rule == ZERO_TILE else
+                    (a[-1] if a.shape[0] else 0) if rule == LAST else rule)
+            out[k] = pad_to(a, shape, fill)
+    return out
+
+
+def pad_container_to_bucket(container: HostLayout,
+                            tiles: Optional[np.ndarray] = None
+                            ) -> HostLayout:
+    """The container with every leaf padded up to its bucket edge
+    (``pad_arrays``) and its shape to the padded block grid; numerics
+    unchanged. Raw BSR is returned as it is: its exec paths consume
+    symbolic products that are bucketed on their own. ``tiles``, given, is
+    the bucket-sized tile array that already starts with the container's
+    tiles; it becomes the container's, uncopied."""
     if isinstance(container, BSR):
         return container
-    dense = np.asarray(container, np.float32)
-    r, c = dense.shape
-    r_p, c_p = bucket_edge(r), bucket_edge(c)
-    if (r_p, c_p) == (r, c):
-        return dense
-    out = np.zeros((r_p, c_p), np.float32)
-    out[:r, :c] = dense
-    return out
+    layout, arrays = container_arrays(container)
+    zero = arrays["blocks"].shape[0] - 1 if layout != "dense" else None
+    if tiles is not None:
+        arrays["blocks"] = tiles
+    padded = pad_arrays(arrays, bucket_target(
+        [{k: v.shape for k, v in arrays.items()}]), zero)
+    if layout == "dense":
+        return padded["dense"]
+    bs = container.block_size
+    n_br = padded["block_indices" if layout == "ell" else "row_perm"].shape[0]
+    n_bc = bucket_edge(-(-container.shape[1] // bs))
+    return dataclasses.replace(container, **padded,
+                               shape=(n_br * bs, n_bc * bs))
 
 
 # ------------------------------------------------------- sharded container

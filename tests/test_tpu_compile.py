@@ -54,7 +54,8 @@ def one_chip(topo):
 @pytest.fixture(scope="module")
 def four_chips(topo):
     """The described v5e:2x2 as the sharded path's 1-D ``shards`` mesh."""
-    from repro.launch.mesh import SHARD_AXIS, auto_mesh
+    from repro.launch.mesh import auto_mesh
+    from repro.sparse.partition import SHARD_AXIS
     return auto_mesh((4,), (SHARD_AXIS,), topo.devices)
 
 
@@ -173,7 +174,7 @@ def test_sharded_matvec_compiles_for_four_v5e(four_chips):
     x replicated. One shard_map program: each chip runs the same split
     ELL launches as a one-chip plan, and no collective is compiled in."""
     from jax.sharding import NamedSharding, PartitionSpec as P
-    from repro.launch.mesh import SHARD_AXIS
+    from repro.sparse.partition import SHARD_AXIS
     from repro.sparse.ops_builtin import _sharded_matvec_exec
     rows = NamedSharding(four_chips, P(SHARD_AXIS))
     n_br, mb, nb, bs = 4096, 12, 32768, 256
