@@ -1,7 +1,7 @@
 """SpChar core: the paper's contribution as a composable library.
 
 Public API:
-  CSR / BSR / ELLBSR / SELLBSR    sparse containers (csr.py)
+  CSR / BSR / ELLBSR / SELLBSR / DIA  sparse containers (csr.py)
   characterize / branch_entropy / reuse_affinity / index_affinity /
   thread_imbalance                static input metrics (metrics.py, Eq. 1-6)
   GENERATORS / TABLE2             synthetic matrices (synthetic.py, Table 2)
@@ -13,7 +13,7 @@ Public API:
   characterize_slice / compare_platforms   the characterization loop (charloop.py)
   ScheduleTuner                   loop-driven autotuning (autotune.py)
 """
-from .csr import CSR, BSR, ELLBSR, SELLBSR
+from .csr import CSR, BSR, DIA, ELLBSR, SELLBSR
 from .metrics import (branch_entropy, reuse_affinity, index_affinity,
                       thread_imbalance, partition_imbalance, characterize,
                       sell_slice_widths, sell_padding_fraction,
@@ -32,7 +32,7 @@ from .charloop import (build_slice, characterize_slice, characterize_all,
 from .autotune import ScheduleTuner, Schedule, select_moe_block_size
 
 __all__ = [
-    "CSR", "BSR", "ELLBSR", "SELLBSR", "branch_entropy", "reuse_affinity", "index_affinity",
+    "CSR", "BSR", "DIA", "ELLBSR", "SELLBSR", "branch_entropy", "reuse_affinity", "index_affinity",
     "thread_imbalance", "partition_imbalance", "characterize", "THREAD_SWEEP",
     "FEATURE_NAMES", "GENERATORS", "TABLE2", "corpus", "DOMAINS",
     "DecisionTreeRegressor", "kfold_cv", "mape", "r2_score", "Platform",

@@ -31,6 +31,9 @@ ELL_QUANTILES = (0.8, 0.95, 1.0)
 SLICE_HEIGHTS = (4, 8, 16)      # SELL slice heights swept as a schedule axis
 SELL_SIGMA = 64                 # sorting window (block-rows); fixed, not swept
 DENSE_DENSITY_THRESHOLD = 0.25  # above this, a dense matmul wins trivially
+# the diagonal layout wins where the occupied diagonals hold the operand at
+# this fill or less: d * n_rows <= DIA_FILL_MAX * nnz
+DIA_FILL_MAX = 1.5
 TUNER_TREE_DEPTH = 14           # cost-tree depth shared by fit() and refit()
 # fit(prune_top_k="auto"): grids past this size prune themselves with the
 # provisional tree (ROADMAP item — fit cost must not scale with the full
@@ -44,10 +47,10 @@ CFG_FEATURES = ("cfg_block_size", "cfg_ell_quantile", "cfg_slice_height",
 
 @dataclasses.dataclass(frozen=True)
 class Schedule:
-    backend: str          # "dense" | "bsr"
+    backend: str          # "dense" | "bsr" | "dia"
     block_size: int
     ell_quantile: float
-    layout: str = "ell"   # "ell" (global padding) | "sell" (sliced)
+    layout: str = "ell"   # "ell" (global padding) | "sell" (sliced) | "dia"
     slice_height: int = 0  # SELL C; 0 = n/a for the global-ELL layout
     n_rhs: int = 1        # RHS tile width (1 = SpMV, >1 = the SpMM path)
 
@@ -56,12 +59,35 @@ class Schedule:
                 float(self.slice_height), float(self.n_rhs)]
 
 
+# The diagonal layout's one schedule: no block size, quantile or slice
+# height to choose (128 is the kernel's lane width).
+DIA_SCHEDULE = Schedule("dia", 128, 1.0, layout="dia")
+
+
 def candidate_schedules(n_rhs: int = 1) -> List[Schedule]:
     ell = [Schedule("bsr", bs, q, n_rhs=n_rhs)
            for bs, q in itertools.product(BLOCK_SIZES, ELL_QUANTILES)]
     sell = [Schedule("bsr", bs, 1.0, layout="sell", slice_height=c, n_rhs=n_rhs)
             for bs, c in itertools.product(BLOCK_SIZES, SLICE_HEIGHTS)]
     return ell + sell
+
+
+def count_diagonals(A: CSR) -> int:
+    """``A``'s occupied diagonals, counted exactly up to the most a DIA
+    pick allows it and no further: a count above that only says it is
+    passed."""
+    limit = int(DIA_FILL_MAX * A.nnz // max(A.n_rows, 1))
+    return len(A.diagonal_offsets(limit=limit))
+
+
+def takes_dia(n_diagonals: int, n_rows: int, nnz: int, n_rhs: int = 1,
+              mutable: bool = False) -> bool:
+    """Whether an operand goes to the diagonal layout: an SpMV (``n_rhs``
+    1) of an immutable operand whose ``n_diagonals`` occupied diagonals
+    hold it at a fill of at most ``DIA_FILL_MAX``. ``plan()`` asks this of
+    an operand it launches alone, after the selector's pick."""
+    return n_rhs == 1 and not mutable and nnz > 0 \
+        and n_diagonals * n_rows <= DIA_FILL_MAX * nnz
 
 
 def _modeled_time(kernel: str, A: CSR, platform: Platform, sched: Schedule) -> float:

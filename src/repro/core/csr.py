@@ -1,5 +1,5 @@
-"""Sparse matrix containers: CSR (paper interchange format), BSR, ELL-BSR
-and SELL-BSR.
+"""Sparse matrix containers: CSR (paper interchange format), BSR, ELL-BSR,
+SELL-BSR and DIA.
 
 CSR is the paper's format (Fig. 1): ``row_ptrs`` / ``col_idxs`` / ``nnz_vals``.
 BSR/ELL-BSR are the TPU-native blocked layouts our Pallas kernels consume
@@ -8,6 +8,8 @@ schedule *is* the paper's §4.4 "ELL / 2D-blocked format" recommendation.
 SELL-BSR (DESIGN.md §2.3) is the sliced refinement: block-rows are sorted by
 work inside windows of ``sigma`` and padded per slice of ``slice_height``
 rows instead of globally, so one power-law row no longer pads everyone.
+DIA keeps one value per (occupied diagonal, row): a banded operand moves its
+values and no index.
 
 Containers are plain numpy on the host (construction/characterization side)
 with ``jax_arrays()`` exporters for device-side kernels. All ``from_*``
@@ -117,6 +119,28 @@ class CSR:
             seen[brows * n_bc + self.col_idxs[p0:p1] // bs] = True
             out[r0:r1] = seen.reshape(r1 - r0, n_bc).sum(axis=1)
         return out
+
+    def diagonal_offsets(self, limit: Optional[int] = None) -> np.ndarray:
+        """Offsets (col - row) of the occupied diagonals, ascending. With
+        ``limit`` the count stops early: once more than ``limit`` are found,
+        those found so far are returned. Rows are read a band of about
+        65,536 entries at a time."""
+        n_rows = self.n_rows
+        seen = np.zeros(max(n_rows + self.n_cols - 1, 1), dtype=bool)
+        found, r0 = 0, 0
+        while r0 < n_rows and (limit is None or found <= limit):
+            r1 = int(np.searchsorted(self.row_ptrs,
+                                     self.row_ptrs[r0] + (1 << 16), "right"))
+            r1 = min(max(r1 - 1, r0 + 1), n_rows)
+            p0, p1 = int(self.row_ptrs[r0]), int(self.row_ptrs[r1])
+            rows = np.repeat(np.arange(r0, r1),
+                             np.diff(self.row_ptrs[r0:r1 + 1]))
+            diag = self.col_idxs[p0:p1].astype(np.int64) - rows + n_rows - 1
+            fresh = np.unique(diag[~seen[diag]])
+            seen[fresh] = True
+            found += fresh.size
+            r0 = r1
+        return np.flatnonzero(seen) - (n_rows - 1)
 
     def to_dense(self) -> np.ndarray:
         out = np.zeros(self.shape, dtype=np.float32)
@@ -408,3 +432,48 @@ class SELLBSR:
             [bsr.blocks, np.zeros((1, bs, bs), np.float32)], axis=0)
         return cls(cell_block, cell_col, cell_row.astype(np.int32), row_perm,
                    slice_widths.astype(np.int32), blocks, bsr.shape, bs, C, sg)
+
+
+@dataclasses.dataclass
+class DIA:
+    """Diagonal layout: one value per (occupied diagonal, row) and no index
+    per nonzero, the classic format of a banded operand such as a stencil.
+
+    ``diags[d]`` holds A[i, i + offsets[d]] at row i, as lane rows of 128
+    rows each: (d, n_row_pad // 128, 128) float32 with the rows padded to
+    ``n_row_pad``. A slot whose column falls outside the matrix, or whose
+    entry is not stored, holds 0. The matrix may be rectangular: a row
+    shard of a stencil is a DIA operand with shifted offsets.
+    """
+
+    offsets: Tuple[int, ...]  # ascending
+    diags: np.ndarray  # (d, n_row_pad // 128, 128) float32
+    shape: Tuple[int, int]
+    nnz: int  # stored entries of the CSR it was built from
+
+    def fill(self) -> float:
+        """Slots over stored entries: d * n_rows / nnz."""
+        return len(self.offsets) * self.shape[0] / max(self.nnz, 1)
+
+    @classmethod
+    def from_csr(cls, csr: CSR, n_row_pad: int,
+                 offsets: Optional[np.ndarray] = None) -> "DIA":
+        """Scatter ``csr``'s values into its occupied diagonals
+        (``offsets``, ``csr.diagonal_offsets()`` where not given), rows
+        padded to ``n_row_pad`` (a multiple of 128 and at least
+        ``n_rows``). Duplicate entries sum, as ``CSR.to_dense`` sums them."""
+        if n_row_pad % 128 or n_row_pad < csr.n_rows:
+            raise ValueError(f"n_row_pad {n_row_pad} must be a multiple of "
+                             f"128 holding {csr.n_rows} rows")
+        if offsets is None:
+            offsets = csr.diagonal_offsets()
+        rows = np.repeat(np.arange(csr.n_rows), csr.row_lengths())
+        which = np.searchsorted(offsets,
+                                csr.col_idxs.astype(np.int64) - rows)
+        diags = np.bincount(which * n_row_pad + rows,
+                            weights=csr.nnz_vals.astype(np.float64),
+                            minlength=offsets.size * n_row_pad)
+        return cls(tuple(int(o) for o in offsets),
+                   diags.astype(np.float32).reshape(offsets.size,
+                                                    n_row_pad // 128, 128),
+                   (int(csr.n_rows), int(csr.n_cols)), csr.nnz)

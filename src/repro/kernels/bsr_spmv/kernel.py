@@ -1,5 +1,5 @@
-"""ELL/SELL-BSR SpMV + multi-RHS SpMM Pallas TPU kernels (paper Alg. 1
-adapted per §4.4 / DESIGN §2).
+"""ELL/SELL-BSR SpMV + multi-RHS SpMM, and DIA SpMV, Pallas TPU kernels
+(paper Alg. 1 adapted per §4.4 / DESIGN §2).
 
 Schedules
   ELL (global padding, DESIGN §2.2)
@@ -33,6 +33,15 @@ Schedules
     grid runs sum_s C*w_s steps instead of n_block_rows*max_w — the padding
     eliminated by slicing is grid steps that simply never launch.
 
+  DIA (diagonal layout, DESIGN §2.5; SpMV only)
+    grid = (row blocks,), each 128 * ``dia_rows`` matrix rows. The block
+    of every diagonal's values comes by BlockSpec; the x window the block
+    reads (its rows plus the band) is copied by hand, the next block's
+    while this one computes, so each diagonal's shift is a static offset
+    into it: sublane rows, then a lane roll and select. No index is read.
+    ``bsr_spmv_pallas`` runs it as its second format (``offsets`` given),
+    so a device trace names both SpMV formats alike.
+
   Vector layout
     A TPU block's last two dims must be multiples of (8, 128) or equal the
     array's. A (1, bs) slice of an (n_block_cols, bs) vector is neither, so
@@ -65,6 +74,8 @@ empty lanes (autotune.py arbitrates via the tree model).
 from __future__ import annotations
 
 import functools
+import math
+from typing import Optional, Tuple
 
 import jax
 import jax.numpy as jnp
@@ -232,10 +243,27 @@ def _ell_stream_kernel(idx_ref, cols_ref, vc_ref, blocks_hbm, x_hbm, y_ref,
     y_ref[0] = _lane_sum(acc_ref[...])
 
 
-@functools.partial(jax.jit, static_argnames=("interpret",))
-def bsr_spmv_pallas(block_indices: jax.Array, block_cols: jax.Array,
-                    valid_counts: jax.Array, blocks: jax.Array,
-                    x_blocks: jax.Array, interpret: bool = False) -> jax.Array:
+@functools.partial(jax.jit, static_argnames=("offsets", "interpret"))
+def bsr_spmv_pallas(*arrays: jax.Array,
+                    offsets: Optional[Tuple[int, ...]] = None,
+                    interpret: bool = False) -> jax.Array:
+    """y = A @ x: the family's SpMV entry, for either of its launch formats.
+
+    ELL (``offsets`` None): ``(block_indices, block_cols, valid_counts,
+    blocks, x_blocks)``, returning the blocked (n_br, bs) result
+    (``_ell_spmv``). DIA (``offsets`` the operand's diagonals): ``(diags,
+    x)``, returning the (n_row_pad,) result (``_dia_spmv``). Each format
+    keeps its own ``pallas_call``; both run inside this one jitted entry,
+    whose name is what a device trace shows for either kernel.
+    """
+    if offsets is not None:
+        return _dia_spmv(*arrays, offsets=offsets, interpret=interpret)
+    return _ell_spmv(*arrays, interpret=interpret)
+
+
+def _ell_spmv(block_indices: jax.Array, block_cols: jax.Array,
+              valid_counts: jax.Array, blocks: jax.Array,
+              x_blocks: jax.Array, interpret: bool = False) -> jax.Array:
     """y = A @ x with A in ELL-BSR layout, streaming only the valid tiles.
 
     Args:
@@ -383,3 +411,147 @@ def _sell_call(cell_block, cell_col, cell_row, blocks, x_blocks,
         out_shape=jax.ShapeDtypeStruct((n_block_rows,) + tile, jnp.float32),
         interpret=interpret,
     )(cell_block, cell_col, cell_row, blocks, x_blocks)
+
+
+# ----------------------------------------------------------------------- DIA
+
+LANES = 128
+# Sublane rows of x (128 matrix rows each) a DIA grid step covers at most,
+# and the VMEM the step's double-buffered block of diagonals may take.
+DIA_MAX_ROWS = 256
+DIA_BLOCK_BYTES = 8 << 20
+# Sublane rows the VPU carries across every diagonal at a time.
+DIA_CHUNK = 8
+# One DIA pallas_call takes at most DIA_GROUP diagonals (it unrolls them)
+# spanning at most DIA_BAND columns (its x window, 1 MiB a slot); an
+# operand past either runs one call a group of diagonals and sums them.
+DIA_GROUP = 64
+DIA_BAND = 1 << 18
+
+
+def dia_rows(n_diags: int, lane_rows: int) -> int:
+    """Sublane rows one DIA grid step covers, for ``n_diags`` diagonals
+    over ``lane_rows`` lane rows (128 matrix rows each): at most
+    ``DIA_MAX_ROWS``, a group's double-buffered diagonals within
+    ``DIA_BLOCK_BYTES``, no more than the rows need, a multiple of 8. The
+    DIA container pads its lane rows to a multiple of it, and the kernel
+    reads the same value back from the padded count."""
+    group = min(max(n_diags, 1), DIA_GROUP)
+    cap = DIA_BLOCK_BYTES // (2 * 4 * LANES * group) // 8 * 8
+    return max(8, min(cap, DIA_MAX_ROWS, -(-lane_rows // 8) * 8))
+
+
+def _dia_groups(offsets: Tuple[int, ...]):
+    """(first, end) index ranges of ``offsets`` (ascending) that one
+    ``pallas_call`` takes: at most ``DIA_GROUP`` diagonals spanning at most
+    ``DIA_BAND`` columns each."""
+    first = 0
+    for k in range(1, len(offsets) + 1):
+        if k == len(offsets) or k - first == DIA_GROUP \
+                or offsets[k] - offsets[first] > DIA_BAND:
+            yield first, k
+            first = k
+
+
+def _dia_kernel(diags_ref, x_hbm, y_ref, xwin, sems, *, taps, chunk):
+    """One grid step: ``y`` rows [i R, (i + 1) R), R = 128 ``rows``.
+
+    ``x_hbm`` is x as lane rows, shifted so that step i's window of x
+    starts at lane row i ``rows``; the window (``xwin``, two slots) is
+    copied by hand, the next step's while this one computes. Diagonal k
+    reads x at lane ``(q, r) = taps[k]`` past each row: the window's rows
+    q.. give lanes r.. and rows q + 1.. the lanes before r, joined by a
+    roll of 128 - r lanes and a lane select. ``chunk`` sublane rows at a
+    time, every diagonal is multiplied and summed in f32 on the VPU."""
+    rows, win = y_ref.shape[0], xwin.shape[1]
+    i = pl.program_id(0)
+    slot = i % 2
+
+    def window(step, s):
+        return pltpu.make_async_copy(x_hbm.at[pl.ds(step * rows, win)],
+                                     xwin.at[s], sems.at[s])
+
+    @pl.when(i == 0)
+    def _first():
+        window(0, 0).start()
+
+    @pl.when(i + 1 < pl.num_programs(0))
+    def _next():
+        window(i + 1, 1 - slot).start()
+
+    window(i, slot).wait()
+    lane = lax.broadcasted_iota(jnp.int32, (chunk, LANES), 1)
+
+    def chunk_rows(c, carry):
+        c0 = pl.multiple_of(c * chunk, chunk)
+        loaded = {}
+
+        def at(q):
+            if q not in loaded:
+                loaded[q] = xwin[slot, pl.ds(c0 + q, chunk), :]
+            return loaded[q]
+
+        acc = jnp.zeros((chunk, LANES), jnp.float32)
+        for k, (q, r) in enumerate(taps):
+            xs = at(q)
+            if r:
+                xs = jnp.where(lane < LANES - r,
+                               pltpu.roll(xs, LANES - r, 1),
+                               pltpu.roll(at(q + 1), LANES - r, 1))
+            acc = acc + diags_ref[k, pl.ds(c0, chunk), :] * xs
+        y_ref[pl.ds(c0, chunk), :] = acc
+        return carry
+
+    lax.fori_loop(0, rows // chunk, chunk_rows, 0)
+
+
+def _dia_spmv(diags: jax.Array, x: jax.Array, *, offsets: Tuple[int, ...],
+              interpret: bool = False) -> jax.Array:
+    """y = A @ x with A in the DIA layout: ``diags`` (d, n_row_pad / 128,
+    128) float32, one lane row per 128 matrix rows, and ``offsets`` its
+    diagonals, ascending; x (n_cols,), read as zero outside its length.
+    Returns the (n_row_pad,) result: one ``pallas_call`` a group of
+    diagonals (``_dia_groups``; one on a stencil), summed. The padded rows
+    must be a whole number of grid steps (``dia_rows``), as the DIA launch
+    format pads them (``sparse.tensor.dia_row_pad``)."""
+    d, lane_rows, _ = diags.shape
+    rows = dia_rows(d, lane_rows)
+    if lane_rows % rows:
+        raise ValueError(f"{lane_rows} lane rows of diagonals are not a "
+                         f"whole number of {rows}-row DIA grid steps")
+    parts = [_dia_call(diags if g1 - g0 == d else diags[g0:g1], x,
+                       offsets[g0:g1], rows, interpret)
+             for g0, g1 in _dia_groups(offsets)]
+    if not parts:
+        return jnp.zeros((lane_rows * LANES,), jnp.float32)
+    return sum(parts[1:], parts[0]).reshape(-1)
+
+
+def _dia_call(diags, x, offsets, rows, interpret) -> jax.Array:
+    """The DIA ``pallas_call`` over one group of diagonals: a grid step a
+    block of 128 ``rows`` matrix rows, x padded and shifted into lane rows
+    here, inside the launch. Returns (n_row_pad / 128, 128)."""
+    d, lane_rows, _ = diags.shape
+    steps = lane_rows // rows
+    base = min(offsets) // LANES        # lane row of the lowest read
+    taps = tuple(divmod(o - LANES * base, LANES) for o in offsets)
+    win = -(-(rows + taps[-1][0] + 1) // 8) * 8
+    n_x = ((steps - 1) * rows + win) * LANES
+    start = LANES * base                # x index of the shifted x's first
+    left = max(-start, 0)
+    xs = x.astype(jnp.float32)[max(start, 0):][: n_x - left]
+    xw = jnp.pad(xs, (left, n_x - left - xs.shape[0])).reshape(-1, LANES)
+    return pl.pallas_call(
+        functools.partial(_dia_kernel, taps=taps,
+                          chunk=math.gcd(DIA_CHUNK, rows)),
+        grid=(steps,),
+        in_specs=[pl.BlockSpec((d, rows, LANES), lambda i: (0, i, 0)),
+                  pl.BlockSpec(memory_space=pl.ANY)],
+        out_specs=pl.BlockSpec((rows, LANES), lambda i: (i, 0)),
+        out_shape=jax.ShapeDtypeStruct((lane_rows, LANES), jnp.float32),
+        scratch_shapes=[pltpu.VMEM((2, win, LANES), jnp.float32),
+                        pltpu.SemaphoreType.DMA((2,))],
+        compiler_params=pltpu.CompilerParams(
+            dimension_semantics=("arbitrary",)),
+        interpret=interpret,
+    )(diags, xw)

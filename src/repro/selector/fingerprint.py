@@ -8,6 +8,11 @@ subsampled streams and log transforms whose last bits are not meaningful,
 so two byte-identical matrices must map to one key while structurally
 different matrices keep distinct keys (shape/nnz are exact, and the cache
 double-checks the full rounded vector on every hit — see cache.py).
+
+Beside the features, a fingerprint counts the operand's occupied diagonals
+(``core.autotune.count_diagonals``, exact up to what a DIA pick allows):
+the DIA rule reads it. It is not a feature and not part of the key, so the
+tree's inputs are what ``characterize`` gives.
 """
 from __future__ import annotations
 
@@ -16,6 +21,7 @@ import hashlib
 from typing import Dict, Tuple
 
 from ..core import metrics as metrics_mod
+from ..core.autotune import count_diagonals
 from ..core.csr import CSR
 
 # Decimal digits kept per feature when forming the hash key. All features
@@ -45,6 +51,7 @@ class Fingerprint:
     features: Dict[str, float]                 # unrounded, for the predictor
     shape: Tuple[int, int]
     nnz: int
+    n_diagonals: int = 0                       # occupied, up to the DIA limit
 
 
 def fingerprint(csr: CSR, precision: int = FP_PRECISION) -> Fingerprint:
@@ -57,7 +64,7 @@ def fingerprint(csr: CSR, precision: int = FP_PRECISION) -> Fingerprint:
     key = hashlib.sha1(payload.encode("utf-8")).hexdigest()
     return Fingerprint(key=key, canonical=canonical, features=dict(feats),
                        shape=(int(csr.shape[0]), int(csr.shape[1])),
-                       nnz=int(csr.nnz))
+                       nnz=int(csr.nnz), n_diagonals=count_diagonals(csr))
 
 
 def routing_fingerprint(tokens_per_expert, d_model: int, platform: str = "",

@@ -229,6 +229,12 @@ class SelectorService:
         timed.sort(key=lambda p: p[0])
         return timed[0][1], timed[0][0]
 
+    def memoized_fingerprint(self, ck: Optional[str]) -> Optional[Fingerprint]:
+        """The fingerprint a decision's content key (``Decision.ck``) was
+        given, while the memo still holds it; None otherwise. ``plan()``
+        reads its diagonal count from here."""
+        return self._fp_memo.get(ck) if ck else None
+
     def _fingerprint(self, req: Request) -> Fingerprint:
         from ..sparse.prepared import content_key
         with obs_trace.span("hash", req.name):

@@ -58,7 +58,7 @@ from ..obs import trace as obs_trace
 from .prepared import PreparedStore, raw_content_key
 from .resilience import (GUARDED_EXCEPTIONS, InjectedFault, _note_handled,
                          check_fault, fault_fired, note_recovery)
-from .tensor import SparseTensor, pad_arrays
+from .tensor import LANES, SparseTensor, pad_arrays
 
 # Spare all-zero blocks reserved per unit of slack: ``slack`` bounds
 # inserts per block-row, SPARE_FACTOR * slack bounds them matrix-wide.
@@ -342,6 +342,26 @@ def _scatter3(arr, ks, rr, cc, vals, mode: str):
     return ref.add(vals) if mode == "add" else ref.set(vals)
 
 
+def _dia_delta(st: SparseTensor, rows, cols, vals, mode: str) -> None:
+    """A delta on a DIA operand: every position on one of its diagonals
+    takes its value in place, stored or not; a position off them raises
+    SlackOverflow before anything changes, so the caller epoch-swaps."""
+    offsets = np.asarray(st.meta.offsets, np.int64)
+    diag = cols - rows
+    ks = np.searchsorted(offsets, diag)
+    if not offsets.size or not np.array_equal(
+            offsets[np.minimum(ks, offsets.size - 1)], diag):
+        raise SlackOverflow("delta position off the dia operand's diagonals")
+    rr, cc = rows // LANES, rows % LANES
+    st.arrays["diags"] = _scatter3(st.arrays["diags"], ks, rr, cc, vals,
+                                   mode)
+    if st._host is not None:
+        if mode == "add":
+            np.add.at(st._host.diags, (ks, rr, cc), vals)
+        else:
+            st._host.diags[ks, rr, cc] = vals
+
+
 def apply_delta_to_tensor(st: SparseTensor, delta: DeltaLike) -> SparseTensor:
     """In-place delta on a prepared container (``SparseTensor.apply_delta``
     body). Same-shape leaf rebinds only — warm jitted executors see the
@@ -355,6 +375,10 @@ def apply_delta_to_tensor(st: SparseTensor, delta: DeltaLike) -> SparseTensor:
     if (rows.min() < 0 or rows.max() >= n
             or cols.min() < 0 or cols.max() >= m):
         raise ValueError(f"delta position outside {st.true_shape}")
+    if st.layout == "dia":
+        _dia_delta(st, rows, cols, vals, delta.mode)
+        st.generation += 1
+        return st
     if st.layout == "dense":
         # jitted scatter: eager .at[].set pays per-op dispatch (~ms); the
         # compiled update is the value-churn fast path's actual cost model

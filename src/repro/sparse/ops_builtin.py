@@ -49,7 +49,8 @@ from ..kernels.bsr_spmv.kernel import (bsr_spmm_pallas, bsr_spmm_sell_pallas,
                                        bsr_spmv_pallas, bsr_spmv_sell_pallas,
                                        ell_streams)
 from ..kernels.bsr_spmv.ref import (ref_bsr_spmm, ref_bsr_spmm_sell,
-                                    ref_bsr_spmv, ref_bsr_spmv_sell)
+                                    ref_bsr_spmv, ref_bsr_spmv_sell,
+                                    ref_dia_spmv)
 from ..kernels.flash_attention.kernel import flash_attention_pallas
 from ..kernels.flash_attention.ref import ref_attention
 from ..kernels.moe_gmm.kernel import moe_gmm_pallas
@@ -66,7 +67,7 @@ from .tensor import (LAUNCH_FIELDS, ShardedMeta, ShardedSparseTensor,
                      SparseTensor, bucket_target, build_host, common_shape,
                      container_arrays, container_shapes, pad_arrays, pad_to)
 
-MATVEC_LAYOUTS = ("ell", "sell", "dense")
+MATVEC_LAYOUTS = ("ell", "sell", "dense", "dia")
 
 
 def _cached(store: Optional[PreparedStore], key, builder):
@@ -125,14 +126,27 @@ def _sell_launch(cb, cc, cr, blocks, xb, n_br: int, multi: bool,
 
 
 def _matvec_tiles(arrays, layout: str, xb: jax.Array, n_br: int,
-                  backend: str, multi: bool) -> jax.Array:
+                  backend: str, multi: bool,
+                  offsets: Tuple[int, ...] = ()) -> jax.Array:
     """The product of one operand's launch arrays: on the blocked RHS as
     block rows (n_br, bs) or (n_br, bs, k) for ell/sell (the Pallas kernels
     on pallas/interpret, the jnp reference otherwise), on the flat RHS for
-    dense. One body for the one-chip launch, each member of a stacked
-    bucket and each shard."""
+    dense, and for dia (the operand's ``offsets``) as its padded rows
+    (n_row_pad,) or (n_row_pad, k). One body for the one-chip launch, each
+    member of a stacked bucket and each shard."""
     if layout == "dense":
         return arrays["dense"] @ xb.astype(jnp.float32)
+    if layout == "dia":
+        diags = arrays["diags"]
+        if backend == "jnp":
+            return ref_dia_spmv(diags, xb, offsets)
+
+        def one(x):
+            return bsr_spmv_pallas(diags, x, offsets=offsets,
+                                   interpret=(backend == "interpret"))
+        # the DIA kernel is an SpMV: a multi-RHS launch runs it a column
+        # at a time
+        return jax.lax.map(one, xb.T).T if multi else one(xb)
     if layout == "sell":
         cb, cc, cr = (arrays["cell_block"], arrays["cell_col"],
                       arrays["cell_row"])
@@ -156,12 +170,16 @@ def _matvec_tiles(arrays, layout: str, xb: jax.Array, n_br: int,
 @functools.partial(jax.jit, static_argnames=("backend", "rhs_tile"))
 def _exec_matvec(st: SparseTensor, x: jax.Array, backend: str,
                  rhs_tile: int) -> jax.Array:
-    """y = A @ x (or Y = A @ X for 2-D x) for an ell/sell/dense operand."""
+    """y = A @ x (or Y = A @ X for 2-D x) for an ell/sell/dense/dia
+    operand."""
     _bump_trace("matvec")
     meta = st.meta
     multi = x.ndim == 2
     if meta.layout == "dense":
         return _matvec_tiles(st.arrays, "dense", x, 0, backend, multi)
+    if meta.layout == "dia":
+        return _matvec_tiles(st.arrays, "dia", x, 0, backend, multi,
+                             meta.offsets)[: meta.shape[0]]
     if meta.layout not in ("ell", "sell"):
         raise ValueError(f"spmv/spmm cannot execute layout {meta.layout!r}")
     bs = meta.block_size
@@ -212,7 +230,7 @@ def _plan_matvec(operands, schedule: Optional[Schedule], backend: str, *,
     else:
         st = SparseTensor.wrap(a, schedule)
     if st.layout not in MATVEC_LAYOUTS:
-        raise ValueError(f"{op} needs an ell/sell/dense operand, got a "
+        raise ValueError(f"{op} needs an ell/sell/dense/dia operand, got a "
                          f"{st.layout!r} SparseTensor")
     sched = schedule if schedule is not None else st.meta.schedule
     tile = _rhs_tile(backend)
@@ -240,10 +258,12 @@ def _plan_matvec(operands, schedule: Optional[Schedule], backend: str, *,
     if st.layout == "ell" and ell_streams(st.block_size):
         st.ell_stream()         # counted now, not in a launch
         stream = st.ell_stream
+    if st.layout == "dia":
+        default_registry().set_gauge("kernel.dia.fill", st.to_host().fill())
     return Plan(op=op, schedule=sched, backend=backend, _run=run,
                 operands=(st,),
-                kernel_rhs=None if st.layout == "dense" else "given",
-                ell_stream=stream)
+                kernel_rhs=None if st.layout in ("dense", "dia") else "given",
+                ell_stream=stream, dia=st.layout == "dia")
 
 
 # ---------------------------------------------------------------------------
@@ -484,10 +504,19 @@ def _pad_member_axis(built: Dict, b_pad: int) -> Dict:
     return {**built, "arrays": arrays}
 
 
+def _no_dia(schedules, launch: str) -> None:
+    """Stacked buckets and shard meshes take no DIA operand: each DIA
+    operand's diagonals fix its own program. The selector never picks one;
+    only ``plan()`` launches an operand as DIA."""
+    if any(s is not None and s.layout == "dia" for s in schedules):
+        raise ValueError(f"a {launch} takes no dia operand; plan() it alone")
+
+
 def _plan_matvec_bucket(members: List, schedule: Schedule, backend: str, *,
                         op: str = "spmv", sigma: int = SELL_SIGMA,
                         store: Optional[PreparedStore] = None,
                         member_keys=None, **_) -> Plan:
+    _no_dia([schedule], "stacked bucket")
     if (store is not None and member_keys is not None
             and all(member_keys) and len(set(member_keys)) == 1
             and all(isinstance(m, CSR) for m in members)):
@@ -711,6 +740,7 @@ def _plan_matvec_sharded(operands, schedules, backend: str, *, op: str,
             shard_csrs = part.slice(a)
         shape = (int(a.shape[0]), int(a.shape[1]))
         strategy = part.strategy
+    _no_dia(schedules, "sharded launch")
     n_shards = len(bounds) - 1
     true_rows = tuple(int(bounds[i + 1] - bounds[i]) for i in range(n_shards))
     n_cols = int(shape[1])

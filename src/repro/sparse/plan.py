@@ -24,7 +24,9 @@ ticks ``kernel.tile_product.vpu`` or ``.mxu``, as ``Plan.tile_product``
 reads it from the launch's runtime input. A VPU launch of the streaming ELL
 SpMV adds the valid tiles it copied to ``kernel.ell_stream.tiles`` and the
 grid slots it did not stream to ``kernel.ell_stream.skipped``, from counts
-the plan holds on the host (``Plan.ell_stream``).
+the plan holds on the host (``Plan.ell_stream``). A kernel launch on a DIA
+operand ticks ``kernel.dia.launches``; its planner sets the gauge
+``kernel.dia.fill`` (slots over stored entries, d n_rows / nnz).
 """
 from __future__ import annotations
 
@@ -34,7 +36,8 @@ from typing import Callable, Dict, List, Optional, Sequence, Tuple
 
 import numpy as np
 
-from ..core.autotune import SELL_SIGMA, Schedule
+from ..core.autotune import (DIA_SCHEDULE, SELL_SIGMA, Schedule,
+                             count_diagonals, takes_dia)
 from ..core.csr import BSR, CSR, ELLBSR, SELLBSR
 from ..kernels.common import resolve_backend
 from ..obs import default_registry, trace as obs_trace
@@ -103,6 +106,9 @@ class Plan:
     # through the ELL SpMV kernel, counted on the host (no device read);
     # None where no launch streams. Read by ``execute``.
     ell_stream: Optional[Callable[[], Tuple[int, int]]] = None
+    # whether the launch's operand is in the DIA layout: a launch on a
+    # kernel backend ticks ``kernel.dia.launches``. Read by ``execute``.
+    dia: bool = False
     # wall-clock of the most recent execute (set per call). With the NaN
     # guard on (default) the guarded run synchronizes on the result, so
     # this is end-to-end launch latency, not dispatch-only.
@@ -142,6 +148,8 @@ class Plan:
             tiles, slots = self.ell_stream()
             reg.inc("kernel.ell_stream.tiles", tiles)
             reg.inc("kernel.ell_stream.skipped", slots - tiles)
+        if self.dia and self.backend in ("pallas", "interpret"):
+            reg.inc("kernel.dia.launches")
         return out
 
     def tile_product(self, *runtime) -> Optional[str]:
@@ -166,6 +174,8 @@ class Plan:
             sched = ("per-shard" if self.n_shards > 1 else "none")
         elif s.backend == "dense":
             sched = "dense"
+        elif s.layout == "dia":
+            sched = "dia"
         else:
             lay = (f"sell C={s.slice_height}" if s.layout == "sell"
                    else f"ell q={s.ell_quantile}")
@@ -216,23 +226,34 @@ def _resolve_with_selector(selector, A: CSR, op: str = "",
     or a ScheduleTuner. The service already hashed the matrix bytes for its
     fingerprint memo; the key is forwarded so the planner's PreparedStore
     lookup does not pay a second O(nnz) hashing pass. ``quarantine`` is the
-    registry the tuner path consults (defaults to the process-wide one)."""
+    registry consulted (defaults to the process-wide one).
+
+    An SpMV then goes to the diagonal layout where its structure says so
+    (``autotune.takes_dia``), whatever the selector picked, unless that
+    layout is quarantined: the selector's picks serve every launch form,
+    and only a plan launches an operand alone."""
     if not isinstance(A, CSR):
         raise TypeError("selector-based planning needs a CSR first operand, "
                         f"got {type(A).__name__}")
+    q = (quarantine if quarantine is not None
+         else resilience.default_quarantine())
+    n_diagonals = None
     if hasattr(selector, "process_pending"):      # SelectorService
         dec = selector.select(A)
-        return dec.schedule, {
+        schedule, ck, kind = dec.schedule, getattr(dec, "ck", None), "selector"
+        provenance = {
             "source": f"selector-{dec.source}",
             "fingerprint_key": dec.fingerprint_key,
             "modeled_time_s": dec.modeled_time_s,
             "confidence": dec.confidence,
-        }, getattr(dec, "ck", None)
-    if hasattr(selector, "select"):               # ScheduleTuner
+        }
+        fp = selector.memoized_fingerprint(ck)
+        if fp is not None:
+            n_diagonals = fp.n_diagonals
+        n_rhs = selector.tuner.n_rhs
+    elif hasattr(selector, "select"):             # ScheduleTuner
         schedule, info = selector.select(A)
-        source = "tuner"
-        q = (quarantine if quarantine is not None
-             else resilience.default_quarantine())
+        source, ck, kind, n_rhs = "tuner", None, "tuner", selector.n_rhs
         if op and schedule is not None \
                 and q.blocked_any_backend(op, schedule):
             # never re-serve a poisoned schedule: re-argmin the candidate
@@ -241,12 +262,20 @@ def _resolve_with_selector(selector, A: CSR, op: str = "",
             resel = resilience.unquarantined_select(selector, A, op, q)
             if resel is not None:
                 schedule, source = resel, "tuner-requarantined"
-        return schedule, {
-            "source": source,
-            "modeled_time_s": info.get("verified_time_s"),
-        }, None
-    raise TypeError(f"unsupported selector {type(selector).__name__}; pass a "
-                    "SelectorService or a fitted ScheduleTuner")
+        provenance = {"source": source,
+                      "modeled_time_s": info.get("verified_time_s")}
+    else:
+        raise TypeError(f"unsupported selector {type(selector).__name__}; "
+                        "pass a SelectorService or a fitted ScheduleTuner")
+    if op == "spmv" and schedule is not None and schedule.backend != "dense" \
+            and not q.blocked_any_backend(op, DIA_SCHEDULE):
+        if n_diagonals is None:
+            n_diagonals = count_diagonals(A)
+        if takes_dia(n_diagonals, A.n_rows, A.nnz, n_rhs,
+                     getattr(A, "mutation_slack", 0) > 0):
+            schedule = DIA_SCHEDULE
+            provenance.update(source=f"{kind}-structure", modeled_time_s=None)
+    return schedule, provenance, ck
 
 
 def plan(op: str, operands, schedule: Optional[Schedule] = None,

@@ -6,6 +6,9 @@ One class wraps every prepared layout the kernels consume (DESIGN.md §8):
   sell   sliced SELL-BSR cell schedule (``core.csr.SELLBSR``)
   bsr    raw blocked rows (spgemm/spadd operands; symbolic phase is host-side)
   dense  the dense-schedule escape hatch (density above the autotune threshold)
+  dia    one value per (occupied diagonal, row) (``core.csr.DIA``): banded
+         SpMV operands, which ``plan()`` picks from their diagonal fill
+         (``autotune.takes_dia``); its offsets are static meta
 
 The device arrays are pytree *leaves* and the structural facts (layout,
 shape, block size, the ``Schedule`` that built it) are static aux data, so a
@@ -26,12 +29,13 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from ..core.autotune import SELL_SIGMA, Schedule
-from ..core.csr import (BSR, CSR, ELLBSR, SELLBSR, ell_block_cap,
+from ..core.autotune import DIA_SCHEDULE, SELL_SIGMA, Schedule
+from ..core.csr import (BSR, CSR, DIA, ELLBSR, SELLBSR, ell_block_cap,
                         sell_layout)
+from ..kernels.bsr_spmv.kernel import LANES, dia_rows
 from .prepared import bucket_edge
 
-HostLayout = Union[ELLBSR, SELLBSR, BSR, np.ndarray]
+HostLayout = Union[ELLBSR, SELLBSR, BSR, DIA, np.ndarray]
 
 # Leaf names per layout, in flatten order (the pytree contract).
 LAYOUT_FIELDS: Dict[str, Tuple[str, ...]] = {
@@ -40,9 +44,10 @@ LAYOUT_FIELDS: Dict[str, Tuple[str, ...]] = {
              "slice_widths", "blocks"),
     "bsr": ("block_ptrs", "block_cols", "blocks"),
     "dense": ("dense",),
+    "dia": ("diags",),
 }
 
-# The launch format (DESIGN.md §9): the leaves an ell/sell/dense launch
+# The launch format (DESIGN.md §9): the leaves an ell/sell/dense/dia launch
 # reads (all but ``slice_widths``, which only construction reads), and how
 # each pads when a member grows to a bucket edge or to the shapes a stack
 # shares. Slots and cells past a member's own point at its trailing
@@ -50,14 +55,16 @@ LAYOUT_FIELDS: Dict[str, Tuple[str, ...]] = {
 # nondecreasing (the Pallas kernels keep a row's output tile resident until
 # the row changes); ``row_perm`` extends as the identity, so padded rows
 # land on sliced-away output rows; ``slice_widths`` pads with 1, an empty
-# slice's width. Every other leaf pads with 0.
+# slice's width; ``diags`` with 0, a slot no entry holds. Every other leaf
+# pads with 0.
 LAUNCH_FIELDS: Dict[str, Tuple[str, ...]] = {
     lay: tuple(f for f in LAYOUT_FIELDS[lay] if f != "slice_widths")
-    for lay in ("ell", "sell", "dense")}
+    for lay in ("ell", "sell", "dense", "dia")}
 ZERO_TILE, LAST, IDENTITY = "zero tile", "last", "identity"
 PAD_FILL: Dict[str, object] = {"block_indices": ZERO_TILE,
                                "cell_block": ZERO_TILE, "cell_row": LAST,
-                               "row_perm": IDENTITY, "slice_widths": 1}
+                               "row_perm": IDENTITY, "slice_widths": 1,
+                               "diags": 0}
 
 
 @dataclasses.dataclass(frozen=True)
@@ -71,6 +78,7 @@ class SparseMeta:
     slice_height: int = 0
     sigma: int = 0
     schedule: Optional[Schedule] = None
+    offsets: Tuple[int, ...] = ()   # dia: the diagonals, the kernel unrolls
 
 
 class SparseTensor:
@@ -159,6 +167,10 @@ class SparseTensor:
             return csr.to_dense()
         if layout == "bsr":
             return BSR.from_csr(csr, schedule.block_size)
+        if schedule.layout == "dia":
+            offsets = csr.diagonal_offsets()
+            return DIA.from_csr(csr, dia_row_pad(offsets.size, csr.n_rows),
+                                offsets)
         if schedule.layout == "sell":
             return SELLBSR.from_bsr(BSR.from_csr(csr, schedule.block_size),
                                     max(schedule.slice_height, 1), sigma)
@@ -220,8 +232,12 @@ class SparseTensor:
     def from_layout(cls, container: HostLayout,
                     schedule: Optional[Schedule] = None,
                     device: Optional[jax.Device] = None) -> "SparseTensor":
-        """Wrap an existing host container (ELLBSR/SELLBSR/BSR/dense), its
-        arrays uploaded to ``device`` (the default device when None)."""
+        """Wrap an existing host container (ELLBSR/SELLBSR/BSR/DIA/dense),
+        its arrays uploaded to ``device`` (the default device when None).
+        A DIA container's rows are re-padded to the kernel's row block
+        (``dia_row_pad``) where they are not already."""
+        if isinstance(container, DIA):
+            container = _dia_whole_row_blocks(container)
         layout, host = container_arrays(container)
         if layout == "dense":
             if host["dense"].ndim != 2:
@@ -232,6 +248,11 @@ class SparseTensor:
             meta = SparseMeta("dense", host["dense"].shape,
                               schedule.block_size, schedule=schedule)
             container = host["dense"]
+        elif layout == "dia":
+            meta = SparseMeta("dia", container.shape, LANES,
+                              n_block_rows=container.diags.shape[1],
+                              schedule=schedule or DIA_SCHEDULE,
+                              offsets=container.offsets)
         else:
             bs = container.block_size
             if schedule is None:
@@ -303,6 +324,9 @@ class SparseTensor:
         elif m.layout == "bsr":
             a["block_ptrs"] = a["block_ptrs"].astype(np.int64)
             host = BSR(**a, shape=m.shape, block_size=m.block_size)
+        elif m.layout == "dia":
+            host = DIA(m.offsets, a["diags"], m.shape,
+                       int(np.count_nonzero(a["diags"])))
         else:
             host = a["dense"]
         self._host = host
@@ -316,11 +340,15 @@ def container_shapes(csr: CSR, schedule: Schedule, *,
                      shape_bucket: bool = False) -> Dict[str, Tuple[int, ...]]:
     """Shape of each device array ``SparseTensor.from_csr(csr, schedule,
     sigma=sigma, shape_bucket=shape_bucket)`` would hold, from the tile
-    count of each block row alone: no tile is built. Every array is 4 bytes
-    an element (int32 or float32)."""
+    count of each block row (or the diagonal count) alone: no tile is
+    built. Every array is 4 bytes an element (int32 or float32). A DIA
+    operand is never bucketed."""
     edge = bucket_edge if shape_bucket else int
     if schedule.backend == "dense":
         return {"dense": (edge(csr.n_rows), edge(csr.n_cols))}
+    if schedule.layout == "dia":
+        d = csr.diagonal_offsets().size
+        return {"diags": (d, dia_row_pad(d, csr.n_rows) // LANES, LANES)}
     bs = schedule.block_size
     bpr = csr.block_row_tiles(bs)
     n_br, nb = bpr.size, int(bpr.sum()) + 1   # + the shared zero tile
@@ -349,8 +377,15 @@ def build_host(csr: CSR, schedule: Schedule, *, layout: Optional[str] = None,
                shape_bucket: bool = False, slack: int = 0
                ) -> Tuple[HostLayout, Optional[int], list]:
     """The host side of ``SparseTensor.from_csr``: the container it wraps,
-    the index of that container's shared zero tile (None for bsr/dense),
-    and the spare tiles of a mutable one."""
+    the index of that container's shared zero tile (None for
+    bsr/dense/dia), and the spare tiles of a mutable one. A DIA container
+    takes no slack and no bucketing: its rows pad to the kernel's row
+    block, its columns not at all."""
+    if schedule.layout == "dia" and layout != "bsr":
+        if slack > 0:
+            raise ValueError("a dia operand takes no mutation slack; "
+                             "mutable operands use ell or sell")
+        return SparseTensor.build_container(csr, schedule), None, []
     if shape_bucket and slack == 0 and layout != "bsr" \
             and schedule.backend != "dense" and schedule.layout != "sell":
         container, zero_idx = _bucketed_ell(csr, schedule, max_blocks)
@@ -367,6 +402,27 @@ def build_host(csr: CSR, schedule: Schedule, *, layout: Optional[str] = None,
     if shape_bucket and not isinstance(container, BSR):
         container = pad_container_to_bucket(container)
     return container, zero_idx, spare
+
+
+def dia_row_pad(n_diags: int, n_rows: int) -> int:
+    """The rows a DIA container of ``n_diags`` diagonals holds for
+    ``n_rows``: a whole number of the kernel's row blocks
+    (``kernels.bsr_spmv.kernel.dia_rows`` lane rows of 128)."""
+    lane_rows = -(-max(n_rows, 1) // LANES)
+    step = dia_rows(n_diags, lane_rows)
+    return -(-lane_rows // step) * step * LANES
+
+
+def _dia_whole_row_blocks(D: DIA) -> DIA:
+    """``D`` with its rows padded (or cut, past ``D.shape[0]``) to
+    ``dia_row_pad``; ``D`` itself where they already are."""
+    lanes = dia_row_pad(len(D.offsets), D.shape[0]) // LANES
+    if D.diags.shape[1] == lanes:
+        return D
+    diags = np.zeros((len(D.offsets), lanes, LANES), np.float32)
+    keep = min(lanes, D.diags.shape[1])
+    diags[:, :keep] = D.diags[:, :keep]
+    return DIA(D.offsets, diags, D.shape, D.nnz)
 
 
 # --------------------------------------------------------- shape bucketing
@@ -411,6 +467,8 @@ def container_arrays(container: HostLayout
                                       np.float32 if f == "blocks"
                                       else np.int32)
                         for f in LAYOUT_FIELDS[layout]}
+    if isinstance(container, DIA):
+        return "dia", {"diags": np.asarray(container.diags, np.float32)}
     return "dense", {"dense": np.asarray(container, np.float32)}
 
 
@@ -480,8 +538,10 @@ def pad_container_to_bucket(container: HostLayout,
     unchanged. Raw BSR is returned as it is: its exec paths consume
     symbolic products that are bucketed on their own. ``tiles``, given, is
     the bucket-sized tile array that already starts with the container's
-    tiles; it becomes the container's, uncopied."""
-    if isinstance(container, BSR):
+    tiles; it becomes the container's, uncopied. A DIA container is
+    returned as it is too: its rows are already padded to the kernel's row
+    block, and its diagonals fix its program anyway."""
+    if isinstance(container, (BSR, DIA)):
         return container
     layout, arrays = container_arrays(container)
     zero = arrays["blocks"].shape[0] - 1 if layout != "dense" else None

@@ -13,7 +13,7 @@ import jax
 import numpy as np
 
 from repro.core import TPU_V5E, ScheduleTuner, corpus
-from repro.core.autotune import Schedule
+from repro.core.autotune import DIA_SCHEDULE, Schedule
 from repro.core.synthetic import gen_stencil27
 from repro.obs import default_registry
 from repro.selector import ScheduleCache, SelectorService
@@ -113,9 +113,10 @@ def main() -> dict:
         corpus(n_matrices=6, n_min=128, n_max=256, seed=3), max_mats=4)
     svc = SelectorService(tuner, cache=ScheduleCache(),
                           prepared_store=PreparedStore(need))
-    sel = svc.select(A).schedule
+    # plan() launches the stencil as DIA (it takes no shards): too large
+    # for one chip even so, each shard is the selector's own pick
     plan_mod.chip_memory_bytes = lambda: prepared_nbytes(
-        A, sel, shape_bucket=True) // 2
+        A, DIA_SCHEDULE, shape_bucket=True) // 2
     ps = plan("spmv", A, selector=svc, backend="interpret")
     same = plan_sharded("spmv", A, n_shards=4, backend="interpret",
                         schedules=[pr["schedule"]
@@ -126,6 +127,25 @@ def main() -> dict:
                sel_vs_explicit=float(np.max(np.abs(
                    np.asarray(ps.execute(x)) - np.asarray(same.execute(x))))),
                sel_shard_requests=svc.telemetry()["shard_requests"])
+
+    # a stencil whose tiles overflow one chip but whose diagonals fit:
+    # plan()'s DIA pick is placed on one chip, the selector's tile pick is
+    # not
+    tiles = svc.select(A).schedule
+    room = plan_mod.CALLER_VECTORS * 4 * (A.n_rows + A.n_cols)
+    dia = DIA_SCHEDULE
+    plan_mod.chip_memory_bytes = lambda: prepared_nbytes(
+        A, dia, shape_bucket=True) + room
+    fresh = SelectorService(tuner, cache=ScheduleCache(),
+                            prepared_store=PreparedStore(need))
+    on_one = plan("spmv", A, selector=fresh, backend="interpret")
+    out.update(dia_fit_shards=on_one.n_shards,
+               dia_fit_describe=on_one.describe(),
+               dia_fit_err=row_error(on_one.execute(x), ref, bound),
+               tiles_fit_shards=plan("spmv", A, schedule=tiles,
+                                     backend="interpret").n_shards,
+               tiles_over_dia=prepared_nbytes(A, tiles, shape_bucket=True)
+               / prepared_nbytes(A, dia, shape_bucket=True))
     return out
 
 

@@ -96,12 +96,24 @@ def test_the_selector_decides_each_shard(four):
     assert four["sel_vs_explicit"] == 0.0
 
 
+def test_a_stencil_whose_diagonals_fit_stays_on_one_chip(four):
+    """Placement compares the selected schedule's bytes with the chip: a
+    stencil whose tiles would be placed over four chips fits one as DIA
+    (as hpcg_4rank does on a v5e)."""
+    assert four["tiles_over_dia"] > 1 and four["tiles_fit_shards"] == 4
+    assert four["dia_fit_shards"] == 1
+    assert four["dia_fit_describe"] == \
+        "plan[spmv] dia via selector-structure"
+    assert four["dia_fit_err"] <= HPCG_TOL
+
+
 # ------------------------------------------- shapes placement rests on
 
 SCHEDULES = [Schedule("bsr", 32, 1.0), Schedule("bsr", 16, 0.9),
              Schedule("bsr", 32, 1.0, layout="sell", slice_height=4),
              Schedule("bsr", 8, 1.0, layout="sell", slice_height=8),
-             Schedule("dense", 128, 1.0)]
+             Schedule("dense", 128, 1.0), Schedule("dia", 128, 1.0,
+                                                   layout="dia")]
 
 
 @pytest.mark.parametrize("bucket", [False, True])

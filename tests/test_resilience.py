@@ -11,7 +11,8 @@ import numpy as np
 import pytest
 
 from repro.core import CSR, TPU_V5E, ScheduleTuner, corpus
-from repro.core.autotune import Schedule, candidate_schedules
+from repro.core.autotune import (DIA_FILL_MAX, Schedule,
+                                 candidate_schedules, count_diagonals)
 from repro.selector import ScheduleCache, SelectorService
 from repro.selector.cache import CACHE_FORMAT_VERSION
 from repro.selector.fingerprint import fingerprint
@@ -433,7 +434,10 @@ def test_quarantine_ttl_expiry():
 
 def test_quarantined_schedule_never_reselected_across_refit(tuner):
     svc = SelectorService(tuner, confidence_threshold=0.0)
-    A = HELD[0][2]
+    # a held matrix plan() does not launch as DIA: the tuner path below
+    # re-selects through the quarantine
+    A = next(m for _, _, m in HELD
+             if count_diagonals(m) * m.n_rows > DIA_FILL_MAX * m.nnz)
     first = svc.select(A)
     assert first.source in ("tree", "verify")
     # the serving loop quarantines the pick (as a failed launch would)
@@ -449,12 +453,13 @@ def test_quarantined_schedule_never_reselected_across_refit(tuner):
     assert svc._counts["refits"] == 1
     third = svc.select(A)
     assert third.schedule != first.schedule      # still never re-served
-    # the tuner path honors the same quarantine
+    # the tuner path honors the same quarantine: its own pick is
+    # quarantined too where it is not the service's
     sched, _ = tuner.select(A)
-    if sched == first.schedule:
-        p = plan("spmv", A, selector=tuner)
-        assert p.schedule != first.schedule
-        assert p.source == "tuner-requarantined"
+    svc.quarantine.add(tuner.kernel, "jnp", sched, reason="test")
+    p = plan("spmv", A, selector=tuner)
+    assert p.schedule not in (first.schedule, sched)
+    assert p.source == "tuner-requarantined"
 
 
 def test_verify_sweep_excludes_quarantined_candidates(tuner):

@@ -122,6 +122,31 @@ def test_matvec_compiles_for_v5e(one_chip, layout, bs, op):
     assert _launches(compiled) == len(row_ranges(tables[0][0], tables)) > 1
 
 
+def _stencil_offsets(nx, ny):
+    return tuple(sorted({dz * nx * ny + dy * nx + dx for dz in (-1, 0, 1)
+                         for dy in (-1, 0, 1) for dx in (-1, 0, 1)}))
+
+
+@pytest.mark.parametrize("nz", [96, 384])
+def test_dia_spmv_compiles_for_v5e(one_chip, nz):
+    """hpcg's and hpcg_4rank's DIA launch: the 96x96xnz stencil's 27
+    diagonals, one Pallas call named as the family's SpMV entry (the name a
+    device trace shows), x padded into lane rows inside the launch."""
+    from repro.sparse.tensor import dia_row_pad
+    n = 96 * 96 * nz
+    pad = dia_row_pad(27, n)
+    assert pad == n     # 96 x 96 x nz fills whole row blocks
+    meta = SparseMeta("dia", (n, n), 128, n_block_rows=pad // 128,
+                      schedule=Schedule("dia", 128, 1.0, layout="dia"),
+                      offsets=_stencil_offsets(96, 96))
+    st = SparseTensor(meta, {"diags": _spec(one_chip, (27, pad // 128, 128),
+                                            F32)})
+    compiled = _exec_matvec.lower(st, _spec(one_chip, (n,), F32),
+                                  backend="pallas", rhs_tile=128).compile()
+    assert _launches(compiled) == 1
+    assert "%bsr_spmv_pallas" in compiled.as_text()
+
+
 def test_stacked_matvec_compiles_for_v5e(one_chip):
     """A mixed-content bucket of two 48^3 stencils: one program, each
     member's streamed ELL SpMV split by block-row range."""
